@@ -13,9 +13,9 @@ import numpy as np
 
 from benchmarks.common import emit, time_call
 from repro.core.bsr import pack_dense
+from repro.core.gqs_layer import pack_w4
 from repro.core.pruning import PruneConfig, group_mask
-from repro.core.quant import QuantConfig, group_minmax_params, pack_int4, \
-    quantize
+from repro.core.quant import QuantConfig
 from repro.core.saliency import group_saliency
 from repro.kernels import ops, ref
 from repro.launch.hlo_analysis import HBM_BW
@@ -37,10 +37,9 @@ def main():
          f"bytes={bts['total_bytes']}")
 
     # W4 dense baseline
-    qcfg = QuantConfig(bits=4, group_size=16)
-    s, z = group_minmax_params(w, qcfg)
-    qw = pack_int4(quantize(w, s, z, qcfg))
-    w4 = jax.jit(lambda xx: ref.w4_matmul_ref(xx, qw, s, z, 16))
+    p4 = pack_w4(w, QuantConfig(bits=4, group_size=16))
+    w4 = jax.jit(lambda xx: ref.w4_matmul_ref(xx, p4["qw"], p4["scale"],
+                                              p4["zero"]))
     us = time_call(w4, x)
     bts = ops.dense_bytes_model(N, K, bits=4, group_size=16)
     emit("fig6/w4_dense", us,

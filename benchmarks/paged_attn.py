@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops, ref as kref
+from repro.launch.compile_cache import enable_compile_cache
 
 try:
     from benchmarks.common import emit, time_call, write_bench_json
@@ -62,9 +63,9 @@ def make_case(ctx: int, page_size: int, t: int, seed: int = 0,
         bt[i, :len(pg)] = pg
     lengths = (lens[:, None] - (t - 1) + np.arange(t)[None, :]).clip(1)
     q = jnp.asarray(g.normal(size=(b, t, kh * r, d)), jnp.float32)
-    kp = jnp.asarray(g.normal(size=(num_pages, page_size, kh, d)) * 0.1,
+    kp = jnp.asarray(g.normal(size=(kh, num_pages, page_size, d)) * 0.1,
                      jnp.float32)
-    vp = jnp.asarray(g.normal(size=(num_pages, page_size, kh, d)) * 0.1,
+    vp = jnp.asarray(g.normal(size=(kh, num_pages, page_size, d)) * 0.1,
                      jnp.float32)
     return (q, kp, vp, jnp.asarray(lengths.astype(np.int32)),
             jnp.asarray(bt), int(occ.max()), lens)
@@ -86,13 +87,13 @@ def main(argv=None):
     ap.add_argument("--quick", action="store_true",
                     help="smallest sweep point only (CI smoke)")
     args, _ = ap.parse_known_args(argv)
+    enable_compile_cache()
 
     # parity gate first: the kernel must match the oracle before any
     # traffic claim is emitted (interpret mode, small shape)
     q, kp, vp, lengths, bt, occ, _ = make_case(64, 16, 3, seed=7)
     o_ref = kref.paged_attention_ref(q, kp, vp, lengths, bt)
-    o_ker = ops.paged_decode_attention(q, kp, vp, lengths, bt,
-                                       use_pallas=True, interpret=True)
+    o_ker = ops.paged_decode_attention(q, kp, vp, lengths, bt)
     np.testing.assert_allclose(np.asarray(o_ker), np.asarray(o_ref),
                                rtol=1e-4, atol=1e-4)
     print("# kernel parity vs dense-gather reference: OK (max|err| "

@@ -38,6 +38,8 @@ def main() -> None:
                          "decode, paged attention) instead of the "
                          "paper-table group")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     mods = args.only.split(",") if args.only \
         else (SERVE_MODULES if args.serve else MODULES)
     print("name,us_per_call,derived")

@@ -28,6 +28,7 @@ from repro.engine.loadgen import (SLO, SLOLedger, WorkloadSpec, generate,
                                   make_source)
 from repro.launch.serve import compressed_params, make_requests
 from repro.models.registry import get_model
+from repro.launch.compile_cache import enable_compile_cache
 
 try:
     from benchmarks.common import emit, write_bench_json
@@ -423,6 +424,7 @@ def main(argv=None):
     ap.add_argument("--max-seq", type=int, default=64)
     ap.add_argument("--seed", type=int, default=0)
     args, _ = ap.parse_known_args(argv)
+    enable_compile_cache()
 
     cfg = get_config("llama2_7b", reduced=True)
     speedups = []

@@ -30,6 +30,7 @@ from repro.core.pruning import PruneConfig
 from repro.core.quant import QuantConfig
 from repro.engine import (EngineConfig, InferenceEngine, SamplingParams,
                           Telemetry)
+from repro.launch.compile_cache import enable_compile_cache
 
 try:
     from benchmarks.common import (calib_batches, emit, held_out_batches,
@@ -98,6 +99,7 @@ def main(argv=None):
     ap.add_argument("--max-seq", type=int, default=64)
     ap.add_argument("--repeats", type=int, default=3)
     args, _ = ap.parse_known_args(argv)
+    enable_compile_cache()
 
     # serving-regime checkpoint: decode steps are dispatch/op-bound (like
     # DMA-bound real-size decode, the K+1-token verify costs about one

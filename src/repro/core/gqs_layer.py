@@ -7,8 +7,12 @@ so the same model definition runs FP training, fake-quant optimization
 
     fp          {"w": [N,K] (, "b")}
     fake_quant  {"w", "gmask" [N,K/G] bool (, "scale","zero" [N,K/G])}
-    w4          {"qw" packed u8 [N,K/2], "scale","zero" [N,K/G]}   dense quant
+    w4          {"qw" int32 [Kp/8,N], "scale","zero" [8,Kp/8G,N]}  dense quant
     gqsa        {"bsr": BSRMatrix}                                  quant+sparse
+
+The packed forms are laid out for their TPU kernels at pack time
+(kernels/w4_matmul.py, core/bsr.py). ``use_pallas`` picks the kernels
+(`kernels/ops.py`) or their plain-jnp oracles (`kernels/ref.py`).
 """
 from __future__ import annotations
 
@@ -21,7 +25,7 @@ import jax.numpy as jnp
 from repro.core import pruning
 from repro.core.bsr import BSRMatrix, pack_dense
 from repro.core.quant import (QuantConfig, fake_quant, group_minmax_params,
-                              pack_int4, quantize)
+                              quantize)
 from repro.core.pruning import PruneConfig, expand_mask, group_mask
 from repro.core.saliency import (HessianStats, group_saliency,
                                  weight_saliency)
@@ -58,14 +62,11 @@ def apply_linear(p: Dict, x: jnp.ndarray, *, qcfg: Optional[QuantConfig] = None,
     k = x.shape[-1]
     x2 = x.reshape(-1, k)
     if isinstance(p, dict) and "bsr" in p:
-        bsr = p["bsr"]
-        y = kops.gqsa_gemv(x2, bsr, use_pallas=use_pallas)
-        y = y.astype(x.dtype)
+        gemv = kops.gqsa_gemv if use_pallas else kref.gqsa_gemv_ref
+        y = gemv(x2, p["bsr"]).astype(x.dtype)
     elif isinstance(p, dict) and "qw" in p:
-        g = k // p["scale"].shape[-1]
-        y = kops.w4_matmul(x2, p["qw"], p["scale"], p["zero"],
-                           group_size=g,
-                           use_pallas=use_pallas).astype(x.dtype)
+        mm = kops.w4_matmul if use_pallas else kref.w4_matmul_ref
+        y = mm(x2, p["qw"], p["scale"], p["zero"]).astype(x.dtype)
     elif isinstance(p, dict) and "q" in p:
         # E2E-OQP: frozen INT codes, trainable (scale, zero) — dequant is
         # linear in (s, z) so gradients flow to them with no STE
@@ -118,15 +119,31 @@ def pack_gqsa(p_fake: Dict, cfg: GQSAConfig) -> Dict:
 
 
 def pack_w4(w: jnp.ndarray, qcfg: QuantConfig) -> Dict:
-    """FP weight -> dense W<=4 serving params (quantization-only baseline).
-    Nibble packing only holds codes < 16; wider bit-widths use the
+    """FP weight -> dense W<=4 serving params (quantization-only baseline),
+    in the plane-packed layout of ``kernels/w4_matmul.py``: K padded to a
+    multiple of 8·G with zero codes and scales, word r of the [Kp/8, N]
+    int32 ``qw`` holding the codes of input columns r + i·Kp/8 in nibble
+    i. Nibble packing only holds codes < 16; wider bit-widths use the
     fake-quant (dense FP) representation instead."""
     if qcfg.bits > 4:
-        raise ValueError("pack_w4 packs two codes per byte: bits must be "
+        raise ValueError("pack_w4 packs 4-bit nibbles: bits must be "
                          "<= 4 (use fake_quant for W8)")
+    n, k = w.shape
+    g = qcfg.group_size
     s, z = group_minmax_params(w, qcfg)
     q = quantize(w, s, z, qcfg)
-    return {"qw": pack_int4(q), "scale": s, "zero": z}
+    kp = -(-k // (8 * g)) * (8 * g)
+    pad = ((0, kp - k), (0, 0))
+    planes = jnp.pad(q.T.astype(jnp.uint32), pad).reshape(8, kp // 8, n)
+    words = jnp.zeros((kp // 8, n), jnp.uint32)
+    for i in range(8):
+        words = words | (planes[i] << (4 * i))
+
+    def groups(a):                                # [N, K/G] -> [8, Kp/8G, N]
+        return jnp.pad(a.T, ((0, (kp - k) // g), (0, 0))).reshape(
+            8, kp // (8 * g), n).astype(jnp.float32)
+    return {"qw": jax.lax.bitcast_convert_type(words, jnp.int32),
+            "scale": groups(s), "zero": groups(z)}
 
 
 def compress_linear(w: jnp.ndarray, stats: HessianStats,
@@ -140,31 +157,33 @@ def compress_linear(w: jnp.ndarray, stats: HessianStats,
 # ---------------------------------------------------------------------------
 
 def packed_linear_shapes(n: int, k: int, cfg: GQSAConfig) -> Dict:
-    """ShapeDtypeStructs of the packed representation for (n, k)."""
+    """ShapeDtypeStructs of the packed representation for (n, k), as
+    ``core/bsr.py:pack_dense`` lays out a row-balanced mask."""
+    from repro.core.bsr import tiles
     g = cfg.prune.group_size
     m = pruning.groups_kept_per_row(k, cfg.prune)
+    block_n, lane, np_, mp, cp = tiles(n, k, g, m)
+    items = np_ // block_n * (-(-m // lane))
     sds = jax.ShapeDtypeStruct
     bsr = BSRMatrix(
-        idx=sds((n, m), jnp.int32),
-        vals=sds((n, m, g // 2), jnp.uint8),
-        scale=sds((n, m), jnp.float32),
-        zero=sds((n, m), jnp.float32),
-        shape=(n, k), group_size=g, bits=cfg.quant.bits)
+        words=sds((g // 8, np_, mp), jnp.int32),
+        scale=sds((np_, mp), jnp.float32),
+        zero=sds((np_, mp), jnp.float32),
+        pos=sds((np_, cp), jnp.int32),
+        work=sds((4, items), jnp.int32),
+        shape=(n, k), group_size=g, bits=cfg.quant.bits, m=m,
+        block_n=block_n, lane=lane)
     return {"bsr": bsr}
 
 
 def dequant_dense(p: Dict, qcfg: Optional[QuantConfig] = None) -> jnp.ndarray:
-    """Any representation -> dense FP weight (for tests / analysis)."""
+    """Any representation -> dense FP weight (for tests / analysis). The
+    W4 form returns its K padded to a multiple of 8·G (zero columns)."""
     from repro.core.bsr import to_dense
-    from repro.core.quant import dequantize, unpack_int4
     if "bsr" in p:
         return to_dense(p["bsr"])
     if "qw" in p:
-        k2 = p["qw"].shape[1] * 2
-        g = k2 // p["scale"].shape[-1]
-        q = unpack_int4(p["qw"])
-        return dequantize(q, p["scale"], p["zero"],
-                          QuantConfig(group_size=g))
+        return kref.w4_dense(p["qw"], p["scale"], p["zero"]).T
     if "gmask" in p:
         assert qcfg is not None
         w = fake_quant(p["w"], qcfg, p.get("scale"), p.get("zero"))

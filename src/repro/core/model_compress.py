@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.bsr import BSRMatrix, pack_dense
+from repro.core.bsr import BSRMatrix, pack_dense, stack_bsr
 from repro.core.gqs_layer import GQSAConfig, packed_linear_shapes, pack_w4
 from repro.core.pruning import PruneConfig, group_mask
 from repro.core.quant import QuantConfig, group_minmax_params, quantize, \
@@ -67,10 +67,7 @@ def _pack_stacked(w: np.ndarray, cfg: GQSAConfig,
         gsal = group_saliency(sal, cfg.prune.group_size)
         gm = group_mask(gsal, cfg.prune)
         packed.append(pack_dense(wi, gm, cfg.quant))
-    if not lead:
-        return packed[0]
-    stack = lambda *xs: jnp.stack(xs).reshape(lead + xs[0].shape)
-    return jax.tree_util.tree_map(stack, *packed)
+    return stack_bsr(packed, lead) if lead else packed[0]
 
 
 # HessianStats has no _replace_uniform; provide magnitude fallback directly

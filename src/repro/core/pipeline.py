@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.bqpo import BQPOConfig, bqpo
-from repro.core.bsr import pack_quantized
+from repro.core.bsr import pack_quantized, stack_bsr
 from repro.core.e2e_oqp import E2EConfig, e2e_oqp, freeze_int
 from repro.core.gqs_layer import GQSAConfig
 
@@ -41,10 +41,7 @@ def pack_frozen(params_frozen: Dict) -> Dict:
                                      jnp.asarray(scf[i]), jnp.asarray(zrf[i]),
                                      group_size=g)
                       for i in range(qf.shape[0])]
-            if not lead:
-                return {"bsr": packed[0]}
-            stack = lambda *xs: jnp.stack(xs).reshape(lead + xs[0].shape)
-            return {"bsr": jax.tree_util.tree_map(stack, *packed)}
+            return {"bsr": stack_bsr(packed, lead) if lead else packed[0]}
         if isinstance(node, dict):
             return {k: walk(v) for k, v in node.items()}
         return node

@@ -47,7 +47,7 @@ def update_sharded_cache(cache: jnp.ndarray, new: jnp.ndarray,
     """Write ``new`` [B, 1, KH, D] at sequence position ``pos`` of a cache
     [B, S, KH, D] sharded over ``axis`` on the S dim. Only the owning
     shard writes; others pass their slice through."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     s = cache.shape[1]
     s_loc = s // int(mesh.shape[axis])
@@ -62,7 +62,7 @@ def update_sharded_cache(cache: jnp.ndarray, new: jnp.ndarray,
 
     return shard_map(local, mesh=mesh,
                      in_specs=(P(None, axis), P(), P()),
-                     out_specs=P(None, axis), check_rep=False)(
+                     out_specs=P(None, axis), check_vma=False)(
                          cache, new, jnp.asarray(pos, jnp.int32))
 
 
@@ -72,7 +72,7 @@ def sharded_decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     """Flash-decoding over a sequence-sharded KV cache: each shard computes
     partial (max, exp-sum, weighted values) over its local keys; pmax/psum
     combine to the exact softmax. q: [B, 1, H, D]; caches: [B, S, KH, D]."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     b, s, kh, d = k_cache.shape
     h = q.shape[2]
@@ -100,6 +100,6 @@ def sharded_decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
 
     return shard_map(local, mesh=mesh,
                      in_specs=(P(), P(None, axis), P(None, axis), P()),
-                     out_specs=P(), check_rep=False)(
+                     out_specs=P(), check_vma=False)(
                          q, k_cache, v_cache,
                          jnp.asarray(length, jnp.int32))

@@ -64,7 +64,6 @@ class EngineConfig:
     page_size: int = 16
     num_pages: Optional[int] = None   # None: num_slots * max_seq / page_size
     prompt_bucket_min: int = 8        # prefill pad bucket floor (pow2 above)
-    use_pallas: bool = False
     seed: int = 0
     # shared-prefix KV reuse (engine/prefix_cache.py, DESIGN.md §13):
     # admission maps cached full-page prompt blocks to existing pages
@@ -100,6 +99,14 @@ class EngineConfig:
     # inject into co-resident decodes. 0 = monolithic (the historical
     # behaviour); greedy outputs are bit-identical on/off (pinned).
     prefill_chunk_tokens: int = 0
+
+
+def kernels_on() -> bool:
+    """The platform picks the step path: the Pallas kernels on a TPU, the
+    plain-jnp references (`kernels/ref.py`) on the CPU. There is no user
+    option; tests replace this function to drive the interpret-mode
+    kernels through the engine."""
+    return jax.default_backend() == "tpu"
 
 
 def _bucket(n: int, lo: int) -> int:
@@ -286,12 +293,13 @@ class InferenceEngine:
         self._token_log: List[jnp.ndarray] = []        # [B] arrays, lazy
         # spec mode log: (tokens [B, W], counts [B]) per prefill/round
         self._spec_log: List = []
+        self._use_pallas = kernels_on()
         self._prefill_fn, self._decode_fn, self._tail_fn = _step_fns(
-            cfg, sampling, engine_cfg.use_pallas)
+            cfg, sampling, self._use_pallas)
         if self.spec and not self._spec_tree:
             from repro.engine.spec import spec_step_fns
             self._draft_fn, self._verify_fn = spec_step_fns(
-                cfg, sampling, engine_cfg.use_pallas, engine_cfg.spec_k,
+                cfg, sampling, self._use_pallas, engine_cfg.spec_k,
                 engine_cfg.spec_draft_layers)
 
     # -- API ----------------------------------------------------------------
@@ -701,7 +709,7 @@ class InferenceEngine:
                 # smallest reservation
                 fanout = (1,) * min(seg_la, self._tree_depth)
             draft_fn, verify_fn, tpl = tree_step_fns(
-                self.cfg, self.sampling, self.ecfg.use_pallas, fanout,
+                self.cfg, self.sampling, self._use_pallas, fanout,
                 self.ecfg.spec_draft_layers)
             k, width = tpl.depth, tpl.n_nodes + 1
             draft_dispatches = tpl.depth          # root + frontier calls
@@ -712,7 +720,7 @@ class InferenceEngine:
             else:
                 from repro.engine.spec import spec_step_fns
                 draft_fn, verify_fn = spec_step_fns(
-                    self.cfg, self.sampling, self.ecfg.use_pallas, k,
+                    self.cfg, self.sampling, self._use_pallas, k,
                     self.ecfg.spec_draft_layers)
             width = k + 1
             draft_dispatches = 1                  # one fused K-step call

@@ -139,7 +139,7 @@ class PagedKVCache:
     """Host-side manager of the device page pool.
 
     ``data`` is the device pytree from ``api.init_paged_cache`` (leaves
-    [L, P, page_size, ...]); it flows through the jitted prefill/decode
+    [L, KH, P, page_size, X]); it flows through the jitted prefill/decode
     calls functionally and is stored back here each iteration.
 
     With ``prefix_cache=True`` a radix :class:`PrefixCache` sits on top:
@@ -273,10 +273,10 @@ class PagedKVCache:
 
     def _copy_page(self, src: int, dst: int) -> None:
         """Device-copy one pool page (copy-on-write): every cache leaf is
-        [L, P, page_size, ...], so copy index ``src``->``dst`` along the
+        [L, KH, P, page_size, X], so copy index ``src``->``dst`` along the
         page axis in each leaf."""
         self.data = jax.tree_util.tree_map(
-            lambda leaf: leaf.at[:, dst].set(leaf[:, src]), self.data)
+            lambda leaf: leaf.at[:, :, dst].set(leaf[:, :, src]), self.data)
 
     def assign(self, slot: int, n_tokens: int,
                lookahead: Optional[int] = None, prompt=None) -> None:

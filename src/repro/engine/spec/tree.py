@@ -191,7 +191,7 @@ def compact_accepted(cache, block_tables, positions, path, n_new,
     valid = i < (n_new[:, None] - 1)               # accepted drafts only
     src_pos = positions[:, None] + jnp.maximum(path, 1)
     dst_pos = positions[:, None] + 1 + i
-    num_pages = jax.tree_util.tree_leaves(cache)[0].shape[1]
+    num_pages = jax.tree_util.tree_leaves(cache)[0].shape[2]
     src_page = jnp.take_along_axis(block_tables, src_pos // page_size,
                                    axis=1)
     src_off = src_pos % page_size
@@ -200,9 +200,9 @@ def compact_accepted(cache, block_tables, positions, path, n_new,
                                    axis=1), num_pages)
     dst_off = dst_pos % page_size
 
-    def move(buf):                                 # [L, P, ps, ...]
-        vals = buf[:, src_page, src_off]           # [L, B, D, ...]
-        return buf.at[:, dst_page, dst_off].set(vals)
+    def move(buf):                                 # [L, KH, P, ps, X]
+        vals = buf[:, :, src_page, src_off]        # [L, KH, B, D, X]
+        return buf.at[:, :, dst_page, dst_off].set(vals, mode="drop")
 
     return jax.tree_util.tree_map(move, cache)
 
@@ -233,10 +233,10 @@ def build_tree_verify_fn(cfg, api, sampling: SamplingParams,
             nxt = jnp.take_along_axis(
                 out, jnp.maximum(n_new - 1, 0)[:, None], axis=1)[:, 0]
             tokens = jnp.where(n_new > 0, nxt, tokens)
-            # leaves are [L, P, ps, ...] for every paged layout (K/V
+            # leaves are [L, KH, P, ps, X] for every paged layout (K/V
             # pools or the MLA latent pool) — compaction is the same
             # block-table move
-            page_size = jax.tree_util.tree_leaves(cache)[0].shape[2]
+            page_size = jax.tree_util.tree_leaves(cache)[0].shape[3]
             with jax.named_scope("tree_compact"):
                 cache = compact_accepted(cache, block_tables, positions,
                                          path, n_new, page_size)

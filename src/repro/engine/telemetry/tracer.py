@@ -31,10 +31,7 @@ import time
 from pathlib import Path
 from typing import Dict, List, Optional
 
-try:                                    # host-side device-trace annotation
-    from jax.profiler import TraceAnnotation as _TraceAnnotation
-except ImportError:                     # pragma: no cover - ancient jax
-    _TraceAnnotation = None
+from jax.profiler import TraceAnnotation
 
 # thread ids of the exported trace (one process, two logical tracks)
 TID_ENGINE = 0
@@ -187,10 +184,9 @@ class SpanTracer:
         """``jax.profiler.TraceAnnotation`` around a dispatch so device
         profiler traces carry the engine's phase names. No-op (shared
         null span, nothing constructed) unless device annotation is on."""
-        if not (self.enabled and self.annotate_device
-                and _TraceAnnotation is not None):
+        if not (self.enabled and self.annotate_device):
             return NULL_SPAN
-        return _TraceAnnotation(name)
+        return TraceAnnotation(name)
 
     # -- reading / export -----------------------------------------------
 

@@ -3,116 +3,116 @@
 GPU original: Stream-K work-centric decomposition over CTAs, gathering
 surviving INT4 groups and their activation slices. TPU adaptation (see
 DESIGN.md §2): the grid is a *1-D flattened work list* of equal-size
-(row-block, group-chunk) items built offline at pack time. Scalar-prefetched
+(row-block, slot-chunk) items built offline at pack time. Scalar-prefetched
 work arrays drive every BlockSpec index map, so each sequential grid step
-DMAs exactly one [BN, BM] tile of BSR payload — equal work per step means a
-bubble-free software pipeline, which is the TPU analogue of Stream-K's SM
-load balancing. Output tiles are revisited by consecutive items of the same
-row block and accumulated in VMEM (`first` flag zero-initializes).
+DMAs exactly one [BN, LANE] tile of BSR payload — equal work per step means
+a bubble-free software pipeline, which is the TPU analogue of Stream-K's SM
+load balancing.
 
-Layouts (padded BSR, see core/bsr.py):
-    x      [B, K]          activations (B <= 8 per chip in decode)
-    idx    [N, M]  int32   kept group columns (sorted; -1 pad)
-    vals   [N, M, G/2] u8  packed INT4 codes
-    scale  [N, M]  f32     0 on padding => padded slots contribute nothing
-    zero   [N, M]  f32
-    y      [B, N]
+The TPU has no cross-vreg gather, so the kernel never gathers activations.
+Each item instead *places* its dequantized slots at their group columns in
+a dense VMEM tile of the row block — a lane gather through the inverse
+index ``pos`` (slot of each column), one 128-lane vreg at a time — and the
+row block's last item multiplies that tile with the activations on the MXU.
+HBM traffic stays the compressed payload; the dense tile exists only in
+VMEM, one row block at a time.
+
+Layouts (core/bsr.py, built at pack time):
+    x      [T, G*Cp]          activations, group-column-major inside each
+                              code position: x[t, j*Cp + c] = x[t, c*G + j]
+    words  [G/8, Np, Mp] i32  eight 4-bit codes per word
+    scale  [Np, Mp] f32       0 on padding slots
+    zero   [Np, Mp] f32
+    pos    [Np, Cp] i32       slot of group column c, -1 where pruned
+    work   [4, W] i32         (row_block, chunk, first, last) per item
+    y      [T, Np] f32
 """
 from __future__ import annotations
 
 import functools
-from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+DEFAULT_BLOCK_T = 256   # activation rows per grid row (prefill is tiled)
+VMEM_LIMIT = 64 * 1024 * 1024
 
-DEFAULT_BLOCK_N = 128   # output rows per tile (lane dim)
-DEFAULT_BLOCK_M = 8     # group slots per work item
 
+def _kernel(work_ref,                                   # scalar prefetch
+            words_ref, scale_ref, zero_ref, pos_ref, x_ref,   # VMEM in
+            y_ref,                                      # VMEM out
+            wd_ref,                                     # scratch
+            *, group_size: int, lane: int, cp: int):
+    w = pl.program_id(1)
 
-def _kernel(row_block_ref, chunk_ref, first_ref,   # scalar prefetch
-            idx_ref, vals_ref, scale_ref, zero_ref, x_ref,  # VMEM in
-            y_ref,                                  # VMEM out (revisited)
-            *, group_size: int, batch: int):
-    w = pl.program_id(0)
-
-    @pl.when(first_ref[w] == 1)
+    @pl.when(work_ref[2, w] == 1)
     def _init():
-        y_ref[...] = jnp.zeros_like(y_ref)
+        wd_ref[...] = jnp.zeros_like(wd_ref)
 
-    bn, bm, _ = vals_ref.shape
-    g = group_size
+    base = work_ref[1, w] * lane            # first slot of this item's chunk
+    scale = scale_ref[...]
+    zero = zero_ref[...]
+    for j in range(group_size):
+        word = words_ref[j // 8]                              # [BN, LANE]
+        q = (word >> (4 * (j % 8))) & 0xF
+        wj = (q.astype(jnp.float32) - zero) * scale           # [BN, LANE]
+        for c0 in range(0, cp, lane):
+            rel = pos_ref[:, c0:c0 + lane] - base
+            hit = (rel >= 0) & (rel < lane)
+            got = jnp.take_along_axis(wj, jnp.clip(rel, 0, lane - 1),
+                                      axis=1)
+            col = j * cp + c0
+            wd_ref[:, col:col + lane] = jnp.where(
+                hit, got, wd_ref[:, col:col + lane])
 
-    # --- dequantize the INT4 payload tile ---------------------------------
-    packed = vals_ref[...]                       # [BN, BM, G/2] uint8
-    lo = (packed & 0xF).astype(jnp.float32)
-    hi = ((packed >> 4) & 0xF).astype(jnp.float32)
-    q = jnp.stack([lo, hi], axis=-1).reshape(bn, bm, g)
-    wt = (q - zero_ref[...][..., None]) * scale_ref[...][..., None]
-
-    # --- gather the matching activation groups ----------------------------
-    x = x_ref[...]                               # [B, K]
-    k = x.shape[-1]
-    xg = x.reshape(batch, k // g, g)
-    safe = jnp.maximum(idx_ref[...], 0).reshape(-1)          # [BN*BM]
-    # NOTE(tpu): 1-D take lowers to Mosaic dynamic-gather; the MXU-friendly
-    # fallback is a one-hot [BN*BM, K/G] matmul against xg.
-    xt = jnp.take(xg, safe, axis=1)              # [B, BN*BM, G]
-    xt = xt.reshape(batch, bn, bm, g)
-
-    # --- multiply-reduce on the VPU (decode is bandwidth-bound; no MXU) ---
-    acc = jnp.sum(wt[None, ...] * xt.astype(jnp.float32), axis=(2, 3))
-    y_ref[...] += acc.astype(y_ref.dtype)        # [B, BN]
+    @pl.when(work_ref[3, w] == 1)
+    def _matmul():
+        x = x_ref[...]
+        y_ref[...] = jax.lax.dot_general(
+            x, wd_ref[...].astype(x.dtype), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
 
-def gqsa_gemv_pallas(
-    x: jnp.ndarray,
-    idx: jnp.ndarray,
-    vals: jnp.ndarray,
-    scale: jnp.ndarray,
-    zero: jnp.ndarray,
-    work: Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray],
-    *,
-    group_size: int,
-    block_n: int = DEFAULT_BLOCK_N,
-    block_m: int = DEFAULT_BLOCK_M,
-    interpret: bool = False,
-) -> jnp.ndarray:
-    """Inputs must be pre-padded: N % block_n == 0, M % block_m == 0.
-
-    work = (row_block[W], chunk[W], first[W]) from core.bsr.build_work_list
-    (items sorted by row_block so output revisits are consecutive).
+def gqsa_gemv_pallas(x: jnp.ndarray, words: jnp.ndarray, scale: jnp.ndarray,
+                     zero: jnp.ndarray, pos: jnp.ndarray, work: jnp.ndarray,
+                     *, group_size: int, block_n: int, lane: int,
+                     block_t: int, interpret: bool = False) -> jnp.ndarray:
+    """x: [T, G*Cp] in the layout above, T % block_t == 0. Returns
+    [T, Np] f32. Items of one row block are consecutive in ``work``, so
+    the output tile stays resident in VMEM until its last item writes it.
     """
-    b, k = x.shape
-    n, m = idx.shape
-    row_block, chunk, first = work
-    n_items = row_block.shape[0]
-    g = group_size
-
+    t, kp = x.shape
+    np_, mp = scale.shape
+    cp = pos.shape[1]
+    n_words = words.shape[0]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(n_items,),
+        num_scalar_prefetch=1,
+        grid=(t // block_t, work.shape[1]),
         in_specs=[
-            pl.BlockSpec((block_n, block_m),
-                         lambda w, rb, ch, fs: (rb[w], ch[w])),
-            pl.BlockSpec((block_n, block_m, g // 2),
-                         lambda w, rb, ch, fs: (rb[w], ch[w], 0)),
-            pl.BlockSpec((block_n, block_m),
-                         lambda w, rb, ch, fs: (rb[w], ch[w])),
-            pl.BlockSpec((block_n, block_m),
-                         lambda w, rb, ch, fs: (rb[w], ch[w])),
-            pl.BlockSpec((b, k), lambda w, rb, ch, fs: (0, 0)),
+            pl.BlockSpec((n_words, block_n, lane),
+                         lambda i, w, wk: (0, wk[0, w], wk[1, w])),
+            pl.BlockSpec((block_n, lane),
+                         lambda i, w, wk: (wk[0, w], wk[1, w])),
+            pl.BlockSpec((block_n, lane),
+                         lambda i, w, wk: (wk[0, w], wk[1, w])),
+            pl.BlockSpec((block_n, cp), lambda i, w, wk: (wk[0, w], 0)),
+            pl.BlockSpec((block_t, kp), lambda i, w, wk: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((b, block_n),
-                               lambda w, rb, ch, fs: (0, rb[w])),
+        out_specs=pl.BlockSpec((block_t, block_n),
+                               lambda i, w, wk: (i, wk[0, w])),
+        scratch_shapes=[pltpu.VMEM((block_n, kp), jnp.float32)],
     )
-    kernel = functools.partial(_kernel, group_size=g, batch=b)
+    kernel = functools.partial(_kernel, group_size=group_size, lane=lane,
+                               cp=cp)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, n), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((t, np_), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
-    )(row_block, chunk, first, idx, vals, scale, zero, x)
+        name="gqsa_gemv",
+    )(work, words, scale, zero, pos, x)

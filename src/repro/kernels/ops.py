@@ -1,28 +1,32 @@
-"""Public kernel entry points: padding, work-list plumbing, CPU fallback.
+"""Public kernel entry points: activation padding and layout plumbing.
 
-``use_pallas`` selects the Pallas kernel (interpret=True off-TPU) vs the
-pure-jnp reference (the GSPMD/dry-run path — identical math).
+Every entry point runs its Pallas kernel: compiled on the TPU, interpreted
+on the CPU (the only platform where interpret mode runs). The weights and
+KV pools arrive in the layouts the kernels read — built at pack time
+(core/bsr.py, core/gqs_layer.py:pack_w4) or by ``init_paged_cache`` — so
+per call only activations are padded or permuted. The pure-jnp oracles
+live in kernels/ref.py; choosing between them and these kernels is the
+model's ``use_pallas`` switch, never this module's.
 """
 from __future__ import annotations
 
-import functools
-from typing import Optional
-
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from repro.core.bsr import BSRMatrix, build_work_list
-from repro.kernels import ref as kref
-from repro.kernels.gqsa_gemv import (gqsa_gemv_pallas, DEFAULT_BLOCK_N,
-                                     DEFAULT_BLOCK_M)
+from repro.core.bsr import BSRMatrix
+from repro.kernels.gqsa_gemv import gqsa_gemv_pallas
+from repro.kernels.gqsa_gemv import DEFAULT_BLOCK_T as DEFAULT_GEMV_BLOCK_T
+from repro.kernels.paged_attention import paged_attention_pallas
 from repro.kernels.w4_matmul import (w4_matmul_pallas, DEFAULT_BLOCK_T,
-                                     DEFAULT_BLOCK_K)
-from repro.kernels.w4_matmul import DEFAULT_BLOCK_N as W4_BLOCK_N
+                                     DEFAULT_BLOCK_N, DEFAULT_BLOCK_R)
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret() -> bool:
+    """Kernels compile for the TPU; only the CPU runs them interpreted."""
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(f"no Pallas path for backend {backend!r}")
+    return backend == "cpu"
 
 
 def _pad_to(x: jnp.ndarray, axis: int, mult: int, value=0) -> jnp.ndarray:
@@ -35,93 +39,60 @@ def _pad_to(x: jnp.ndarray, axis: int, mult: int, value=0) -> jnp.ndarray:
     return jnp.pad(x, widths, constant_values=value)
 
 
-# The GEMV kernel keeps ALL of x resident in VMEM per grid step and sizes
-# its accumulator tile [B, BN] for decode batches — one sublane tile. The
-# engine's speculative verify step flattens [slots, K+1] token rows into
-# the batch dim, so B routinely exceeds this; larger batches are chunked
-# explicitly rather than silently mis-tiled.
-MAX_GEMV_BATCH = 8
+def _row_tile(t: int, cap: int) -> int:
+    """Rows per grid step: all of them up to ``cap`` (sublane-rounded),
+    else ``cap`` — the caller pads T to a multiple of the result."""
+    return min(cap, -(-t // 8) * 8)
 
 
-def gqsa_gemv(
-    x: jnp.ndarray,
-    bsr: BSRMatrix,
-    *,
-    use_pallas: bool = True,
-    block_n: int = DEFAULT_BLOCK_N,
-    block_m: int = DEFAULT_BLOCK_M,
-    interpret: Optional[bool] = None,
-) -> jnp.ndarray:
-    """y = x @ dense(bsr).T using the task-centric sparse kernel.
+def gqsa_gemv(x: jnp.ndarray, bsr: BSRMatrix) -> jnp.ndarray:
+    """y = x @ dense(bsr).T with the task-centric sparse kernel.
 
-    x: [B, K], any B: rows are padded to the sublane multiple and batches
-    beyond MAX_GEMV_BATCH are chunked over the kernel (the BSR payload
-    pads and the work list build happen once, shared by every chunk).
-    Returns [B, N].
+    x: [T, K], any T (decode slots, verify rows or a prefill block: rows
+    beyond DEFAULT_BLOCK_T tile the kernel's grid). The packed operands
+    are used as laid out at pack time; only the activations are permuted
+    to the kernel's group-column-major order. Returns [T, N] f32.
     """
-    if not use_pallas:
-        return kref.gqsa_gemv_ref(x, bsr)
-    if interpret is None:
-        interpret = not _on_tpu()
-
-    b, k = x.shape
-    n, m = bsr.idx.shape
-
-    idx = _pad_to(_pad_to(bsr.idx, 0, block_n, value=-1), 1, block_m, value=-1)
-    vals = _pad_to(_pad_to(bsr.vals, 0, block_n), 1, block_m)
-    scale = _pad_to(_pad_to(bsr.scale, 0, block_n), 1, block_m)
-    zero = _pad_to(_pad_to(bsr.zero, 0, block_n), 1, block_m)
-    wl = build_work_list(idx, block_n, block_m)
-
-    def run(xc: jnp.ndarray) -> jnp.ndarray:
-        bc = xc.shape[0]
-        y = gqsa_gemv_pallas(
-            _pad_to(xc, 0, MAX_GEMV_BATCH), idx, vals, scale, zero,
-            (wl.row_block, wl.chunk, wl.first),
-            group_size=bsr.group_size, block_n=block_n, block_m=block_m,
-            interpret=interpret)
-        return y[:bc, :n]
-
-    if b <= MAX_GEMV_BATCH:
-        return run(x)
-    return jnp.concatenate([run(x[i:i + MAX_GEMV_BATCH])
-                            for i in range(0, b, MAX_GEMV_BATCH)], axis=0)
-
-
-def w4_matmul(
-    x: jnp.ndarray,
-    qw: jnp.ndarray,
-    scale: jnp.ndarray,
-    zero: jnp.ndarray,
-    *,
-    group_size: int,
-    use_pallas: bool = True,
-    block_t: int = DEFAULT_BLOCK_T,
-    block_n: int = W4_BLOCK_N,
-    block_k: int = DEFAULT_BLOCK_K,
-    interpret: Optional[bool] = None,
-) -> jnp.ndarray:
-    """y = x @ deq(qw).T (dense grouped-dequant). x: [T, K] -> [T, N]."""
-    if not use_pallas:
-        return kref.w4_matmul_ref(x, qw, scale, zero, group_size)
-    if interpret is None:
-        interpret = not _on_tpu()
-
     t, k = x.shape
-    n = qw.shape[0]
-    block_t = min(block_t, max(8, int(np.ceil(t / 8)) * 8))
-    block_k = min(block_k, k) if k % group_size == 0 else block_k
-    if block_k % group_size != 0 or k % block_k != 0:
-        # fall back: single K block (K is a multiple of G by construction)
-        block_k = k
-    xp = _pad_to(_pad_to(x, 0, block_t), 1, block_k)
-    qwp = _pad_to(_pad_to(qw, 0, block_n), 1, block_k // 2)
-    sp = _pad_to(_pad_to(scale, 0, block_n), 1, block_k // group_size)
-    zp = _pad_to(_pad_to(zero, 0, block_n), 1, block_k // group_size)
-    y = w4_matmul_pallas(xp, qwp, sp, zp, group_size=group_size,
-                         block_t=block_t, block_n=block_n, block_k=block_k,
-                         interpret=interpret)
+    n = bsr.shape[0]
+    g = bsr.group_size
+    cp = bsr.pos.shape[-1]
+    xg = x.reshape(t, k // g, g).transpose(0, 2, 1)           # [T, G, C]
+    xg = _pad_to(xg, 2, cp).reshape(t, g * cp)
+    bt = _row_tile(t, DEFAULT_GEMV_BLOCK_T)
+    y = gqsa_gemv_pallas(_pad_to(xg, 0, bt), bsr.words, bsr.scale, bsr.zero,
+                         bsr.pos, bsr.work, group_size=g,
+                         block_n=bsr.block_n, lane=bsr.lane, block_t=bt,
+                         interpret=_interpret())
     return y[:t, :n]
+
+
+def _block_r(rows: int, group_size: int) -> int:
+    """Word rows per K step: the largest multiple of the (8, 128) tile
+    unit that divides ``rows`` and is at most DEFAULT_BLOCK_R, else all."""
+    unit = max(128, 8 * group_size)
+    for br in range(DEFAULT_BLOCK_R - DEFAULT_BLOCK_R % unit, 0, -unit):
+        if rows % br == 0:
+            return br
+    return rows
+
+
+def w4_matmul(x: jnp.ndarray, qw: jnp.ndarray, scale: jnp.ndarray,
+              zero: jnp.ndarray) -> jnp.ndarray:
+    """y = x @ deq(qw).T for the plane-packed W4 layout of
+    ``kernels/w4_matmul.py``. x: [T, K] -> [T, N] f32."""
+    t, k = x.shape
+    rows, n = qw.shape                       # rows = Kp / 8
+    g = rows // scale.shape[1]
+    xp = jnp.pad(x, ((0, 0), (0, 8 * rows - k)))
+    bt = _row_tile(t, DEFAULT_BLOCK_T)
+    xp = _pad_to(xp, 0, bt)
+    x3 = xp.reshape(-1, 8, rows).transpose(1, 0, 2)          # [8, Tp, Kp/8]
+    bn = DEFAULT_BLOCK_N if n % DEFAULT_BLOCK_N == 0 else n
+    y = w4_matmul_pallas(x3, qw, scale, zero, group_size=g, block_t=bt,
+                         block_n=bn, block_r=_block_r(rows, g),
+                         interpret=_interpret())
+    return y[:t]
 
 
 def gemv_bytes_model(bsr: BSRMatrix, batch: int = 1) -> dict:
@@ -130,7 +101,7 @@ def gemv_bytes_model(bsr: BSRMatrix, batch: int = 1) -> dict:
     (paper/gguf convention: int16 group index, fp16 scale, u8 zero —
     the padded in-memory form above uses wider dev-side types)."""
     n, k = bsr.shape
-    m = bsr.idx.shape[1]
+    m = bsr.m
     g = bsr.group_size
     payload = n * m * (g * bsr.bits // 8 + 2 + 2 + 1)
     x_bytes = batch * k * 2           # bf16 activations
@@ -170,123 +141,93 @@ def _paged_query_prep(lengths, block_tables, b: int, t: int,
 
 def paged_decode_attention(q, k_pages, v_pages, lengths, block_tables,
                            k_scale_pages=None, v_scale_pages=None, *,
-                           anc=None, anc_base=None, anc_window: int = 0,
-                           use_pallas: bool = True,
-                           interpret: Optional[bool] = None):
+                           anc=None, anc_base=None, anc_window: int = 0):
     """Fused decode attention directly on the paged KV pool.
 
     q: [B, T, H, D] (T=1 continuous-batching decode; T=K+1 speculative
-    verify); k/v_pages: [P, ps, KH, D] (bf16/f32, or int8 with f32
-    [P, ps, KH] scale pages); lengths: [] / [B] / [B, T] per-query valid
-    prefix (the multi-token staircase); block_tables: [B, MP] page ids,
-    entries >= P are out-of-range sentinels. Returns [B, T, H, D] f32.
+    verify); k/v_pages: [KH, P, ps, D] head-major (bf16/f32, or int8 with
+    f32 [KH, P, ps, 1] scale pages); lengths: [] / [B] / [B, T] per-query
+    valid prefix (the multi-token staircase); block_tables: [B, MP] page
+    ids, entries >= P are out-of-range sentinels. Returns [B, T, H, D] f32.
 
     ``anc`` [B, T] / ``anc_base`` [B] / ``anc_window`` switch the fed
     block to token-TREE semantics (`models/layers.py:ancestor_mask`):
     query t additionally needs bit ``s - anc_base[b]`` of ``anc[b, t]``
     for cache positions inside the fed window.
 
-    The Pallas path streams only each slot's live pages through VMEM —
-    O(live tokens) HBM traffic; the jnp path is the dense-gather
-    reference (`kernels/ref.py:paged_attention_ref` /
-    `tree_attention_ref`, identical math).
+    The kernel streams only each slot's live pages through VMEM —
+    O(live tokens) HBM traffic; the dense-gather oracle is
+    `kernels/ref.py:paged_attention_ref` (identical math).
     """
-    if not use_pallas:
-        return kref.paged_attention_ref(q, k_pages, v_pages, lengths,
-                                        block_tables, k_scale_pages,
-                                        v_scale_pages, anc=anc,
-                                        anc_base=anc_base,
-                                        anc_window=anc_window)
-    if interpret is None:
-        interpret = not _on_tpu()
-    from repro.kernels.paged_attention import paged_attention_pallas
     b, t, h, d = q.shape
-    page_size = k_pages.shape[1]
-    khn = k_pages.shape[2]
+    page_size = k_pages.shape[2]
+    khn = k_pages.shape[0]
     r = h // khn
     lq, live = _paged_query_prep(lengths, block_tables, b, t, page_size)
-    # kernel row layout: [B, KH, T*R, D], T-major inside the row dim
+    # kernel row layout: [B, KH, T*R, D], T-major inside the row dim,
+    # padded to the sublane multiple (padding rows are fully masked)
     qh = q.reshape(b, t, khn, r, d).transpose(0, 2, 1, 3, 4) \
           .reshape(b, khn, t * r, d)
-    o = paged_attention_pallas(qh, k_pages, v_pages, lq, block_tables,
-                               live, k_scale_pages, v_scale_pages,
-                               t=t, anc=anc, anc_base=anc_base,
-                               anc_window=anc_window, interpret=interpret)
-    return o.reshape(b, khn, t, r, d).transpose(0, 2, 1, 3, 4) \
+    o = paged_attention_pallas(_pad_to(qh, 2, 8), k_pages, v_pages, lq,
+                               block_tables, live, k_scale_pages,
+                               v_scale_pages, t=t, r=r, anc=anc,
+                               anc_base=anc_base, anc_window=anc_window,
+                               interpret=_interpret())
+    return o[:, :, :t * r].reshape(b, khn, t, r, d).transpose(0, 2, 1, 3, 4) \
             .reshape(b, t, h, d)
 
 
 def paged_latent_attention(q, lat_pages, lengths, block_tables, *,
                            v_rank: int, anc=None, anc_base=None,
-                           anc_window: int = 0, use_pallas: bool = True,
-                           interpret: Optional[bool] = None):
+                           anc_window: int = 0):
     """Fused decode attention on the paged MLA LATENT pool (DESIGN.md §9).
 
     q: [B, T, H, R + rope] absorbed-W_UK queries, PRE-SCALED by
     sqrt(fake/true) (`models/mla.py:_absorbed_q` — the kernel divides by
-    sqrt(R + rope)); lat_pages: [P, ps, R + rope] — one logical KV head,
-    post-norm c_kv ++ post-RoPE k_rope per token; lengths / block_tables
-    / anc semantics exactly as :func:`paged_decode_attention`. Returns
-    the latent context [B, T, H, v_rank] f32: the value of a cached
-    token is the leading ``v_rank`` (= kv_lora_rank) dims of its latent
-    row — there is no V pool, and W_UV is applied by the caller AFTER
-    attention.
+    sqrt(R + rope)); lat_pages: [1, P, ps, R + rope] — one logical KV
+    head, post-norm c_kv ++ post-RoPE k_rope per token; lengths /
+    block_tables / anc semantics exactly as :func:`paged_decode_attention`.
+    Returns the latent context [B, T, H, v_rank] f32: the value of a
+    cached token is the leading ``v_rank`` (= kv_lora_rank) dims of its
+    latent row — there is no V pool, and W_UV is applied by the caller
+    AFTER attention.
 
-    The Pallas path shares the scalar-prefetch/block-table machinery of
-    the GQA kernel (``v_pages=None`` latent mode: V = K pages, lane-dim
-    tiled scores for R + rope > 128) and computes the full R + rope
-    value columns (sliced here — column independence makes the leading
-    dims identical); the jnp path is the dense-gather reference
-    (`kernels/ref.py:paged_latent_attention_ref`).
+    The kernel shares the scalar-prefetch/block-table machinery of the
+    GQA mode (``v_pages=None`` latent mode: V = K pages) and computes the
+    full R + rope value columns (sliced here — column independence makes
+    the leading dims identical); the dense-gather oracle is
+    `kernels/ref.py:paged_latent_attention_ref`.
     """
-    if not use_pallas:
-        return kref.paged_latent_attention_ref(
-            q, lat_pages, lengths, block_tables, v_rank, anc=anc,
-            anc_base=anc_base, anc_window=anc_window)
-    if interpret is None:
-        interpret = not _on_tpu()
-    from repro.kernels.paged_attention import paged_attention_pallas
     b, t, h, d = q.shape
-    page_size = lat_pages.shape[1]
+    page_size = lat_pages.shape[2]
     lq, live = _paged_query_prep(lengths, block_tables, b, t, page_size)
     # kernel row layout: [B, KH=1, T*H, D], T-major inside the row dim
-    qh = q.reshape(b, t * h, d)[:, None]
-    o = paged_attention_pallas(qh, lat_pages[:, :, None, :], None, lq,
-                               block_tables, live, t=t, anc=anc,
-                               anc_base=anc_base, anc_window=anc_window,
-                               interpret=interpret)
-    return o.reshape(b, t, h, d)[..., :v_rank]
+    qh = _pad_to(q.reshape(b, t * h, d)[:, None], 2, 8)
+    o = paged_attention_pallas(qh, lat_pages, None, lq, block_tables, live,
+                               t=t, r=h, anc=anc, anc_base=anc_base,
+                               anc_window=anc_window, interpret=_interpret())
+    return o[:, 0, :t * h].reshape(b, t, h, d)[..., :v_rank]
 
 
 def kv_decode_attention(q, k_cache, k_scale, v_cache, v_scale, length, *,
-                        use_pallas: bool = True, block_s: int = 512,
-                        interpret: Optional[bool] = None):
+                        block_s: int = 512):
     """int8-KV decode attention over a *contiguous* cache — the degenerate
     one-page-table case of the paged kernel: the [B, S, ...] cache is
     viewed as B*ceil(S/block_s) pages of ``block_s`` tokens with identity
-    block tables (no data movement beyond the pad). q: [B, KH, R, D] ->
-    [B, KH, R, D] f32."""
-    if not use_pallas:
-        return kref.kv_decode_attention_ref(q, k_cache, k_scale, v_cache,
-                                            v_scale, length)
+    block tables. q: [B, KH, R, D] -> [B, KH, R, D] f32."""
     b, khn, r, d = q.shape
     s = k_cache.shape[1]
     block_s = min(block_s, s)
-    pad = (-s) % block_s
-    if pad:
-        k_cache = jnp.pad(k_cache, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        v_cache = jnp.pad(v_cache, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        k_scale = jnp.pad(k_scale, ((0, 0), (0, pad), (0, 0)))
-        v_scale = jnp.pad(v_scale, ((0, 0), (0, pad), (0, 0)))
-    npg = (s + pad) // block_s
+    npg = -(-s // block_s)
 
-    def pages(buf):                      # [B, S, ...] -> [B*NP, bs, ...]
-        return buf.reshape((b * npg, block_s) + buf.shape[2:])
+    def pages(buf):                      # [B, S, KH, ...] -> [KH, B*NP, bs, ...]
+        buf = _pad_to(buf, 1, block_s)
+        buf = buf.reshape((b * npg, block_s) + buf.shape[2:])
+        return jnp.moveaxis(buf, 2, 0)
 
     bt = jnp.arange(b * npg, dtype=jnp.int32).reshape(b, npg)
     o = paged_decode_attention(
         q.reshape(b, 1, khn * r, d), pages(k_cache), pages(v_cache),
         jnp.broadcast_to(jnp.reshape(length, (-1,)), (b,)), bt,
-        pages(k_scale), pages(v_scale), use_pallas=True,
-        interpret=interpret)
+        pages(k_scale[..., None]), pages(v_scale[..., None]))
     return o.reshape(b, khn, r, d)

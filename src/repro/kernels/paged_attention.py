@@ -7,14 +7,17 @@ each step — O(B * max_pages) HBM traffic regardless of how long the
 sequences actually are. This kernel streams only the *live* pages of
 each slot through VMEM, so per-step attention traffic is O(live tokens).
 
-Layouts (one layer's view of the pool):
-    q             [B, KH, T*R, D]      query rows grouped by KV head,
-                                       T-major inside the row dim
-    k/v_pages     [P, ps, KH, D]       bf16/f32, or int8 with
-    k/v_scale     [P, ps, KH] f32      per-token x head scales
+Layouts (one layer's view of the pool, head-major so each grid step
+DMAs one tileable [ps, D] (head, page) tile):
+    q             [B, KH, TRp, D]      query rows grouped by KV head,
+                                       T-major inside the row dim, padded
+                                       from T*R to the sublane multiple
+    k/v_pages     [KH, P, ps, D]       bf16/f32, or int8 with
+    k/v_scale     [KH, P, ps, 1] f32   per-token x head scales
     lengths       [B, T] int32         per-query valid prefix (staircase:
                                        query t of a slot sees cache
-                                       positions < lengths[b, t])
+                                       positions < lengths[b, t]); read
+                                       from SMEM
     block_tables  [B, MP] int32        page ids; entries >= P are
                                        out-of-range sentinels
     live          [B] int32            number of live pages per slot
@@ -54,25 +57,19 @@ Token-TREE verification (DESIGN.md §8) adds an optional ancestor-bitmap
 operand: the fed block is a flat BFS token tree written at cache
 positions ``base .. base + window - 1``, and query t additionally
 requires bit ``s - base`` of ``anc[b, t]`` for cache positions inside
-that window — siblings/uncles in the block stay invisible. ``base`` [B]
-rides the scalar-prefetch path next to the block tables; ``anc`` [B, T]
-is a VMEM row operand like ``lengths``. With ``anc`` absent the compiled
-kernel is UNCHANGED (the staircase is the chain special case —
-`models/layers.py:ancestor_mask` is the shared mask definition).
+that window — siblings/uncles in the block stay invisible. ``anc``
+[B, T] and ``base`` [B] ride the scalar-prefetch path next to the block
+tables and ``lengths``; each query row's length and bitmap are selected
+from SMEM scalars (row i belongs to query i // R). With ``anc`` absent
+the compiled kernel is UNCHANGED (the staircase is the chain special
+case — `models/layers.py:ancestor_mask` is the shared mask definition).
 
 MLA LATENT pages (DESIGN.md §9) are the ``v_pages=None`` mode: the pool
 holds one [ps, kv_lora_rank + qk_rope_dim] latent row per token (a
-single logical KV head), the value operand IS the key page (no V pool —
-callers up-project through W_UV after slicing the leading R dims of the
-output), and the score contraction is lane-dim tiled: R + rope = 576 at
-DeepSeek scale exceeds one 128-lane MXU tile, so the dot runs as a
-statically unrolled sum of 128-wide partial products. With ``v_pages``
-present and D <= 128 the compiled kernel is UNCHANGED.
-
-Rows (T*R) and D are used as-is — adequate for interpret mode (the
-repo's off-TPU convention) and for MXU-friendly head dims; a deployment
-at exotic head dims should pad rows to the sublane multiple in
-``ops.paged_decode_attention``.
+single logical KV head, [1, P, ps, Dl]), the value operand IS the key
+page (no V pool — callers up-project through W_UV after slicing the
+leading R dims of the output). The 576-lane latent row is one full-width
+block; Mosaic tiles its contraction.
 """
 from __future__ import annotations
 
@@ -82,8 +79,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(bt_ref, live_ref,                       # scalar prefetch
-            len_ref, q_ref, k_ref, v_ref,           # VMEM in (bf16/f32)
+def _kernel(bt_ref, live_ref, len_ref,               # scalar prefetch
+            q_ref, k_ref, v_ref,                    # VMEM in (bf16/f32)
             o_ref,                                  # VMEM out
             m_ref, l_ref, acc_ref,                  # scratch
             *, page_size: int, t: int, r: int,
@@ -106,50 +103,40 @@ def _kernel(bt_ref, live_ref,                       # scalar prefetch
     # pages at/above the live count were not (re)fetched — skip compute
     @pl.when(pi < live_ref[bi])
     def _update():
-        q = q_ref[0, 0].astype(jnp.float32)          # [TR, D]
-        d = q.shape[-1]
+        q = q_ref[0, 0].astype(jnp.float32)          # [TRp, D]
+        trp, d = q.shape
         scale = 1.0 / jnp.sqrt(d).astype(jnp.float32)
 
         # dequantize this page tile in VMEM (HBM traffic stays int8)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)    # [ps, D]
-        v = k if v_ref is None else v_ref[0, :, 0, :].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)          # [ps, D]
+        v = k if v_ref is None else v_ref[0, 0].astype(jnp.float32)
         if ks_ref is not None:
-            k = k * ks_ref[0, :, 0][:, None]
-            v = v * vs_ref[0, :, 0][:, None]
+            k = k * ks_ref[0, 0]                     # [ps, 1] per token
+            v = v * vs_ref[0, 0]
+        sco = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale   # [TRp, ps]
 
-        def qk_dot(qc, kc):
-            return jax.lax.dot_general(
-                qc, kc, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)          # [TR, ps]
-
-        if d > 128:
-            # lane-dim tiling: the MLA latent head (kv_lora_rank +
-            # qk_rope_dim = 576 at DeepSeek scale) exceeds one 128-lane
-            # MXU tile, so the contraction runs as a statically unrolled
-            # sum of 128-wide partial dots (the trailing ragged chunk is
-            # narrower; Mosaic pads it). d <= 128 keeps the single-dot
-            # program every pre-existing caller compiled to.
-            sco = qk_dot(q[:, :128], k[:, :128])
-            for lo in range(128, d, 128):
-                sco += qk_dot(q[:, lo:lo + 128], k[:, lo:lo + 128])
-            sco *= scale
-        else:
-            sco = qk_dot(q, k) * scale
-
-        # per-query staircase mask: query t sees positions < lengths[b, t]
-        pos = pi * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (t, page_size), 1)
-        lq = len_ref[0]                              # [T]
-        valid = pos < lq[:, None]                    # [T, ps]
+        # per-query staircase mask: query t sees positions < lengths[b, t].
+        # Row i of the tile is query i // r; rows past T*r are sublane
+        # padding and stay fully masked (length 0).
+        shape = (trp, page_size)
+        row_t = jax.lax.broadcasted_iota(jnp.int32, shape, 0) // r
+        pos = pi * page_size + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        lq = jnp.zeros(shape, jnp.int32)
+        for tt in range(t):
+            lq = jnp.where(row_t == tt, len_ref[bi * t + tt], lq)
+        valid = pos < lq
         if anc_ref is not None:
             # token-tree window: positions base..base+window-1 hold the
             # fed BFS block; query t sees only its ancestor bits there
-            fed = pos - base_ref[bi]                 # [T, ps]
+            aq = jnp.zeros(shape, jnp.int32)
+            for tt in range(t):
+                aq = jnp.where(row_t == tt, anc_ref[bi * t + tt], aq)
+            fed = pos - base_ref[bi]
             in_win = (fed >= 0) & (fed < window)
-            bits = (anc_ref[0][:, None] >> jnp.clip(fed, 0, 31)) & 1
+            bits = (aq >> jnp.clip(fed, 0, 31)) & 1
             valid &= jnp.logical_not(in_win) | (bits == 1)
-        valid = jnp.broadcast_to(valid[:, None, :],
-                                 (t, r, page_size)).reshape(t * r, page_size)
         sco = jnp.where(valid, sco, -jnp.inf)
 
         m_old = m_ref[:, 0]
@@ -179,31 +166,31 @@ def _kernel(bt_ref, live_ref,                       # scalar prefetch
 
 
 def paged_attention_pallas(
-    q: jnp.ndarray,            # [B, KH, T*R, D]
-    k_pages: jnp.ndarray,      # [P, ps, KH, D] bf16/f32/int8
+    q: jnp.ndarray,            # [B, KH, TRp, D]
+    k_pages: jnp.ndarray,      # [KH, P, ps, D] bf16/f32/int8
     v_pages: jnp.ndarray,
     lengths: jnp.ndarray,      # [B, T] int32 per-query valid prefix
     block_tables: jnp.ndarray,  # [B, MP] int32 (>= P entries = sentinel)
     live_pages: jnp.ndarray,   # [B] int32 live page count per slot
-    k_scale_pages=None,        # [P, ps, KH] f32 (int8 pages only)
+    k_scale_pages=None,        # [KH, P, ps, 1] f32 (int8 pages only)
     v_scale_pages=None,
     *,
     t: int,
+    r: int,
     anc=None,                  # [B, T] int32 ancestor bitmaps (tree verify)
     anc_base=None,             # [B] int32 cache position of the tree root
     anc_window: int = 0,       # fed-block width (bits used in anc)
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """Returns [B, KH, T*R, D] f32. See module docstring for semantics.
+    """Returns [B, KH, TRp, D] f32. See module docstring for semantics.
 
     ``v_pages=None`` selects the LATENT mode (MLA latent pool, one
     logical KV head): the value operand is the key page itself, so each
     grid step DMAs one pool page instead of two; callers slice the
     leading ``kv_lora_rank`` dims of the output
     (`ops.paged_latent_attention`)."""
-    b, khn, tr, d = q.shape
-    r = tr // t
-    num_pages, page_size = k_pages.shape[0], k_pages.shape[1]
+    b, khn, trp, d = q.shape
+    num_pages, page_size = k_pages.shape[1], k_pages.shape[2]
     mp = block_tables.shape[1]
     int8 = k_scale_pages is not None
     tree = anc is not None
@@ -213,53 +200,37 @@ def paged_attention_pallas(
                                   "follow-on (ROADMAP)")
     grid = (b, khn, mp)
 
-    # index maps take the scalar-prefetch operands after the grid ids; the
-    # tree variant prefetches a third array (the per-slot window base), so
-    # trailing prefetch args are absorbed generically
+    # index maps take the scalar-prefetch operands after the grid ids
+    # (block tables, live counts, lengths[, anc, base])
     def page_map(bi, ki, pi, bt, live, *_):
         # steps past the live prefix re-map to the last live page so the
         # block index is unchanged and Pallas elides the DMA; sentinel
         # entries clamp to P - 1 (== XLA's OOB-gather clip)
         pe = jnp.minimum(pi, jnp.maximum(live[bi] - 1, 0))
-        return (jnp.minimum(bt[bi, pe], num_pages - 1), 0, ki, 0)
-
-    def scale_map(bi, ki, pi, bt, live, *_):
-        pe = jnp.minimum(pi, jnp.maximum(live[bi] - 1, 0))
-        return (jnp.minimum(bt[bi, pe], num_pages - 1), 0, ki)
+        return (ki, jnp.minimum(bt[bi, pe], num_pages - 1), 0, 0)
 
     def row_map(bi, ki, pi, *_):
         return (bi, ki, 0, 0)
 
-    def len_map(bi, ki, pi, *_):
-        return (bi, 0)
-
     prefetch = [block_tables.astype(jnp.int32),
-                live_pages.astype(jnp.int32)]
+                live_pages.astype(jnp.int32),
+                lengths.astype(jnp.int32).reshape(-1)]
     if tree:
-        prefetch.append(anc_base.astype(jnp.int32))
-    in_specs = [pl.BlockSpec((1, t), len_map)]
-    args = [lengths.astype(jnp.int32)]
-    if tree:
-        in_specs.append(pl.BlockSpec((1, t), len_map))
-        args.append(anc.astype(jnp.int32))
-    in_specs += [pl.BlockSpec((1, 1, tr, d), row_map),
-                 pl.BlockSpec((1, page_size, 1, d), page_map)]
-    args += [q, k_pages]
+        prefetch += [anc.astype(jnp.int32).reshape(-1),
+                     anc_base.astype(jnp.int32)]
+    page_spec = pl.BlockSpec((1, 1, page_size, d), page_map)
+    in_specs = [pl.BlockSpec((1, 1, trp, d), row_map), page_spec]
+    args = [q, k_pages]
     if not latent:
-        in_specs.append(pl.BlockSpec((1, page_size, 1, d), page_map))
+        in_specs.append(page_spec)
         args.append(v_pages)
     if int8:
-        in_specs += [pl.BlockSpec((1, page_size, 1), scale_map),
-                     pl.BlockSpec((1, page_size, 1), scale_map)]
+        in_specs += [pl.BlockSpec((1, 1, page_size, 1), page_map)] * 2
         args += [k_scale_pages, v_scale_pages]
 
     def kern(*refs):
-        i = 2 + tree                     # bt, live[, base]
-        base_ref = refs[2] if tree else None
-        len_ref = refs[i]; i += 1
-        anc_ref = None
-        if tree:
-            anc_ref = refs[i]; i += 1
+        anc_ref, base_ref = (refs[3], refs[4]) if tree else (None, None)
+        i = len(prefetch)
         q_ref, k_ref = refs[i:i + 2]; i += 2
         v_ref = None
         if not latent:
@@ -268,7 +239,7 @@ def paged_attention_pallas(
         if int8:
             ks_ref, vs_ref = refs[i:i + 2]; i += 2
         o_ref, m_ref, l_ref, acc_ref = refs[i:i + 4]
-        return _kernel(refs[0], refs[1], len_ref, q_ref, k_ref, v_ref,
+        return _kernel(refs[0], refs[1], refs[2], q_ref, k_ref, v_ref,
                        o_ref, m_ref, l_ref, acc_ref,
                        page_size=page_size, t=t, r=r,
                        ks_ref=ks_ref, vs_ref=vs_ref,
@@ -279,16 +250,19 @@ def paged_attention_pallas(
         num_scalar_prefetch=len(prefetch),
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, tr, d), row_map),
+        out_specs=pl.BlockSpec((1, 1, trp, d), row_map),
         scratch_shapes=[
-            pltpu.VMEM((tr, 128), jnp.float32),   # running max (lane-padded)
-            pltpu.VMEM((tr, 128), jnp.float32),   # running denom
-            pltpu.VMEM((tr, d), jnp.float32),     # unnormalized output
+            pltpu.VMEM((trp, 128), jnp.float32),   # running max (lane-padded)
+            pltpu.VMEM((trp, 128), jnp.float32),   # running denom
+            pltpu.VMEM((trp, d), jnp.float32),     # unnormalized output
         ],
     )
     return pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, khn, tr, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, khn, trp, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_attention",
     )(*prefetch, *args)

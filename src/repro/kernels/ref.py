@@ -1,15 +1,13 @@
-"""Pure-jnp oracles for the Pallas kernels.
-
-These are also the *dry-run / GSPMD path*: identical math to the kernels,
-expressed as gather + einsum so XLA can shard them (N on the `model` axis).
+"""Pure-jnp oracles for the Pallas kernels: identical math, expressed as
+gather + einsum. Models run them with ``use_pallas=False`` (the CPU
+default, and the reference side of kernel comparisons on the chip).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from repro.core.bsr import BSRMatrix
-from repro.core.quant import unpack_int4
+from repro.core.bsr import BSRMatrix, to_dense
 
 
 def gqsa_gemv_ref(x: jnp.ndarray, bsr: BSRMatrix,
@@ -18,45 +16,38 @@ def gqsa_gemv_ref(x: jnp.ndarray, bsr: BSRMatrix,
 
     x: [B, K]  (B = decode slots, or slots x (K+1) draft rows in the
     speculative verify step)
-    returns y: [B, N] with y[b,n] = sum_m deq(vals[n,m]) . x[b, idx[n,m]G:+G]
+    returns y: [B, N] with y[b,n] = sum_m deq(slot n,m) . x[b, col(n,m)G:+G]
 
-    The surviving groups are dequantized once and scattered into a dense
-    [N, K] operand, then contracted with ONE matmul: traffic is
-    row-count-independent (~2.5x the BSR payload). The previous
-    formulation gathered activation groups per output row into a
-    [B, N, M, G] tensor — the whole payload times B — which made
-    multi-row calls (the verify step) pay for their rows twice over.
-    Padding slots carry idx -1 -> clamped to group 0 with scale 0, so
-    they scatter-add zeros.
+    The surviving groups are dequantized once and placed into a dense
+    f32 [N, K] operand (`core/bsr.py:to_dense`), then contracted with ONE
+    matmul: traffic is row-count-independent.
     """
-    n, k = bsr.shape
-    g = bsr.group_size
-    q = unpack_int4(bsr.vals).astype(jnp.float32)              # [N, M, G]
-    w = (q - bsr.zero[..., None]) * bsr.scale[..., None]       # [N, M, G]
-    safe = jnp.maximum(bsr.idx, 0)                              # [N, M]
-    rows = jnp.arange(n)[:, None]
-    # duplicates only occur among padding slots (all-zero contributions),
-    # so scatter-ADD is order-independent and exact
-    wd = jnp.zeros((n, k // g, g), jnp.float32).at[rows, safe].add(w)
-    y = x.astype(jnp.float32) @ wd.reshape(n, k).T
+    y = x.astype(jnp.float32) @ to_dense(bsr).T
     return y.astype(dtype)
 
 
-def w4_matmul_ref(x: jnp.ndarray, qw: jnp.ndarray, scale: jnp.ndarray,
-                  zero: jnp.ndarray, group_size: int,
-                  dtype=jnp.float32) -> jnp.ndarray:
-    """Dense grouped-dequant matmul (W4A16 baseline / prefill path).
+def w4_dense(qw: jnp.ndarray, scale: jnp.ndarray,
+             zero: jnp.ndarray) -> jnp.ndarray:
+    """Plane-packed W4 (`kernels/w4_matmul.py` layout) -> dense f32
+    W^T [Kp, N]: nibble i of word r is input column i * Kp/8 + r."""
+    rows, n = qw.shape
+    g = rows // scale.shape[1]
+    shifts = 4 * jnp.arange(8, dtype=jnp.int32)[:, None, None]
+    q = ((qw[None] >> shifts) & 0xF).astype(jnp.float32)   # [8, Kp/8, N]
+    w = (q.reshape(8, -1, g, n) - zero[:, :, None, :]) * scale[:, :, None, :]
+    return w.reshape(8 * rows, n)
 
-    x: [B, K]; qw: packed uint8 [N, K/2]; scale/zero: [N, K/G].
+
+def w4_matmul_ref(x: jnp.ndarray, qw: jnp.ndarray, scale: jnp.ndarray,
+                  zero: jnp.ndarray, dtype=jnp.float32) -> jnp.ndarray:
+    """Dense grouped-dequant matmul (W4A16 baseline).
+
+    x: [T, K]; qw/scale/zero: the plane-packed layout (K padded to Kp).
     y = x @ deq(qw).T
     """
-    n = qw.shape[0]
-    q = unpack_int4(qw).astype(jnp.float32)                    # [N, K]
-    k = q.shape[1]
-    qg = q.reshape(n, k // group_size, group_size)
-    w = (qg - zero[..., None]) * scale[..., None]
-    w = w.reshape(n, k)
-    return (x.astype(jnp.float32) @ w.T).astype(dtype)
+    wt = w4_dense(qw, scale, zero)
+    xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (0, wt.shape[0] - x.shape[1])))
+    return (xp @ wt).astype(dtype)
 
 
 def kv_decode_attention_ref(q, k_cache, k_scale, v_cache, v_scale, length,
@@ -78,18 +69,27 @@ def kv_decode_attention_ref(q, k_cache, k_scale, v_cache, v_scale, length,
     return o.astype(dtype)
 
 
+def page_view(pages: jnp.ndarray, block_tables: jnp.ndarray) -> jnp.ndarray:
+    """Dense page gather: head-major pool [KH, P, ps, X] -> per-slot cache
+    [B, MP*ps, KH, X]. Sentinel ids (>= P) clamp to P - 1 (XLA's
+    OOB-gather clip); callers mask those positions by length."""
+    tok_major = jnp.moveaxis(pages, 0, 2)              # [P, ps, KH, X]
+    g = tok_major[jnp.minimum(block_tables, pages.shape[1] - 1)]
+    return g.reshape((block_tables.shape[0], -1) + tok_major.shape[2:])
+
+
 def paged_attention_ref(q, k_pages, v_pages, lengths, block_tables,
                         k_scale_pages=None, v_scale_pages=None,
                         dtype=jnp.float32, *, anc=None, anc_base=None,
                         anc_window: int = 0):
-    """Oracle + GSPMD/dry-run path for the paged decode attention kernel.
+    """Oracle for the paged decode attention kernel.
 
     Dense page gather (what the kernel avoids) followed by staircase
     attention — identical math to the Pallas kernel: dequantize int8
     pages, f32 score/value contractions, per-query length mask.
 
     q: [B, T, H, D] (T=1 decode, T=K+1 speculative verify);
-    k/v_pages: [P, ps, KH, D] (int8 variants add [P, ps, KH] scales);
+    k/v_pages: [KH, P, ps, D] (int8 variants add [KH, P, ps, 1] scales);
     lengths: [] / [B] / [B, T] per-query valid prefix; block_tables:
     [B, MP] page ids — entries >= P are sentinels and clamp to P - 1
     (XLA's OOB-gather clip), their positions masked by ``lengths``.
@@ -101,18 +101,13 @@ def paged_attention_ref(q, k_pages, v_pages, lengths, block_tables,
     """
     from repro.models.layers import ancestor_mask
     b, t, h, d = q.shape
-    num_pages, ps, khn, _ = k_pages.shape
+    khn, num_pages = k_pages.shape[:2]
     r = h // khn
-
-    def view(buf):                       # [P, ps, ...] -> [B, MP*ps, ...]
-        g = buf[jnp.minimum(block_tables, num_pages - 1)]
-        return g.reshape((b, -1) + buf.shape[2:])
-
-    k = view(k_pages).astype(jnp.float32)
-    v = view(v_pages).astype(jnp.float32)
+    k = page_view(k_pages, block_tables).astype(jnp.float32)
+    v = page_view(v_pages, block_tables).astype(jnp.float32)
     if k_scale_pages is not None:
-        k = k * view(k_scale_pages)[..., None]
-        v = v * view(v_scale_pages)[..., None]
+        k = k * page_view(k_scale_pages, block_tables)
+        v = v * page_view(v_scale_pages, block_tables)
     s = k.shape[1]
     scale = 1.0 / jnp.sqrt(d).astype(jnp.float32)
     qh = q.reshape(b, t, khn, r, d).astype(jnp.float32)
@@ -129,11 +124,10 @@ def paged_latent_attention_ref(q, lat_pages, lengths, block_tables,
                                v_rank: int, dtype=jnp.float32, *,
                                anc=None, anc_base=None,
                                anc_window: int = 0):
-    """Oracle + GSPMD/dry-run path for the paged LATENT attention kernel
-    (MLA, DESIGN.md §9).
+    """Oracle for the paged LATENT attention kernel (MLA, DESIGN.md §9).
 
     q: [B, T, H, R + rope] absorbed pre-scaled queries; lat_pages:
-    [P, ps, R + rope] — a single logical KV head whose value is the
+    [1, P, ps, R + rope] — a single logical KV head whose value is the
     leading ``v_rank`` dims of the same row (no V pool). Dense page
     gather followed by single-head attention with the shared
     staircase/ancestor masks; sentinel block-table entries clamp to
@@ -142,9 +136,8 @@ def paged_latent_attention_ref(q, lat_pages, lengths, block_tables,
     """
     from repro.models.layers import ancestor_mask
     b, t, h, d = q.shape
-    num_pages, ps, dl = lat_pages.shape
-    g = lat_pages[jnp.minimum(block_tables, num_pages - 1)]
-    k = g.reshape(b, -1, dl).astype(jnp.float32)           # [B, S, R+rope]
+    k = page_view(lat_pages, block_tables)[:, :, 0].astype(
+        jnp.float32)                                       # [B, S, R+rope]
     s = k.shape[1]
     scale = 1.0 / jnp.sqrt(d).astype(jnp.float32)
     sco = jnp.einsum("bthd,bsd->bhts", q.astype(jnp.float32), k) * scale

@@ -278,7 +278,7 @@ def _train_components(cfg, shape, dist, mesh, accum: int,
 
     # gradient sync across DP axes (psum on replicated-across-data grads)
     if dp > 1 and not cfg.fsdp:
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         axes = dist.batch_axes
 
         def sync_fn(grads):
@@ -288,7 +288,7 @@ def _train_components(cfg, shape, dist, mesh, accum: int,
                 lambda g: jax.tree_util.tree_map(
                     lambda x: jax.lax.psum(x, axes), g),
                 mesh=mesh, in_specs=(spec,), out_specs=spec,
-                check_rep=False)(grads)
+                check_vma=False)(grads)
         comps.append(("grad_sync", 1, sync_fn, (params_sds,), (p_sh_full,)))
     return comps
 

@@ -42,6 +42,7 @@ from repro.engine import (ChaosConfig, EngineConfig, InferenceEngine,
                           ResilienceConfig, SamplingParams, Telemetry)
 from repro.engine.loadgen import SLO, SLOLedger, generate, make_source
 from repro.engine.loadgen import WorkloadSpec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.registry import get_model
 
 
@@ -50,9 +51,24 @@ def make_requests(n, vocab, rng, lo=4, hi=16):
     return [rng.integers(0, vocab, size=l).astype(np.int32) for l in lens]
 
 
+def host_device():
+    """The host CPU device: random weights are built and packed there, so
+    the accelerator only ever holds the packed tree."""
+    return jax.local_devices(backend="cpu")[0]
+
+
 def compressed_params(cfg, args, rng, fp_params=None):
+    """Random FP weights from ``rng`` -> the ``--compress`` serving tree,
+    built on the host; only the result moves to the default device."""
     api = get_model(cfg)
-    params = api.init_params(rng, cfg) if fp_params is None else fp_params
+    with jax.default_device(host_device()):
+        if fp_params is None:
+            fp_params = api.init_params(rng, cfg)
+        params = _compress(cfg, args, fp_params)
+    return jax.device_put(params, jax.devices()[0])
+
+
+def _compress(cfg, args, params):
     t0 = time.time()
     if args.compress == "gqsa":
         gqsa = GQSAConfig(
@@ -121,11 +137,6 @@ def main(argv=None):
     ap.add_argument("--draft-profile", default="w4s75",
                     choices=list_draft_profiles(),
                     help="draft compression of the same checkpoint")
-    ap.add_argument("--use-pallas", action="store_true",
-                    help="Pallas kernel path (interpret off-TPU): packed "
-                         "linears AND the fused paged-attention decode "
-                         "kernel (attends in place on the KV pool; the "
-                         "jnp reference gathers pages densely)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--trace", default=None, metavar="OUT.json",
                     help="record phase spans + per-request flow events "
@@ -210,18 +221,23 @@ def main(argv=None):
         ap.error(f"--arch {args.arch}: family {cfg.family!r} has no "
                  f"paged-cache support "
                  f"(supported: {', '.join(paged_families())})")
-    rng = jax.random.PRNGKey(args.seed)
-    # the FP tree is only needed as the shared source of target + draft
-    # compression; don't keep a full-scale checkpoint alive otherwise
-    fp_params = get_model(cfg).init_params(rng, cfg) if spec_on else None
+    enable_compile_cache()
+    with jax.default_device(host_device()):
+        rng = jax.random.PRNGKey(args.seed)
+        # the FP tree is only needed as the shared source of target + draft
+        # compression; don't keep a full-scale checkpoint alive otherwise
+        fp_params = get_model(cfg).init_params(rng, cfg) if spec_on \
+            else None
     params = compressed_params(cfg, args, rng, fp_params=fp_params)
     draft_params = None
     dlayers = None
     if spec_on:
         t0 = time.time()
-        draft_params = compress_draft(fp_params, cfg,
-                                      profile=args.draft_profile,
-                                      group_size=args.group_size)
+        with jax.default_device(host_device()):
+            draft_params = compress_draft(fp_params, cfg,
+                                          profile=args.draft_profile,
+                                          group_size=args.group_size)
+        draft_params = jax.device_put(draft_params, jax.devices()[0])
         dlayers = draft_layers(cfg, args.draft_profile)
         print(f"packed draft profile {args.draft_profile} "
               f"({dlayers}/{cfg.n_layers} layers) in {time.time()-t0:.1f}s")
@@ -238,7 +254,7 @@ def main(argv=None):
                      page_size=args.page_size, num_pages=args.num_pages,
                      prefix_cache=args.prefix_cache,
                      prefill_chunk_tokens=args.chunked_prefill,
-                     use_pallas=args.use_pallas, seed=args.seed,
+                     seed=args.seed,
                      spec_k=args.spec, spec_draft_layers=dlayers,
                      spec_fanout=spec_fanout,
                      spec_adaptive=args.spec_adaptive,

@@ -156,7 +156,7 @@ def build_train_step_ddp(cfg: ModelConfig, dist: DistContext,
     """shard_map DDP train step with int8 error-feedback gradient all-reduce
     (params replicated; for models that fit per-device — the paper's own
     llama-2-7b class). State gains an ``err`` tree (error feedback)."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.optim import grad_compress as GC
     api = get_model(cfg)
@@ -206,7 +206,7 @@ def build_train_step_ddp(cfg: ModelConfig, dist: DistContext,
         return shard_map(local_step, mesh=dist.mesh,
                          in_specs=(p_spec, o_spec, e_spec, b_spec),
                          out_specs=(p_spec, o_spec, e_spec, m_spec),
-                         check_rep=False)(params, opt_state, err, batch)
+                         check_vma=False)(params, opt_state, err, batch)
 
     return step
 

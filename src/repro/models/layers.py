@@ -465,7 +465,7 @@ def attention_block_sp(p: Dict, x: jnp.ndarray, cfg, *, causal=True,
     Causality across shards is handled by a traced q_offset in the flash
     mask (uniform SPMD program; ~2x attention FLOPs upper bound).
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     b, s, d = x.shape
     maxis = dist.model_axis
@@ -494,7 +494,7 @@ def attention_block_sp(p: Dict, x: jnp.ndarray, cfg, *, causal=True,
     return shard_map(local, mesh=dist.mesh,
                      in_specs=(P(dp, maxis, None), pspec),
                      out_specs=P(dp, maxis, None),
-                     check_rep=False)(x, p)
+                     check_vma=False)(x, p)
 
 
 def attention_decode(p: Dict, x: jnp.ndarray, cache: Dict, pos: jnp.ndarray,
@@ -577,6 +577,33 @@ def paged_block_geometry(positions: jnp.ndarray, t: int,
     return pos_bt, rope_pos, length, base, anc, window
 
 
+def page_write(pages: jnp.ndarray, page: jnp.ndarray, off: jnp.ndarray,
+               new: jnp.ndarray) -> jnp.ndarray:
+    """Scatter per-token rows into a head-major pool: pages [KH, P, ps, X]
+    <- new [B, T, KH, X] at (page, off) [B, T]. Rows whose page is the
+    out-of-range sentinel (>= P) are dropped."""
+    return pages.at[:, page, off].set(
+        jnp.moveaxis(new, 2, 0).astype(pages.dtype), mode="drop")
+
+
+def paged_write_pages(block_tables: jnp.ndarray, pos_bt: jnp.ndarray,
+                      page_size: int, num_pages: int,
+                      feed_len: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """Page id of each fed token's write position [B, T]. With a ragged
+    ``feed_len`` [B] (prefix-cache tail prefill, DESIGN.md §13), rows
+    feed feed_len[i] <= T real tokens: positions at or past it map to
+    the out-of-range sentinel so their writes drop — the convention
+    batched prefill uses for padding — instead of take_along_axis
+    clipping them onto the row's last live page and corrupting it."""
+    page = jnp.take_along_axis(block_tables, pos_bt // page_size, axis=1)
+    if feed_len is None:
+        return page
+    t = pos_bt.shape[1]
+    return jnp.where(
+        jnp.arange(t, dtype=jnp.int32)[None, :] < feed_len[:, None],
+        page, num_pages)
+
+
 def attention_decode_paged(p: Dict, x: jnp.ndarray, cache: Dict,
                            block_tables: jnp.ndarray, positions: jnp.ndarray,
                            cfg, use_pallas=False, tree: Optional[Dict] = None,
@@ -591,8 +618,8 @@ def attention_decode_paged(p: Dict, x: jnp.ndarray, cache: Dict,
     token (token t lands at positions + t); block_tables: [B, MP] page ids
     (entries == n_pages are out-of-range sentinels: scatter-writes to
     them are dropped, gather-reads clip and get masked by the per-query
-    length). cache: {"k_pages"/"v_pages": [P, ps, KH, D]}
-    (+ "k_scale_pages"/"v_scale_pages" [P, ps, KH] for int8).
+    length). cache: {"k_pages"/"v_pages": [KH, P, ps, D]} head-major
+    (+ "k_scale_pages"/"v_scale_pages" [KH, P, ps, 1] for int8).
 
     Causality inside the T block comes from the per-query staircase
     length (query t sees cache positions < positions + t + 1); the K/V of
@@ -614,48 +641,36 @@ def attention_decode_paged(p: Dict, x: jnp.ndarray, cache: Dict,
     With ``use_pallas`` the attention runs the fused paged kernel
     (`kernels/paged_attention.py`): it streams each slot's live pages
     through VMEM directly — the dense `[B, MP*ps, ...]` page gather
-    below exists only on the jnp reference path (GSPMD / dry-run), and
-    even there the engine clamps ``block_tables`` to the batch's max
-    *occupied* page count before calling in (``decode_step``'s
-    ``max_live_pages``), so the reference never pays for unallocated
-    pages either.
+    below exists only on the jnp reference path, and even there the
+    engine clamps ``block_tables`` to the batch's max *occupied* page
+    count before calling in (``decode_step``'s ``max_live_pages``), so
+    the reference never pays for unallocated pages either.
     """
+    from repro.kernels import ops as kops
+    from repro.kernels.ref import page_view
     b, t, _ = x.shape
     kp = cache["k_pages"]
-    page_size = kp.shape[1]
+    page_size = kp.shape[2]
     pos_bt, rope_pos, length, base, anc, window = paged_block_geometry(
         positions, t, tree)
     q, k, v = attn_qkv(p, x, rope_pos, cfg, use_pallas)
-    page = jnp.take_along_axis(block_tables, pos_bt // page_size,
-                               axis=1)                       # [B, T]
+    page = paged_write_pages(block_tables, pos_bt, page_size, kp.shape[1],
+                             feed_len)
     off = pos_bt % page_size
-    if feed_len is not None:
-        # ragged multi-token feed (prefix-cache tail prefill, DESIGN.md
-        # §13): rows feed feed_len[i] <= T real tokens. Positions at or
-        # past a row's feed_len remap to the out-of-range sentinel so
-        # their K/V writes drop — the same convention batched prefill
-        # uses for padding — instead of take_along_axis clipping them
-        # onto the row's last live page and corrupting it.
-        page = jnp.where(
-            jnp.arange(t, dtype=jnp.int32)[None, :] < feed_len[:, None],
-            page, kp.shape[0])
 
-    def write(buf, new):                 # [P, ps, ...] <- [B, T, ...]
-        return buf.at[page, off].set(new.astype(buf.dtype))
-
-    def view(buf):                       # [P, ps, ...] -> [B, MP*ps, ...]
-        g = buf[block_tables]            # OOB sentinel pages clip (masked)
-        return g.reshape((b, -1) + buf.shape[2:])
+    def view(buf):                       # pool -> [B, MP*ps, KH, X]
+        return page_view(buf, block_tables)
 
     if "k_scale_pages" in cache:         # int8 paged cache
         k_i8, k_sc = quantize_kv(k)
         v_i8, v_sc = quantize_kv(v)
-        new = {"k_pages": write(kp, k_i8),
-               "v_pages": write(cache["v_pages"], v_i8),
-               "k_scale_pages": write(cache["k_scale_pages"], k_sc),
-               "v_scale_pages": write(cache["v_scale_pages"], v_sc)}
+        new = {"k_pages": page_write(kp, page, off, k_i8),
+               "v_pages": page_write(cache["v_pages"], page, off, v_i8),
+               "k_scale_pages": page_write(cache["k_scale_pages"], page,
+                                           off, k_sc[..., None]),
+               "v_scale_pages": page_write(cache["v_scale_pages"], page,
+                                           off, v_sc[..., None])}
         if use_pallas:
-            from repro.kernels import ops as kops
             o = kops.paged_decode_attention(
                 q, new["k_pages"], new["v_pages"], length, block_tables,
                 new["k_scale_pages"], new["v_scale_pages"],
@@ -663,15 +678,14 @@ def attention_decode_paged(p: Dict, x: jnp.ndarray, cache: Dict,
                 anc_window=window).astype(q.dtype)
         else:
             o = decode_attention_int8(q, view(new["k_pages"]),
-                                      view(new["k_scale_pages"]),
+                                      view(new["k_scale_pages"])[..., 0],
                                       view(new["v_pages"]),
-                                      view(new["v_scale_pages"]), length,
-                                      anc, base, window)
+                                      view(new["v_scale_pages"])[..., 0],
+                                      length, anc, base, window)
     else:
-        new = {"k_pages": write(kp, k),
-               "v_pages": write(cache["v_pages"], v)}
+        new = {"k_pages": page_write(kp, page, off, k),
+               "v_pages": page_write(cache["v_pages"], page, off, v)}
         if use_pallas:
-            from repro.kernels import ops as kops
             o = kops.paged_decode_attention(
                 q, new["k_pages"], new["v_pages"], length,
                 block_tables, anc=anc, anc_base=base,

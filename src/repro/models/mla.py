@@ -20,7 +20,8 @@ import jax.numpy as jnp
 from repro.core.gqs_layer import apply_linear
 from repro.models.layers import (apply_rope, decode_attention,
                                  flash_attention, linear_init, norm_init,
-                                 paged_block_geometry, rmsnorm)
+                                 page_write, paged_block_geometry,
+                                 paged_write_pages, rmsnorm)
 
 
 def mla_init(rng, cfg, dtype=jnp.float32) -> Dict:
@@ -164,7 +165,7 @@ def mla_decode_paged(p: Dict, x: jnp.ndarray, cache: Dict,
 
     x: [B, T, d]; positions: [B] write position of each slot's first
     token; block_tables: [B, MP] page ids (>= P entries are sentinels);
-    cache: ``{"lat_pages": [P, ps, R + rope]}`` — ONE logical KV "head"
+    cache: ``{"lat_pages": [1, P, ps, R + rope]}`` — ONE logical KV "head"
     per page pool holding post-norm ``c_kv`` ++ post-RoPE ``k_rope``.
     There is NO V pool: the value of a cached token is the leading R
     dims of the same latent row, up-projected through W_UV only AFTER
@@ -176,32 +177,25 @@ def mla_decode_paged(p: Dict, x: jnp.ndarray, cache: Dict,
     path, shared via :func:`layers.paged_block_geometry`).
 
     ``use_pallas`` routes the attention through the fused latent kernel
-    (`kernels/ops.py:paged_latent_attention` — lane-dim-tiled scores for
-    R + rope > 128); the jnp path gathers pages densely and reuses
+    (`kernels/ops.py:paged_latent_attention`); the jnp path gathers
+    pages densely and reuses
     :func:`decode_attention`, the same op sequence as the dense
     :func:`mla_decode` oracle.
     """
     m = cfg.mla
     b, t, _ = x.shape
     lat = cache["lat_pages"]
-    page_size = lat.shape[1]
+    page_size = lat.shape[2]
     pos_bt, rope_pos, length, base, anc, window = paged_block_geometry(
         positions, t, tree)
     q_nope, q_rope = _mla_q(p, x, rope_pos, cfg, use_pallas)
     c_kv_new, k_rope_new = _mla_kv_latent(p, x, rope_pos, cfg, use_pallas)
     lat_new = jnp.concatenate([c_kv_new, k_rope_new], axis=-1)   # [B,T,R+r]
 
-    page = jnp.take_along_axis(block_tables, pos_bt // page_size,
-                               axis=1)                       # [B, T]
+    page = paged_write_pages(block_tables, pos_bt, page_size, lat.shape[1],
+                             feed_len)
     off = pos_bt % page_size
-    if feed_len is not None:
-        # ragged feed (prefix-cache tail prefill): positions at or past a
-        # row's feed_len write to the out-of-range sentinel, same masking
-        # as layers.py:attention_decode_paged
-        page = jnp.where(
-            jnp.arange(t, dtype=jnp.int32)[None, :] < feed_len[:, None],
-            page, lat.shape[0])
-    new = {"lat_pages": lat.at[page, off].set(lat_new.astype(lat.dtype))}
+    new = {"lat_pages": page_write(lat, page, off, lat_new[:, :, None])}
 
     q_scaled = _absorbed_q(p, q_nope, q_rope, cfg)           # [B,T,H,R+r]
     if use_pallas:
@@ -211,10 +205,9 @@ def mla_decode_paged(p: Dict, x: jnp.ndarray, cache: Dict,
             v_rank=m.kv_lora_rank, anc=anc, anc_base=base,
             anc_window=window).astype(q_scaled.dtype)
     else:
-        g = new["lat_pages"][block_tables]    # OOB sentinels clip (masked)
-        g = g.reshape(b, -1, lat.shape[-1])
-        ctx = decode_attention(q_scaled, g[:, :, None, :],
-                               g[:, :, None, :m.kv_lora_rank], length,
+        from repro.kernels.ref import page_view
+        g = page_view(new["lat_pages"], block_tables)   # [B, S, 1, R+r]
+        ctx = decode_attention(q_scaled, g, g[..., :m.kv_lora_rank], length,
                                anc, base, window)
     v = jnp.einsum("bshr,hvr->bshv", ctx, p["w_uv"].astype(ctx.dtype))
     return apply_linear(p["wo"], v.reshape(b, t, -1),

@@ -19,7 +19,7 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.gqs_layer import apply_linear
@@ -180,7 +180,7 @@ def moe_block(p: Dict, x: jnp.ndarray, cfg, dist=None,
             local, mesh=dist.mesh,
             in_specs=(P(dp, None), router_specs, expert_specs),
             out_specs=(P(dp, None), P()),
-            check_rep=False,
+            check_vma=False,
         )(xf.reshape(t, d), p["router"], p["experts"])
 
     if "shared" in p:

@@ -163,33 +163,34 @@ def init_cache(cfg, batch: int, max_seq: int, dtype=None) -> Dict:
 def init_paged_cache(cfg, num_pages: int, page_size: int,
                      dtype=None) -> Dict:
     """Paged KV pool: fixed-size pages shared by all slots via per-request
-    block tables (see DESIGN.md §3). Leaves are [L, P, ps, ...] so the
-    decode scan hands each layer its [P, ps, ...] view.
+    block tables (see DESIGN.md §3). Leaves are head-major
+    [L, KH, P, ps, X]: the decode scan hands each layer its
+    [KH, P, ps, X] view, and the paged-attention kernel DMAs one
+    (head, page) tile [ps, X] per grid step (kernels/paged_attention.py).
 
     ``mla_moe`` pages the LATENT cache (DESIGN.md §9): one pool of
-    [L, P, ps, kv_lora_rank + qk_rope_dim] rows — a single logical KV
+    [L, 1, P, ps, kv_lora_rank + qk_rope_dim] rows — a single logical KV
     "head" per page, and NO V pool (values are up-projected from the
     latent through W_UV after attention). Latent pages stay in the
     compute dtype regardless of ``kv_cache_dtype`` (int8 latent pages
-    are a recorded follow-on, ROADMAP)."""
+    are a recorded follow-on, ROADMAP). int8 pages carry per-token x head
+    scales [L, KH, P, ps, 1]."""
     dtype = dtype or cfg.compute_dtype
+    lyr = cfg.n_layers
     if cfg.family == "mla_moe":
         m = cfg.mla
         return {"lat_pages": jnp.zeros(
-            (cfg.n_layers, num_pages, page_size,
-             m.kv_lora_rank + m.qk_rope_dim), dtype)}
-    lyr, kh, hd = cfg.n_layers, cfg.n_kv_heads, cfg.hd
+            (lyr, 1, num_pages, page_size, m.kv_lora_rank + m.qk_rope_dim),
+            dtype)}
+    shape = (lyr, cfg.n_kv_heads, num_pages, page_size, cfg.hd)
     if cfg.kv_cache_dtype == "int8":
-        return {"k_pages": jnp.zeros((lyr, num_pages, page_size, kh, hd),
-                                     jnp.int8),
-                "v_pages": jnp.zeros((lyr, num_pages, page_size, kh, hd),
-                                     jnp.int8),
-                "k_scale_pages": jnp.zeros((lyr, num_pages, page_size, kh),
-                                           jnp.float32),
-                "v_scale_pages": jnp.zeros((lyr, num_pages, page_size, kh),
-                                           jnp.float32)}
-    return {"k_pages": jnp.zeros((lyr, num_pages, page_size, kh, hd), dtype),
-            "v_pages": jnp.zeros((lyr, num_pages, page_size, kh, hd), dtype)}
+        scale = shape[:-1] + (1,)
+        return {"k_pages": jnp.zeros(shape, jnp.int8),
+                "v_pages": jnp.zeros(shape, jnp.int8),
+                "k_scale_pages": jnp.zeros(scale, jnp.float32),
+                "v_scale_pages": jnp.zeros(scale, jnp.float32)}
+    return {"k_pages": jnp.zeros(shape, dtype),
+            "v_pages": jnp.zeros(shape, dtype)}
 
 
 def prefill(params: Dict, cache: Dict, tokens: jnp.ndarray,
@@ -207,7 +208,7 @@ def prefill(params: Dict, cache: Dict, tokens: jnp.ndarray,
     """
     b, s = tokens.shape
     leaf = jax.tree_util.tree_leaves(cache)[0]
-    num_pages, page_size = leaf.shape[1], leaf.shape[2]
+    num_pages, page_size = leaf.shape[2], leaf.shape[3]
     h = embed_tokens(params, tokens, cfg)
     positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
     # (b, s) -> flat page/offset; invalid (padding) positions -> OOB page
@@ -227,8 +228,8 @@ def prefill(params: Dict, cache: Dict, tokens: jnp.ndarray,
             # ++ post-RoPE k_rope) pages as ONE pool — no V scatter
             a, latent = MLA.mla_prefill_paged(lp["attn"], hn, positions,
                                               cfg, use_pallas)
-            new_c = {"lat_pages": lc["lat_pages"].at[page, off].set(
-                latent.astype(lc["lat_pages"].dtype))}
+            new_c = {"lat_pages": L.page_write(lc["lat_pages"], page, off,
+                                               latent[:, :, None])}
         else:
             q, k, v = L.attn_qkv(lp["attn"], hn, positions, cfg, use_pallas)
             o = L.flash_attention(q, k, v, causal=True,
@@ -241,18 +242,16 @@ def prefill(params: Dict, cache: Dict, tokens: jnp.ndarray,
                 k_i8, k_sc = L.quantize_kv(k)
                 v_i8, v_sc = L.quantize_kv(v)
                 new_c = {
-                    "k_pages": lc["k_pages"].at[page, off].set(k_i8),
-                    "v_pages": lc["v_pages"].at[page, off].set(v_i8),
-                    "k_scale_pages":
-                        lc["k_scale_pages"].at[page, off].set(k_sc),
-                    "v_scale_pages":
-                        lc["v_scale_pages"].at[page, off].set(v_sc)}
+                    "k_pages": L.page_write(lc["k_pages"], page, off, k_i8),
+                    "v_pages": L.page_write(lc["v_pages"], page, off, v_i8),
+                    "k_scale_pages": L.page_write(lc["k_scale_pages"], page,
+                                                  off, k_sc[..., None]),
+                    "v_scale_pages": L.page_write(lc["v_scale_pages"], page,
+                                                  off, v_sc[..., None])}
             else:
                 new_c = {
-                    "k_pages": lc["k_pages"].at[page, off].set(
-                        k.astype(lc["k_pages"].dtype)),
-                    "v_pages": lc["v_pages"].at[page, off].set(
-                        v.astype(lc["v_pages"].dtype))}
+                    "k_pages": L.page_write(lc["k_pages"], page, off, k),
+                    "v_pages": L.page_write(lc["v_pages"], page, off, v)}
         hh = hh + a
         hn = L.rmsnorm(hh, lp["ln2"], cfg.norm_eps)
         if cfg.moe is not None:

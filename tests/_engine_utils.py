@@ -6,9 +6,25 @@ deterministic mid-run arrivals let a test inject a request at an exact
 scheduling boundary (e.g. while a victim is decoding, or mid-chunk), so
 preemption paths replay bit-identically without wall-clock sleeps.
 """
+import contextlib
+
 import numpy as np
 
+from repro.engine import engine as engine_mod
 from repro.engine.loadgen import ArrivalSource, GeneratedRequest
+
+
+@contextlib.contextmanager
+def engine_kernels(on: bool):
+    """Test-only seam: engines built inside the block run their steps
+    through the Pallas kernels (``on``; interpret mode on the CPU) or the
+    jnp references, whatever the platform would choose."""
+    saved = engine_mod.kernels_on
+    engine_mod.kernels_on = lambda: on
+    try:
+        yield
+    finally:
+        engine_mod.kernels_on = saved
 
 
 class ScriptedSource(ArrivalSource):

@@ -13,6 +13,7 @@ ROADMAP's stated success metric, as a test).
 """
 import dataclasses
 import functools
+import gc
 
 import jax
 import numpy as np
@@ -26,7 +27,7 @@ except ImportError:                       # pragma: no cover
 from repro.configs import get_config
 from repro.engine import (EngineConfig, InferenceEngine, SamplingParams,
                           Telemetry, plan_chunks)
-from repro.engine.loadgen import SLO, SLOLedger, generate, make_source
+from repro.engine.loadgen import SLO, SLOLedger, generate
 from repro.engine.loadgen import WorkloadSpec
 from repro.models.registry import get_model
 
@@ -279,22 +280,27 @@ def _traced_workload_run(tiny, chunk):
     cfg, api, params = tiny
     # mixed prompt lengths, staggered decode budgets: slots free one at
     # a time, so each admission prefill lands inside a live co-resident
-    # decode window (the interference being measured)
+    # decode window (the interference being measured). The seeded
+    # requests all arrive at the first scheduling boundary: wall-clock
+    # arrivals would make which prefills overlap a decode depend on host
+    # speed.
     spec = WorkloadSpec(process="poisson", rate=100.0, requests=8,
                         prompt_min=24, prompt_max=64, max_new_min=4,
                         max_new_max=16, seed=13)
+    source = ScriptedSource([(1, r.prompt, r.max_new, 0)
+                             for r in generate(spec, cfg.vocab).requests])
     tel = Telemetry(trace=True)
     eng = InferenceEngine(
         cfg, params,
         EngineConfig(num_slots=2, max_seq=128,
                      prefill_chunk_tokens=chunk),
         GREEDY, telemetry=tel)
-    out = eng.run(source=make_source(generate(spec, cfg.vocab)))
+    out = eng.run(source=source)
     return eng, tel, out
 
 
 def test_chunking_zeroes_prefill_interference_attribution(tiny):
-    """Seeded Poisson mixed-prompt-length workload: a monolithic
+    """Seeded mixed-prompt-length workload: a monolithic
     admission prefill stalls co-resident decodes for its full duration
     in ONE inter-token gap; chunking at budget 8 bounds the longest
     stall to one chunk. Judged at a stall limit every chunked request
@@ -306,8 +312,15 @@ def test_chunking_zeroes_prefill_interference_attribution(tiny):
     # warm the jit caches so compile time doesn't land inside spans
     _traced_workload_run(tiny, 8)
     _traced_workload_run(tiny, 0)
-    eng_c, tel_c, out_c = _traced_workload_run(tiny, 8)
-    eng_m, tel_m, out_m = _traced_workload_run(tiny, 0)
+    # a garbage-collector pause (5-10 ms) inside one chunk span would
+    # read as a chunk-sized stall as long as a monolithic prefill
+    gc.collect()
+    gc.disable()
+    try:
+        eng_c, tel_c, out_c = _traced_workload_run(tiny, 8)
+        eng_m, tel_m, out_m = _traced_workload_run(tiny, 0)
+    finally:
+        gc.enable()
     assert by_rid(out_c) == by_rid(out_m)
     assert any(e.get("name") == "prefill_chunk"
                for e in tel_c.tracer.events)

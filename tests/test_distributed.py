@@ -80,7 +80,7 @@ def test_sharded_train_step_matches_single_device():
 
 def test_compressed_psum_error_feedback():
     out = run_sub("""
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.dist.collectives import compressed_psum_leaf
 
         mesh = Mesh(np.asarray(jax.devices()).reshape(8), ("data",))
@@ -94,7 +94,7 @@ def test_compressed_psum_error_feedback():
         mean_c, err = shard_map(f, mesh=mesh,
                                 in_specs=(P("data", None), P(None)),
                                 out_specs=(P(None), P("data")),
-                                check_rep=False)(g, err0)
+                                check_vma=False)(g, err0)
         exact = jnp.mean(g, axis=0)
         out["rel_err"] = float(jnp.linalg.norm(mean_c[0] - exact)
                                / jnp.linalg.norm(exact))

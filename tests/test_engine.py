@@ -333,3 +333,47 @@ def test_metrics_reported(tiny):
               "latency_ms_p99"):
         assert np.isfinite(m[k]) and m[k] >= 0
     assert m["ttft_ms_p50"] <= m["ttft_ms_p99"] + 1e-9
+
+
+# ---------------------------------------------------------------------------
+# the platform picks the step path; the GQSA kernel serves through it
+# ---------------------------------------------------------------------------
+
+def test_engine_path_follows_platform(tiny):
+    """No option selects the kernels: on the CPU the engine runs the jnp
+    references, and only the test seam turns the kernels on."""
+    from _engine_utils import engine_kernels
+    cfg, api, params = tiny
+    ecfg = EngineConfig(num_slots=1, max_seq=16)
+    assert not hasattr(ecfg, "use_pallas")
+    assert not InferenceEngine(cfg, params, ecfg)._use_pallas
+    with engine_kernels(True):
+        assert InferenceEngine(cfg, params, ecfg)._use_pallas
+
+
+def test_gqsa_engine_kernels_match_reference_greedy():
+    """A reduced starcoder2-3b packed as GQSA W4 S50 G16 serves through
+    the interpret-mode GQSA GEMV and paged-attention kernels inside the
+    engine's layer scan (the work list is a packed leaf, not built while
+    tracing), and its greedy tokens equal the reference path's."""
+    from _engine_utils import engine_kernels
+    from repro.core.gqs_layer import GQSAConfig
+    from repro.core.model_compress import compress_params
+    cfg = get_config("starcoder2_3b", reduced=True)
+    params = compress_params(
+        get_model(cfg).init_params(jax.random.PRNGKey(0), cfg), cfg,
+        GQSAConfig())
+    prompts = _prompts(cfg.vocab, (5, 11, 3, 8))
+
+    def run(kernels):
+        with engine_kernels(kernels):
+            eng = InferenceEngine(cfg, params, EngineConfig(
+                num_slots=2, max_seq=32, page_size=4))
+        assert eng._use_pallas == kernels
+        rids = [eng.submit(p, 6) for p in prompts]
+        out = {r["rid"]: list(r["tokens"]) for r in eng.run()["results"]}
+        return [out[r] for r in rids]
+
+    ref = run(False)
+    assert run(True) == ref
+    assert all(len(t) == 6 for t in ref)

@@ -6,31 +6,36 @@ import numpy as np
 import pytest
 
 from repro.core.bsr import pack_dense
+from repro.core.gqs_layer import pack_w4
 from repro.core.pruning import PruneConfig, group_mask
-from repro.core.quant import (QuantConfig, group_minmax_params, pack_int4,
-                              quantize)
+from repro.core.quant import QuantConfig
 from repro.core.saliency import group_saliency
 from repro.kernels import ops, ref
 
 
-def _bsr_case(seed, n, k, g, sparsity, balanced=True):
+def _bsr_case(seed, n, k, g, sparsity, balanced=True, row_scale=None):
     rng = np.random.default_rng(seed)
-    w = jnp.asarray(rng.normal(size=(n, k)), jnp.float32)
+    w = rng.normal(size=(n, k))
+    if row_scale is not None:
+        w = w * np.asarray(row_scale)[:, None]
+    w = jnp.asarray(w, jnp.float32)
     gm = group_mask(group_saliency(jnp.square(w), g),
                     PruneConfig(sparsity=sparsity, group_size=g,
                                 row_balanced=balanced))
     return w, pack_dense(w, gm, QuantConfig(bits=4, group_size=g))
 
 
+# (136, 4608, 16) is tiled into two row blocks of 128 and, with more than
+# 128 kept groups per row, two 128-slot chunks each: a multi-item work list.
 @pytest.mark.parametrize("n,k,g", [(64, 128, 16), (96, 256, 16),
-                                   (128, 128, 8), (32, 512, 32)])
+                                   (128, 128, 8), (32, 512, 32),
+                                   (136, 4608, 16)])
 @pytest.mark.parametrize("sparsity", [0.25, 0.5])
 def test_gemv_shapes_sparsities(n, k, g, sparsity):
     w, bsr = _bsr_case(0, n, k, g, sparsity)
     x = jnp.asarray(np.random.default_rng(1).normal(size=(2, k)), jnp.float32)
     y_ref = ref.gqsa_gemv_ref(x, bsr)
-    y_ker = ops.gqsa_gemv(x, bsr, use_pallas=True, interpret=True,
-                          block_n=32, block_m=4)
+    y_ker = ops.gqsa_gemv(x, bsr)
     np.testing.assert_allclose(np.asarray(y_ker), np.asarray(y_ref),
                                rtol=1e-4, atol=1e-4)
 
@@ -38,13 +43,19 @@ def test_gemv_shapes_sparsities(n, k, g, sparsity):
 @pytest.mark.parametrize("balanced", [True, False])
 def test_gemv_ragged_rows_task_centric(balanced):
     """Unbalanced (paper-faithful global-threshold) rows exercise the
-    Stream-K-style work list with variable chunks per row block."""
-    w, bsr = _bsr_case(2, 64, 256, 16, 0.6, balanced=balanced)
-    x = jnp.asarray(np.random.default_rng(3).normal(size=(1, 256)),
+    Stream-K-style work list with variable chunks per row block: the
+    last 8 rows are scaled down, so they keep fewer groups and their
+    row block needs one 128-slot chunk where the first needs two."""
+    sparsity, chunks = (0.5, [2, 2]) if balanced else (0.6, [2, 1])
+    n, k = 136, 4608
+    w, bsr = _bsr_case(2, n, k, 16, sparsity, balanced=balanced,
+                       row_scale=np.where(np.arange(n) < 128, 1.0, 0.7))
+    row_block = np.asarray(bsr.work)[0]
+    assert np.bincount(row_block).tolist() == chunks
+    x = jnp.asarray(np.random.default_rng(3).normal(size=(1, k)),
                     jnp.float32)
     y_ref = ref.gqsa_gemv_ref(x, bsr)
-    y_ker = ops.gqsa_gemv(x, bsr, use_pallas=True, interpret=True,
-                          block_n=16, block_m=2)
+    y_ker = ops.gqsa_gemv(x, bsr)
     np.testing.assert_allclose(np.asarray(y_ker), np.asarray(y_ref),
                                rtol=1e-4, atol=1e-4)
 
@@ -55,8 +66,7 @@ def test_gemv_dtypes(xdtype):
     x = jnp.asarray(np.random.default_rng(5).normal(size=(4, 128))).astype(
         xdtype)
     y_ref = ref.gqsa_gemv_ref(x, bsr)
-    y_ker = ops.gqsa_gemv(x, bsr, use_pallas=True, interpret=True,
-                          block_n=32, block_m=4)
+    y_ker = ops.gqsa_gemv(x, bsr)
     np.testing.assert_allclose(np.asarray(y_ker, np.float32),
                                np.asarray(y_ref, np.float32),
                                rtol=2e-2, atol=2e-2)
@@ -67,8 +77,7 @@ def test_gemv_equals_dense_matmul_on_decompressed():
     from repro.core.bsr import to_dense
     x = jnp.asarray(np.random.default_rng(7).normal(size=(3, 128)),
                     jnp.float32)
-    y = ops.gqsa_gemv(x, bsr, use_pallas=True, interpret=True,
-                      block_n=32, block_m=4)
+    y = ops.gqsa_gemv(x, bsr)
     y_dense = x @ to_dense(bsr).T
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_dense),
                                rtol=1e-4, atol=1e-4)
@@ -79,13 +88,10 @@ def test_gemv_equals_dense_matmul_on_decompressed():
 def test_w4_matmul_shapes(t, n, k, g):
     rng = np.random.default_rng(8)
     w = jnp.asarray(rng.normal(size=(n, k)), jnp.float32)
-    qcfg = QuantConfig(bits=4, group_size=g)
-    s, z = group_minmax_params(w, qcfg)
-    qw = pack_int4(quantize(w, s, z, qcfg))
+    p = pack_w4(w, QuantConfig(bits=4, group_size=g))
     x = jnp.asarray(rng.normal(size=(t, k)), jnp.float32)
-    y_ref = ref.w4_matmul_ref(x, qw, s, z, g)
-    y_ker = ops.w4_matmul(x, qw, s, z, group_size=g, use_pallas=True,
-                          interpret=True, block_t=8, block_n=32, block_k=64)
+    y_ref = ref.w4_matmul_ref(x, p["qw"], p["scale"], p["zero"])
+    y_ker = ops.w4_matmul(x, p["qw"], p["scale"], p["zero"])
     np.testing.assert_allclose(np.asarray(y_ker), np.asarray(y_ref),
                                rtol=1e-4, atol=1e-4)
 
@@ -94,13 +100,17 @@ def test_w4_matmul_unaligned_shapes_padded():
     rng = np.random.default_rng(9)
     n, k, g, t = 48, 160, 16, 5
     w = jnp.asarray(rng.normal(size=(n, k)), jnp.float32)
-    qcfg = QuantConfig(bits=4, group_size=g)
-    s, z = group_minmax_params(w, qcfg)
-    qw = pack_int4(quantize(w, s, z, qcfg))
+    p = pack_w4(w, QuantConfig(bits=4, group_size=g))
     x = jnp.asarray(rng.normal(size=(t, k)), jnp.float32)
-    y_ref = ref.w4_matmul_ref(x, qw, s, z, g)
-    y_ker = ops.w4_matmul(x, qw, s, z, group_size=g, use_pallas=True,
-                          interpret=True, block_t=8, block_n=32, block_k=160)
+    y_ref = ref.w4_matmul_ref(x, p["qw"], p["scale"], p["zero"])
+    y_ker = ops.w4_matmul(x, p["qw"], p["scale"], p["zero"])
+    # K pads to a multiple of 8·G with zero codes and scales: the padded
+    # columns contribute nothing
+    w_deq = ref.w4_dense(p["qw"], p["scale"], p["zero"])
+    assert w_deq.shape == (256, n) and not np.asarray(w_deq[k:]).any()
+    np.testing.assert_allclose(np.asarray(y_ref),
+                               np.asarray(x @ w_deq[:k]), rtol=1e-5,
+                               atol=1e-5)
     np.testing.assert_allclose(np.asarray(y_ker), np.asarray(y_ref),
                                rtol=1e-4, atol=1e-4)
 
@@ -133,7 +143,7 @@ def test_kv_decode_attention_kernel(b, s, kh, r, d, bs):
     ln = jnp.int32(s - 17)
     o_ref = kv_decode_attention_ref(q, k_i8, k_sc, v_i8, v_sc, ln)
     o_ker = ops.kv_decode_attention(q, k_i8, k_sc, v_i8, v_sc, ln,
-                                    block_s=bs, interpret=True)
+                                    block_s=bs)
     np.testing.assert_allclose(np.asarray(o_ker), np.asarray(o_ref),
                                rtol=1e-4, atol=1e-4)
 

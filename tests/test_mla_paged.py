@@ -20,6 +20,7 @@ except ImportError:                       # pragma: no cover
     from _hyp import given, settings, st
 
 from repro.configs import get_config
+from _engine_utils import engine_kernels
 from repro.engine import EngineConfig, InferenceEngine, SamplingParams
 from repro.kernels import ops, ref as kref
 from repro.models import transformer as T
@@ -150,7 +151,7 @@ def _latent_case(seed, b, t, h, dl, v_rank, ps, mp, num_pages,
     assert num_pages >= b * mp
     g = np.random.default_rng(seed)
     q = jnp.asarray(g.normal(size=(b, t, h, dl)), dtype)
-    lat = jnp.asarray(g.normal(size=(num_pages, ps, dl)), dtype)
+    lat = jnp.asarray(g.normal(size=(1, num_pages, ps, dl)), dtype)
     pages = g.permutation(num_pages)[:b * mp].reshape(b, mp).astype(np.int32)
     occ = g.integers(1, mp + 1, size=b)
     bt = np.where(np.arange(mp)[None, :] < occ[:, None], pages, num_pages)
@@ -169,8 +170,7 @@ def test_latent_kernel_matches_reference(t, dl, v_rank):
                                        v_rank=v_rank, ps=8, mp=4,
                                        num_pages=16)
     o_ref = kref.paged_latent_attention_ref(q, lat, lengths, bt, v_rank)
-    o_ker = ops.paged_latent_attention(q, lat, lengths, bt, v_rank=v_rank,
-                                       use_pallas=True, interpret=True)
+    o_ker = ops.paged_latent_attention(q, lat, lengths, bt, v_rank=v_rank)
     assert o_ker.shape == (3, t, 4, v_rank)
     np.testing.assert_allclose(np.asarray(o_ker), np.asarray(o_ref),
                                rtol=1e-4, atol=1e-4)
@@ -181,8 +181,7 @@ def test_latent_kernel_bf16_pages():
                                        ps=8, mp=4, num_pages=12,
                                        dtype=jnp.bfloat16)
     o_ref = kref.paged_latent_attention_ref(q, lat, lengths, bt, 32)
-    o_ker = ops.paged_latent_attention(q, lat, lengths, bt, v_rank=32,
-                                       use_pallas=True, interpret=True)
+    o_ker = ops.paged_latent_attention(q, lat, lengths, bt, v_rank=32)
     np.testing.assert_allclose(np.asarray(o_ker), np.asarray(o_ref),
                                rtol=2e-2, atol=2e-2)
 
@@ -192,10 +191,9 @@ def test_latent_kernel_all_sentinel_slot_is_finite():
     implementations (same clamped page, masked identically)."""
     q, lat, lengths, bt = _latent_case(17, b=2, t=1, h=4, dl=40, v_rank=32,
                                        ps=8, mp=4, num_pages=16)
-    bt = bt.at[1].set(lat.shape[0])
+    bt = bt.at[1].set(lat.shape[1])
     o_ref = kref.paged_latent_attention_ref(q, lat, lengths, bt, 32)
-    o_ker = ops.paged_latent_attention(q, lat, lengths, bt, v_rank=32,
-                                       use_pallas=True, interpret=True)
+    o_ker = ops.paged_latent_attention(q, lat, lengths, bt, v_rank=32)
     assert np.isfinite(np.asarray(o_ker)).all()
     assert np.isfinite(np.asarray(o_ref)).all()
     np.testing.assert_allclose(np.asarray(o_ker), np.asarray(o_ref),
@@ -211,7 +209,7 @@ def test_latent_kernel_tree_ancestor_bitmaps():
     g = np.random.default_rng(23)
     b, h, dl, ps, mp, num_pages = 2, 4, 160, 4, 6, 20
     q = jnp.asarray(g.normal(size=(b, w, h, dl)), jnp.float32)
-    lat = jnp.asarray(g.normal(size=(num_pages, ps, dl)), jnp.float32)
+    lat = jnp.asarray(g.normal(size=(1, num_pages, ps, dl)), jnp.float32)
     pages = g.permutation(num_pages)[:b * mp].reshape(b, mp).astype(np.int32)
     need = -(-w // ps) + 1
     occ = g.integers(need, mp + 1, size=b)
@@ -225,8 +223,7 @@ def test_latent_kernel_tree_ancestor_bitmaps():
                                             anc=anc, anc_base=base,
                                             anc_window=w)
     o_ker = ops.paged_latent_attention(q, lat, lengths, bt, v_rank=140,
-                                       anc=anc, anc_base=base, anc_window=w,
-                                       use_pallas=True, interpret=True)
+                                       anc=anc, anc_base=base, anc_window=w)
     np.testing.assert_allclose(np.asarray(o_ker), np.asarray(o_ref),
                                rtol=1e-4, atol=1e-4)
 
@@ -241,25 +238,25 @@ def test_latent_dead_pages_never_change_output(seed, t, mp_extra):
     q, lat, lengths, bt = _latent_case(seed, b=2, t=t, h=2, dl=40,
                                        v_rank=32, ps=8, mp=3, num_pages=12)
     base = np.asarray(ops.paged_latent_attention(
-        q, lat, lengths, bt, v_rank=32, use_pallas=True, interpret=True))
+        q, lat, lengths, bt, v_rank=32))
     wide = jnp.concatenate(
-        [bt, jnp.full((2, mp_extra), lat.shape[0], jnp.int32)], axis=1)
+        [bt, jnp.full((2, mp_extra), lat.shape[1], jnp.int32)], axis=1)
     out_w = np.asarray(ops.paged_latent_attention(
-        q, lat, lengths, wide, v_rank=32, use_pallas=True, interpret=True))
+        q, lat, lengths, wide, v_rank=32))
     np.testing.assert_array_equal(out_w, base)
 
-    ps = lat.shape[1]
+    ps = lat.shape[2]
     bt_np = np.asarray(bt)
     lmax = np.asarray(lengths).max(axis=1)
-    seen = np.zeros((lat.shape[0],), bool)
+    seen = np.zeros((lat.shape[1],), bool)
     for i in range(2):
         flat = np.arange(bt_np.shape[1] * ps)
         live = bt_np[i][flat[flat < lmax[i]] // ps]
-        seen[live[live < lat.shape[0]]] = True
+        seen[live[live < lat.shape[1]]] = True
     noise = jnp.asarray(g.normal(size=lat.shape), lat.dtype)
-    lat2 = jnp.where(jnp.asarray(~seen)[:, None, None], noise, lat)
+    lat2 = jnp.where(jnp.asarray(~seen)[None, :, None, None], noise, lat)
     out_s = np.asarray(ops.paged_latent_attention(
-        q, lat2, lengths, bt, v_rank=32, use_pallas=True, interpret=True))
+        q, lat2, lengths, bt, v_rank=32))
     np.testing.assert_array_equal(out_s, base)
 
 
@@ -309,14 +306,15 @@ def _run_engine(seed, max_new, *, spec_k=0, spec_fanout=None, draft=None,
                 prompts_lens=(5, 9, 4)):
     from repro.core.model_compress import draft_layers
     cfg, api, params = _tiny()
-    eng = InferenceEngine(
-        cfg, params,
-        EngineConfig(num_slots=2, max_seq=24, page_size=4,
-                     num_pages=num_pages, spec_k=spec_k,
-                     spec_fanout=spec_fanout, use_pallas=use_pallas,
-                     spec_draft_layers=(draft_layers(cfg, profile)
-                                        if profile else None)),
-        GREEDY, draft_params=draft)
+    with engine_kernels(use_pallas):
+        eng = InferenceEngine(
+            cfg, params,
+            EngineConfig(num_slots=2, max_seq=24, page_size=4,
+                         num_pages=num_pages, spec_k=spec_k,
+                         spec_fanout=spec_fanout,
+                         spec_draft_layers=(draft_layers(cfg, profile)
+                                            if profile else None)),
+            GREEDY, draft_params=draft)
     prompts = _prompts(cfg.vocab, prompts_lens, seed=seed)
     rids = [eng.submit(p, max_new) for p in prompts]
     res = eng.run()

@@ -1,5 +1,5 @@
 """Fused paged-attention decode kernel vs the jnp dense-gather oracle
-(interpret mode, per the repo's off-TPU kernel convention): T=1 decode and
+(interpret mode: the CPU's way to run a kernel): T=1 decode and
 T=K+1 staircase verify, bf16/f32 and int8+scales pages, ragged lengths,
 GQA ratios, OOB-sentinel block tables, and the occupied-page clamp."""
 import jax
@@ -12,6 +12,7 @@ try:
 except ImportError:                       # pragma: no cover
     from _hyp import given, settings, st
 
+from _engine_utils import engine_kernels
 from repro.kernels import ops, ref as kref
 from repro.models.layers import quantize_kv, staircase_mask
 
@@ -24,8 +25,8 @@ def _case(seed, b, t, kh, r, d, ps, mp, num_pages, int8=False,
     assert num_pages >= b * mp
     g = np.random.default_rng(seed)
     q = jnp.asarray(g.normal(size=(b, t, kh * r, d)), dtype)
-    kp = jnp.asarray(g.normal(size=(num_pages, ps, kh, d)), dtype)
-    vp = jnp.asarray(g.normal(size=(num_pages, ps, kh, d)), dtype)
+    kp = jnp.asarray(g.normal(size=(kh, num_pages, ps, d)), dtype)
+    vp = jnp.asarray(g.normal(size=(kh, num_pages, ps, d)), dtype)
     pages = g.permutation(num_pages)[:b * mp].reshape(b, mp).astype(np.int32)
     occ = g.integers(1, mp + 1, size=b)                  # ragged occupancy
     bt = np.where(np.arange(mp)[None, :] < occ[:, None], pages,
@@ -36,6 +37,7 @@ def _case(seed, b, t, kh, r, d, ps, mp, num_pages, int8=False,
     if int8:
         kp, ksc = quantize_kv(kp.astype(jnp.float32))
         vp, vsc = quantize_kv(vp.astype(jnp.float32))
+        ksc, vsc = ksc[..., None], vsc[..., None]      # [KH, P, ps, 1]
     return (q, kp, vp, jnp.asarray(lengths.astype(np.int32)),
             jnp.asarray(bt), ksc, vsc)
 
@@ -43,8 +45,7 @@ def _case(seed, b, t, kh, r, d, ps, mp, num_pages, int8=False,
 def _run_both(case):
     q, kp, vp, lengths, bt, ksc, vsc = case
     o_ref = kref.paged_attention_ref(q, kp, vp, lengths, bt, ksc, vsc)
-    o_ker = ops.paged_decode_attention(q, kp, vp, lengths, bt, ksc, vsc,
-                                       use_pallas=True, interpret=True)
+    o_ker = ops.paged_decode_attention(q, kp, vp, lengths, bt, ksc, vsc)
     return np.asarray(o_ref), np.asarray(o_ker)
 
 
@@ -82,12 +83,10 @@ def test_paged_kernel_staircase_is_causal():
     own length — the staircase is exactly per-query causal masking."""
     q, kp, vp, lengths, bt, _, _ = _case(23, b=2, t=3, kh=2, r=2, d=32,
                                          ps=8, mp=4, num_pages=16)
-    o = ops.paged_decode_attention(q, kp, vp, lengths, bt,
-                                   use_pallas=True, interpret=True)
+    o = ops.paged_decode_attention(q, kp, vp, lengths, bt)
     for tt in range(3):
         o1 = ops.paged_decode_attention(
-            q[:, tt:tt + 1], kp, vp, lengths[:, tt:tt + 1], bt,
-            use_pallas=True, interpret=True)
+            q[:, tt:tt + 1], kp, vp, lengths[:, tt:tt + 1], bt)
         np.testing.assert_allclose(np.asarray(o[:, tt]),
                                    np.asarray(o1[:, 0]),
                                    rtol=1e-5, atol=1e-5)
@@ -99,7 +98,7 @@ def test_paged_kernel_all_sentinel_slot_is_finite():
     read the same clamped page, masked identically)."""
     q, kp, vp, lengths, bt, _, _ = _case(31, b=2, t=1, kh=2, r=2, d=32,
                                          ps=8, mp=4, num_pages=16)
-    bt = bt.at[1].set(kp.shape[0])                     # slot 1: no pages
+    bt = bt.at[1].set(kp.shape[1])                     # slot 1: no pages
     o_ref, o_ker = _run_both((q, kp, vp, lengths, bt, None, None))
     assert np.isfinite(o_ker).all() and np.isfinite(o_ref).all()
     np.testing.assert_allclose(o_ker, o_ref, rtol=1e-4, atol=1e-4)
@@ -122,36 +121,33 @@ def test_padding_pages_never_change_output(seed, t, mp_extra, int8):
                                              d=32, ps=8, mp=3,
                                              num_pages=12, int8=int8)
     base = np.asarray(ops.paged_decode_attention(
-        q, kp, vp, lengths, bt, ksc, vsc, use_pallas=True, interpret=True))
+        q, kp, vp, lengths, bt, ksc, vsc))
 
     # 1) widen the table with sentinel columns
     wide = jnp.concatenate(
-        [bt, jnp.full((2, mp_extra), kp.shape[0], jnp.int32)], axis=1)
+        [bt, jnp.full((2, mp_extra), kp.shape[1], jnp.int32)], axis=1)
     out_w = np.asarray(ops.paged_decode_attention(
-        q, kp, vp, lengths, wide, ksc, vsc, use_pallas=True,
-        interpret=True))
+        q, kp, vp, lengths, wide, ksc, vsc))
     np.testing.assert_array_equal(out_w, base)
 
     # 2) scribble over every (page, offset) no query can see
     b, mp = bt.shape
-    ps = kp.shape[1]
+    n_pages, ps = kp.shape[1], kp.shape[2]
     flat_pos = np.arange(mp * ps)
     lmax = np.asarray(lengths).max(axis=1)
-    dead = np.zeros((kp.shape[0],), bool)
-    seen = np.zeros((kp.shape[0],), bool)
+    seen = np.zeros((n_pages,), bool)
     bt_np = np.asarray(bt)
     for i in range(b):
         live = bt_np[i][flat_pos[flat_pos < lmax[i]] // ps]
-        seen[live[live < kp.shape[0]]] = True
+        seen[live[live < n_pages]] = True
     dead = ~seen
     noise = g.normal(size=kp.shape)
-    kp2 = jnp.where(jnp.asarray(dead)[:, None, None, None],
+    kp2 = jnp.where(jnp.asarray(dead)[None, :, None, None],
                     jnp.asarray(noise, kp.dtype), kp)
-    vp2 = jnp.where(jnp.asarray(dead)[:, None, None, None],
+    vp2 = jnp.where(jnp.asarray(dead)[None, :, None, None],
                     jnp.asarray(noise[::-1], vp.dtype), vp)
     out_s = np.asarray(ops.paged_decode_attention(
-        q, kp2, vp2, lengths, bt, ksc, vsc, use_pallas=True,
-        interpret=True))
+        q, kp2, vp2, lengths, bt, ksc, vsc))
     np.testing.assert_array_equal(out_s, base)
 
 
@@ -237,9 +233,10 @@ def test_engine_pallas_matches_reference_outputs(tiny):
         0, cfg.vocab, size=4 + s).astype(np.int32) for s in range(3)]
 
     def run(use_pallas):
-        eng = InferenceEngine(
-            cfg, params, EngineConfig(num_slots=2, max_seq=16, page_size=4,
-                                      use_pallas=use_pallas))
+        with engine_kernels(use_pallas):
+            eng = InferenceEngine(
+                cfg, params, EngineConfig(num_slots=2, max_seq=16,
+                                          page_size=4))
         rids = [eng.submit(p, 4) for p in prompts]
         res = eng.run()
         return {r["rid"]: list(r["tokens"]) for r in res["results"]}, rids
@@ -261,14 +258,15 @@ def test_spec_greedy_lossless_with_kernel_path(tiny):
         0, cfg.vocab, size=4 + s).astype(np.int32) for s in range(3)]
 
     def run(spec_k):
-        eng = InferenceEngine(
-            cfg, params,
-            EngineConfig(num_slots=2, max_seq=24, page_size=4,
-                         use_pallas=True, spec_k=spec_k,
-                         spec_draft_layers=(draft_layers(cfg, "w4l50")
-                                            if spec_k else None)),
-            SamplingParams(),
-            draft_params=draft if spec_k else None)
+        with engine_kernels(True):
+            eng = InferenceEngine(
+                cfg, params,
+                EngineConfig(num_slots=2, max_seq=24, page_size=4,
+                             spec_k=spec_k,
+                             spec_draft_layers=(draft_layers(cfg, "w4l50")
+                                                if spec_k else None)),
+                SamplingParams(),
+                draft_params=draft if spec_k else None)
         rids = [eng.submit(p, 5) for p in prompts]
         res = eng.run()
         return {r["rid"]: list(r["tokens"]) for r in res["results"]}, rids
@@ -293,8 +291,8 @@ def _tree_case(seed, b, fanout, kh, r, d, ps, mp, num_pages, int8=False):
     g = np.random.default_rng(seed)
     t = w
     q = jnp.asarray(g.normal(size=(b, t, kh * r, d)), jnp.float32)
-    kp = jnp.asarray(g.normal(size=(num_pages, ps, kh, d)), jnp.float32)
-    vp = jnp.asarray(g.normal(size=(num_pages, ps, kh, d)), jnp.float32)
+    kp = jnp.asarray(g.normal(size=(kh, num_pages, ps, d)), jnp.float32)
+    vp = jnp.asarray(g.normal(size=(kh, num_pages, ps, d)), jnp.float32)
     pages = g.permutation(num_pages)[:b * mp].reshape(b, mp).astype(np.int32)
     need = -(-w // ps) + 1                       # pages the window spans
     occ = g.integers(need, mp + 1, size=b)
@@ -306,6 +304,7 @@ def _tree_case(seed, b, fanout, kh, r, d, ps, mp, num_pages, int8=False):
     if int8:
         kp, ksc = quantize_kv(kp.astype(jnp.float32))
         vp, vsc = quantize_kv(vp.astype(jnp.float32))
+        ksc, vsc = ksc[..., None], vsc[..., None]      # [KH, P, ps, 1]
     return (q, kp, vp, jnp.asarray(lengths), jnp.asarray(bt),
             jnp.asarray(anc), jnp.asarray(base.astype(np.int32)), w,
             ksc, vsc)
@@ -320,8 +319,7 @@ def test_tree_kernel_matches_tree_oracle(fanout, int8):
     o_ref = kref.tree_attention_ref(q, kp, vp, lengths, bt, anc, base, w,
                                     ksc, vsc)
     o_ker = ops.paged_decode_attention(q, kp, vp, lengths, bt, ksc, vsc,
-                                       anc=anc, anc_base=base, anc_window=w,
-                                       use_pallas=True, interpret=True)
+                                       anc=anc, anc_base=base, anc_window=w)
     np.testing.assert_allclose(np.asarray(o_ker), np.asarray(o_ref),
                                rtol=1e-4, atol=1e-4)
 
@@ -333,8 +331,7 @@ def test_tree_kernel_gqa_ratios(kh, r):
         num_pages=10)
     o_ref = kref.tree_attention_ref(q, kp, vp, lengths, bt, anc, base, w)
     o_ker = ops.paged_decode_attention(q, kp, vp, lengths, bt,
-                                       anc=anc, anc_base=base, anc_window=w,
-                                       use_pallas=True, interpret=True)
+                                       anc=anc, anc_base=base, anc_window=w)
     np.testing.assert_allclose(np.asarray(o_ker), np.asarray(o_ref),
                                rtol=1e-4, atol=1e-4)
 
@@ -342,11 +339,10 @@ def test_tree_kernel_gqa_ratios(kh, r):
 def test_tree_kernel_all_sentinel_slot_is_finite():
     q, kp, vp, lengths, bt, anc, base, w, _, _ = _tree_case(
         29, b=2, fanout=(2, 2), kh=2, r=2, d=32, ps=4, mp=6, num_pages=20)
-    bt = bt.at[1].set(kp.shape[0])                # slot 1: no pages
+    bt = bt.at[1].set(kp.shape[1])                # slot 1: no pages
     o_ref = kref.tree_attention_ref(q, kp, vp, lengths, bt, anc, base, w)
     o_ker = ops.paged_decode_attention(q, kp, vp, lengths, bt,
-                                       anc=anc, anc_base=base, anc_window=w,
-                                       use_pallas=True, interpret=True)
+                                       anc=anc, anc_base=base, anc_window=w)
     assert np.isfinite(np.asarray(o_ker)).all()
     np.testing.assert_allclose(np.asarray(o_ker), np.asarray(o_ref),
                                rtol=1e-4, atol=1e-4)
@@ -365,12 +361,10 @@ def test_tree_kernel_chain_bitmaps_bit_identical_to_staircase():
                     jnp.int32)[None, :], (2, w))
     # staircase lengths equivalent to base + bitmap windowing
     stair = (base[:, None] + 1 + jnp.arange(w)[None, :]).astype(jnp.int32)
-    o_plain = ops.paged_decode_attention(q, kp, vp, stair, bt,
-                                         use_pallas=True, interpret=True)
+    o_plain = ops.paged_decode_attention(q, kp, vp, stair, bt)
     o_tree = ops.paged_decode_attention(
         q, kp, vp, jnp.broadcast_to((base + w)[:, None], (2, w)), bt,
-        anc=chain_anc, anc_base=base, anc_window=w,
-        use_pallas=True, interpret=True)
+        anc=chain_anc, anc_base=base, anc_window=w)
     np.testing.assert_array_equal(np.asarray(o_tree), np.asarray(o_plain))
 
 
@@ -386,14 +380,15 @@ def test_spec_tree_greedy_lossless_with_kernel_path(tiny):
         0, cfg.vocab, size=4 + s).astype(np.int32) for s in range(3)]
 
     def run(fanout):
-        eng = InferenceEngine(
-            cfg, params,
-            EngineConfig(num_slots=2, max_seq=24, page_size=4,
-                         use_pallas=True, spec_fanout=fanout,
-                         spec_draft_layers=(draft_layers(cfg, "w4l50")
-                                            if fanout else None)),
-            SamplingParams(),
-            draft_params=draft if fanout else None)
+        with engine_kernels(True):
+            eng = InferenceEngine(
+                cfg, params,
+                EngineConfig(num_slots=2, max_seq=24, page_size=4,
+                             spec_fanout=fanout,
+                             spec_draft_layers=(draft_layers(cfg, "w4l50")
+                                                if fanout else None)),
+                SamplingParams(),
+                draft_params=draft if fanout else None)
         rids = [eng.submit(p, 5) for p in prompts]
         res = eng.run()
         return {r["rid"]: list(r["tokens"]) for r in res["results"]}, rids

@@ -15,11 +15,13 @@ try:
 except ImportError:                       # pragma: no cover
     from _hyp import given, settings, st
 
+from _engine_utils import engine_kernels
 from repro.configs import get_config
 from repro.core.model_compress import compress_draft, draft_layers
 from repro.engine import EngineConfig, InferenceEngine, SamplingParams
 from repro.engine.sampling import tree_verify
 from repro.engine.spec import TreeTemplate, compact_accepted
+from repro.engine.spec.tree import MAX_TREE_TOKENS
 from repro.models.registry import get_model
 
 GREEDY = SamplingParams()
@@ -96,9 +98,17 @@ def _ref_tree_walk(logits, feed, fanout, child_start):
     return len(fanout), toks
 
 
+def _fits_bitmap(fanout):
+    """Trees whose fed block fits the int32 ancestor bitmaps; (3, 3, 3)
+    needs 40 tokens and TreeTemplate refuses it."""
+    nodes = int(sum(np.prod(fanout[:i + 1]) for i in range(len(fanout))))
+    return nodes + 1 <= MAX_TREE_TOKENS
+
+
 @settings(max_examples=40, deadline=None)
 @given(st.integers(0, 2**31 - 1),
-       st.lists(st.integers(1, 3), min_size=1, max_size=3),
+       st.lists(st.integers(1, 3), min_size=1, max_size=3).filter(
+           _fits_bitmap),
        st.integers(4, 17))
 def test_tree_verify_greedy_property(seed, fanout, v):
     """For ANY logits/tree, greedy tree_verify emits exactly the
@@ -214,9 +224,9 @@ def test_compact_accepted_moves_path_and_drops_rest():
     move into the leading positions, other slots' pages and positions
     outside the tree block stay untouched, and invalid rows write
     nothing (sentinel drop)."""
-    L, P, ps, KH, D = 2, 6, 4, 1, 2
-    pool = jnp.arange(L * P * ps * KH * D, dtype=jnp.float32).reshape(
-        L, P, ps, KH, D)
+    L, KH, P, ps, D = 2, 1, 6, 4, 2
+    pool = jnp.arange(L * KH * P * ps * D, dtype=jnp.float32).reshape(
+        L, KH, P, ps, D)
     cache = {"k_pages": pool, "v_pages": pool * 10.0}
     bt = jnp.asarray([[0, 1, 2], [3, 4, 5]], jnp.int32)
     positions = jnp.asarray([2, 5], jnp.int32)
@@ -233,7 +243,7 @@ def test_compact_accepted_moves_path_and_drops_rest():
         for i, src in enumerate((2, 5)):  # path -> pos+1+i
             sp_, so = flat(0, 2 + src)
             dp, do = flat(0, 2 + 1 + i)
-            ref[layer, dp, do] = np.asarray(pool)[layer, sp_, so]
+            ref[layer, :, dp, do] = np.asarray(pool)[layer, :, sp_, so]
     np.testing.assert_array_equal(np.asarray(out["k_pages"]), ref)
     np.testing.assert_array_equal(np.asarray(out["v_pages"]), ref * 10.0)
 
@@ -245,13 +255,14 @@ def test_compact_accepted_moves_path_and_drops_rest():
 def _run_spec_engine(seed, max_new, profile, *, spec_k=0, spec_fanout=None,
                      adaptive=False, use_pallas=False):
     cfg, api, params = _tiny()
-    eng = InferenceEngine(
-        cfg, params,
-        EngineConfig(num_slots=2, max_seq=24, page_size=4,
-                     spec_k=spec_k, spec_fanout=spec_fanout,
-                     spec_adaptive=adaptive, use_pallas=use_pallas,
-                     spec_draft_layers=draft_layers(cfg, profile)),
-        GREEDY, draft_params=_draft(profile))
+    with engine_kernels(use_pallas):
+        eng = InferenceEngine(
+            cfg, params,
+            EngineConfig(num_slots=2, max_seq=24, page_size=4,
+                         spec_k=spec_k, spec_fanout=spec_fanout,
+                         spec_adaptive=adaptive,
+                         spec_draft_layers=draft_layers(cfg, profile)),
+            GREEDY, draft_params=_draft(profile))
     prompts = _prompts(cfg.vocab, (5, 9, 4), seed=seed)
     rids = [eng.submit(p, max_new) for p in prompts]
     res = eng.run()
