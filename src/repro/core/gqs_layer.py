@@ -56,14 +56,19 @@ class GQSAConfig:
 
 
 def apply_linear(p: Dict, x: jnp.ndarray, *, qcfg: Optional[QuantConfig] = None,
-                 use_pallas: bool = False) -> jnp.ndarray:
-    """x: [..., K] -> [..., N]; dispatch on the parameter representation."""
+                 use_pallas: bool = False, label: str = "") -> jnp.ndarray:
+    """x: [..., K] -> [..., N]; dispatch on the parameter representation.
+    ``label`` (static: ``wq`` ... ``wd``) names the GQSA kernel of this
+    linear in a device trace (``gqsa_gemv_<label>``)."""
     lead = x.shape[:-1]
     k = x.shape[-1]
     x2 = x.reshape(-1, k)
     if isinstance(p, dict) and "bsr" in p:
-        gemv = kops.gqsa_gemv if use_pallas else kref.gqsa_gemv_ref
-        y = gemv(x2, p["bsr"]).astype(x.dtype)
+        if use_pallas:
+            y = kops.gqsa_gemv(x2, p["bsr"], label)
+        else:
+            y = kref.gqsa_gemv_ref(x2, p["bsr"])
+        y = y.astype(x.dtype)
     elif isinstance(p, dict) and "qw" in p:
         mm = kops.w4_matmul if use_pallas else kref.w4_matmul_ref
         y = mm(x2, p["qw"], p["scale"], p["zero"]).astype(x.dtype)

@@ -31,7 +31,11 @@ is the previous chunk's end — while the other slots keep decoding
 The decode loop itself runs *two-deep*: each segment's boundary sync
 waits on the PREVIOUS segment's tokens (a trailing copy), so the host
 schedules segment N+1 while N still executes and issues strictly fewer
-``block_until_ready`` calls than segments dispatched.
+``block_until_ready`` calls than segments dispatched. A request whose
+budget runs out is evicted (and its slot refilled) at the boundary where
+its last segment is dispatched, but its finish is stamped when the host
+sees that segment's tokens ready: at the sync that retires it, or at the
+drain when ``run`` ends.
 """
 from __future__ import annotations
 
@@ -262,6 +266,14 @@ class InferenceEngine:
         self._c_chunk_tokens = reg.counter("engine.prefill_chunk_tokens")
         self._c_midprefill_preempt = reg.counter(
             "resil.midprefill_preemptions")
+        # work as dispatched: the rows every prefill dispatch computes
+        # (padding included) against the real prompt tokens it feeds,
+        # and the KV pages each plain decode step's attention grid spans
+        # (slots x max_live) against those that hold keys
+        self._c_prefill_rows = reg.counter("engine.prefill_rows")
+        self._c_prefill_tokens = reg.counter("engine.prefill_tokens")
+        self._c_pages_spanned = reg.counter("engine.decode_pages_spanned")
+        self._c_pages_live = reg.counter("engine.decode_pages_live")
         self._ladder_rung: Optional[int] = None
         self.rcfg = engine_cfg.resilience if engine_cfg.resilience \
             is not None else ResilienceConfig()
@@ -285,11 +297,13 @@ class InferenceEngine:
         self._max_live = self.kv.max_pages_per_slot    # static, pow2-bucketed
         self._source = None              # timed-admission stream, run() only
         # two-deep dispatch (DESIGN.md §14): token arrays of decode
-        # segments dispatched but not yet synced. Each boundary retires
-        # the PREVIOUS segment (trailing copy) and leaves the one just
+        # segments dispatched but not yet synced, each with the rids of
+        # the requests whose last token it holds (their finish is
+        # stamped when it is retired). Each boundary retires the
+        # PREVIOUS segment (trailing copy) and leaves the one just
         # dispatched in flight — at most one entry deep, so the host is
         # always scheduling segment N+1 while N executes.
-        self._inflight: Deque[jnp.ndarray] = deque()
+        self._inflight: Deque[Tuple[jnp.ndarray, List[int]]] = deque()
         self._token_log: List[jnp.ndarray] = []        # [B] arrays, lazy
         # spec mode log: (tokens [B, W], counts [B]) per prefill/round
         self._spec_log: List = []
@@ -424,17 +438,17 @@ class InferenceEngine:
                     finished = self._inject_nan(actives, finished,
                                                 pre_prod)
                 t = self.metrics.now()
-                with tracer.span("evict") as sp:
+                with tracer.span("evict", evicted=len(finished)):
                     for r in finished:
-                        self.metrics.record_finish(r.rid, t, r.produced)
+                        self.metrics.count_finish(r.rid, r.produced)
                         sch.finish(r)
                         if source is not None:
                             source.on_finish(t - t0)
                         # an evicted slot's acceptance history dies with it
                         self._accept_ewma[r.slot] = self.SPEC_EWMA_INIT
+                    self._finish_at_completion([r.rid for r in finished])
                     if finished:
                         self._sync_slot_state()
-                    sp.set(evicted=len(finished))
                 self.tel.maybe_stats(self.metrics)
         except KeyboardInterrupt:
             # graceful shutdown (DESIGN.md §12): shed the queue, account
@@ -443,6 +457,7 @@ class InferenceEngine:
             interrupted = True
             self._drain_on_interrupt()
         self.metrics.run_finished()
+        self._retire_inflight()
         out = {"results": self._materialize(), "metrics":
                self.metrics.summary()}
         if interrupted:
@@ -516,10 +531,13 @@ class InferenceEngine:
             self.metrics.record_shed(r.rid, t, "shutdown")
             if self._source is not None:
                 self._source.on_finish(t - t0)
+        done = []
         for r in list(sch.active()):
             if r.state == DECODE and r.produced > 0:
-                self.metrics.record_finish(r.rid, t, r.produced)
+                self.metrics.count_finish(r.rid, r.produced)
+                done.append(r.rid)
             sch.finish(r)
+        self._finish_at_completion(done)
 
     def _request_tokens(self, r: Request) -> np.ndarray:
         """Materialize the tokens ``r`` generated since its last fold
@@ -614,6 +632,53 @@ class InferenceEngine:
                     time.sleep(chaos.cfg.device_backoff_s
                                * (2 ** (attempt - 1)))
 
+    def _count_decode_pages(self, actives: List[Request], seg: int) -> None:
+        """Count the KV pages ``seg`` plain decode steps span (every
+        slot's row of the block tables, clamped to ``max_live``) and
+        those that hold keys once each step has written: a slot writing
+        at position p holds ceil((p + 1) / page_size) pages. In closed
+        form per slot, since every active slot runs all ``seg`` steps."""
+        ps = self.ecfg.page_size
+
+        def pages_upto(n: int) -> int:     # sum of ceil(m / ps), m = 1..n
+            q, rem = divmod(n, ps)
+            return ps * q * (q + 1) // 2 + rem * (q + 1)
+        live = 0
+        for r in actives:
+            p0 = self.scheduler.slots[r.slot].position
+            live += pages_upto(p0 + seg) - pages_upto(p0)
+        self._c_pages_live.inc(live)
+        self._c_pages_spanned.inc(seg * self.ecfg.num_slots * self._max_live)
+
+    def _stamp_finishes(self, rids: List[int]) -> None:
+        """Stamp the finish of requests whose last token the host has
+        just seen ready."""
+        if rids:
+            t = self.metrics.now()
+            for rid in rids:
+                self.metrics.stamp_finish(rid, t)
+
+    def _finish_at_completion(self, rids: List[int]) -> None:
+        """Requests evicted at this boundary finish when their last
+        token exists: with the last decode segment's tokens in flight,
+        their stamp waits for the sync that retires it; with nothing in
+        flight (a spec segment's own sync, a prefill's) it is now."""
+        if self._inflight:
+            self._inflight[-1][1].extend(rids)
+        else:
+            self._stamp_finishes(rids)
+
+    def _retire_inflight(self) -> None:
+        """The drain when ``run`` ends: read the token arrays still in
+        flight to the host (materialization reads them anyway) and stamp
+        the finishes waiting on them."""
+        if not self._inflight:
+            return
+        jax.device_get([toks for toks, _ in self._inflight])
+        self._stamp_finishes([rid for _, rids in self._inflight
+                              for rid in rids])
+        self._inflight.clear()
+
     def _decode_segment(self, actives: List[Request],
                         max_steps: Optional[int] = None) -> List[Request]:
         """Plain decode segment: no slot can exceed its budget before the
@@ -641,37 +706,37 @@ class InferenceEngine:
         seg = max(1, min(r.remaining for r in actives))
         if max_steps is not None:
             seg = min(seg, max_steps)
+        self._count_decode_pages(actives, seg)
         finished: List[Request] = []
-        with tracer.span("decode_segment") as seg_sp:
-            with tracer.annotate("decode_segment"):
-                for _ in range(seg):
-                    self._tokens, self._positions, self.kv.data, \
-                        self._rng = self._dispatch(
-                            self._decode_fn,
-                            self.params, self.kv.data, self._tokens,
-                            self._positions, self._block_tables,
-                            self._active, self._rng, self._max_live)
-                    if self.spec:
-                        idx = self._log_spec(self._tokens[:, None],
-                                             self._active)
-                    else:
-                        idx = len(self._token_log)
-                        self._token_log.append(self._tokens)
-                    for r in sch.active():
-                        if r.state == DECODE:
-                            r.log_entries.append(idx)
-                    finished.extend(sch.step_decoded())
-            self._inflight.append(self._tokens)
-            if len(self._inflight) > 1:
-                with tracer.span("sync", cat="sync"):
-                    while len(self._inflight) > 1:
-                        jax.block_until_ready(self._inflight.popleft())
-            seg_sp.set(steps=seg, slots=len(actives),
-                       tokens=seg * len(actives))
-            if tracer.enabled:
-                for r in actives:
-                    tracer.flow_point(r.rid, "decode_segment",
-                                      t=seg_sp.t0)
+        with tracer.span("decode_segment", steps=seg, slots=len(actives),
+                         tokens=seg * len(actives)) as seg_sp:
+            for _ in range(seg):
+                self._tokens, self._positions, self.kv.data, \
+                    self._rng = self._dispatch(
+                        self._decode_fn,
+                        self.params, self.kv.data, self._tokens,
+                        self._positions, self._block_tables,
+                        self._active, self._rng, self._max_live)
+                if self.spec:
+                    idx = self._log_spec(self._tokens[:, None],
+                                         self._active)
+                else:
+                    idx = len(self._token_log)
+                    self._token_log.append(self._tokens)
+                for r in sch.active():
+                    if r.state == DECODE:
+                        r.log_entries.append(idx)
+                finished.extend(sch.step_decoded())
+        self._inflight.append((self._tokens, []))
+        if len(self._inflight) > 1:
+            with tracer.span("sync", cat="sync"):
+                while len(self._inflight) > 1:
+                    toks, rids = self._inflight.popleft()
+                    jax.block_until_ready(toks)
+                    self._stamp_finishes(rids)
+        if tracer.enabled:
+            for r in actives:
+                tracer.flow_point(r.rid, "decode_segment", t=seg_sp.t0)
         self.metrics.decode_steps += seg
         self.metrics.record_decode_segment(self.metrics.now() - t0,
                                            seg * len(actives))
@@ -732,15 +797,13 @@ class InferenceEngine:
                 # segment stays sync-free, so they time async enqueue,
                 # not device work — the device side comes from the
                 # profiler annotations / named scopes
-                with tracer.span("draft", cat="dispatch"), \
-                        tracer.annotate("draft"):
+                with tracer.span("draft", cat="dispatch"):
                     draft = self._dispatch(
                         draft_fn,
                         self.draft_params, self.kv.data, self._tokens,
                         self._positions, self._block_tables,
                         self._max_live)
-                with tracer.span("verify", cat="dispatch"), \
-                        tracer.annotate("verify"):
+                with tracer.span("verify", cat="dispatch"):
                     (out, n_new, self._tokens, self._positions,
                      self._remaining, self.kv.data, self._rng) = \
                         self._dispatch(
@@ -759,6 +822,8 @@ class InferenceEngine:
             # segments sync at their own boundary — anything a plain
             # segment left in flight is older than this sync (one
             # device stream) and retires with it
+            self._stamp_finishes([rid for _, rids in self._inflight
+                                  for rid in rids])
             self._inflight.clear()
             seg_tokens = 0
             for idx in round_idxs:                     # replay the rounds
@@ -890,15 +955,17 @@ class InferenceEngine:
                 lengths[r.slot] = r.prompt_len
                 bt[r.slot] = self.kv.block_tables[r.slot]
                 mask[r.slot] = True
-            with tracer.span("prefill") as sp, tracer.annotate("prefill"):
+            with tracer.span("prefill", admitted=len(full), bucket=s,
+                             tokens=len(full),
+                             prompt_tokens=int(lengths.sum())) as sp:
                 first, self.kv.data, self._rng = self._dispatch(
                     self._prefill_fn,
                     self.params, self.kv.data, jnp.asarray(tokens),
                     jnp.asarray(lengths), jnp.asarray(bt), self._rng)
-                jax.block_until_ready(first)
-                sp.set(admitted=len(full), bucket=s,
-                       tokens=len(full),
-                       prompt_tokens=int(lengths.sum()))
+                self._c_prefill_rows.inc(b * s)
+                self._c_prefill_tokens.inc(int(lengths.sum()))
+                with tracer.span("sync", cat="sync"):
+                    jax.block_until_ready(first)
                 if tracer.enabled:
                     for r in full:
                         tracer.flow_point(r.rid, "prefill", t=sp.t0)
@@ -938,17 +1005,18 @@ class InferenceEngine:
             occ = int((bt != self.kv.sentinel).sum(1).max())
             max_live = min(_bucket(max(occ, 1), 1),
                            self.kv.max_pages_per_slot)
-            with tracer.span("prefill_tail") as sp, \
-                    tracer.annotate("prefill_tail"):
+            with tracer.span("prefill_tail", admitted=len(shared),
+                             bucket=t_pad, tail_tokens=int(feed.sum()),
+                             shared_tokens=hit_tokens) as sp:
                 first_t, self.kv.data, self._rng = self._dispatch(
                     self._tail_fn,
                     self.params, self.kv.data, jnp.asarray(toks),
                     jnp.asarray(starts), jnp.asarray(feed),
                     jnp.asarray(bt), self._rng, max_live)
-                jax.block_until_ready(first_t)
-                sp.set(admitted=len(shared), bucket=t_pad,
-                       tail_tokens=int(feed.sum()),
-                       shared_tokens=hit_tokens)
+                self._c_prefill_rows.inc(b * t_pad)
+                self._c_prefill_tokens.inc(int(feed.sum()))
+                with tracer.span("sync", cat="sync"):
+                    jax.block_until_ready(first_t)
                 if tracer.enabled:
                     for r in shared:
                         tracer.flow_point(r.rid, "prefill_tail", t=sp.t0)
@@ -1036,20 +1104,22 @@ class InferenceEngine:
         occ = int((bt != self.kv.sentinel).sum(1).max())
         max_live = min(_bucket(max(occ, 1), 1),
                        self.kv.max_pages_per_slot)
-        with tracer.span("prefill_chunk") as sp, \
-                tracer.annotate("prefill_chunk"):
+        with tracer.span("prefill_chunk", slots=len(chunking), bucket=t_pad,
+                         chunk_tokens=int(feed.sum()),
+                         completed=len(finals)) as sp:
             first_t, self.kv.data, self._rng = self._dispatch(
                 self._tail_fn,
                 self.params, self.kv.data, jnp.asarray(toks),
                 jnp.asarray(starts), jnp.asarray(feed),
                 jnp.asarray(bt), self._rng, max_live)
+            self._c_prefill_rows.inc(b * t_pad)
+            self._c_prefill_tokens.inc(int(feed.sum()))
             if finals:
                 # completed prefills take their TTFT timestamp here, so
                 # the first token must actually exist (same convention
                 # as the monolithic prefill block)
-                jax.block_until_ready(first_t)
-            sp.set(slots=len(chunking), bucket=t_pad,
-                   chunk_tokens=int(feed.sum()), completed=len(finals))
+                with tracer.span("sync", cat="sync"):
+                    jax.block_until_ready(first_t)
             if tracer.enabled:
                 for r in chunking:
                     tracer.flow_point(r.rid, "prefill_chunk", t=sp.t0)
@@ -1121,34 +1191,38 @@ class InferenceEngine:
         # kv.block_tables is mutated in place by assign/release — an
         # aliased device view would change under still-in-flight steps
         # (the old loop's per-boundary block_until_ready hid this)
-        bts = self.kv.block_tables.copy()
-        mid = [i for i, s in enumerate(self.scheduler.slots)
-               if s.request is not None
-               and s.request.state == PREFILLING]
-        if mid:
-            bts[mid, :] = self.kv.sentinel
-        self._block_tables = jnp.asarray(bts)
-        # static clamp for the decode-side page gather / kernel grid: the
-        # batch's max occupied page count, pow2-bucketed so the jitted
-        # steps retrace at most log2(max_pages_per_slot) times
-        occ = int((self.kv.block_tables != self.kv.sentinel).sum(1).max())
-        new_max_live = min(_bucket(max(occ, 1), 1),
-                           self.kv.max_pages_per_slot)
-        if new_max_live != self._max_live:
-            # max_live is a static jit arg: every change retraces the
-            # decode/draft/verify steps (pow2-bucketed, so bounded by
-            # log2(max_pages_per_slot) over an engine lifetime)
-            self._c_retraces.inc()
-            self.tel.tracer.instant("jit_retrace", max_live=new_max_live)
-        self._max_live = new_max_live
-        act = np.zeros((self.ecfg.num_slots,), np.int32)
-        rem = np.zeros((self.ecfg.num_slots,), np.int32)
-        for i, slot in enumerate(self.scheduler.slots):
-            if slot.request is not None and slot.request.state == DECODE:
-                act[i] = 1
-                rem[i] = slot.request.remaining
-        self._active = jnp.asarray(act)
-        self._remaining = jnp.asarray(rem)
+        with self.tel.tracer.span("slot_sync"):
+            bts = self.kv.block_tables.copy()
+            mid = [i for i, s in enumerate(self.scheduler.slots)
+                   if s.request is not None
+                   and s.request.state == PREFILLING]
+            if mid:
+                bts[mid, :] = self.kv.sentinel
+            self._block_tables = jnp.asarray(bts)
+            # static clamp for the decode-side page gather / kernel grid:
+            # the batch's max occupied page count, pow2-bucketed so the
+            # jitted steps retrace at most log2(max_pages_per_slot) times
+            occ = int((self.kv.block_tables
+                       != self.kv.sentinel).sum(1).max())
+            new_max_live = min(_bucket(max(occ, 1), 1),
+                               self.kv.max_pages_per_slot)
+            if new_max_live != self._max_live:
+                # max_live is a static jit arg: every change retraces the
+                # decode/draft/verify steps (pow2-bucketed, so bounded by
+                # log2(max_pages_per_slot) over an engine lifetime)
+                self._c_retraces.inc()
+                self.tel.tracer.instant("jit_retrace",
+                                        max_live=new_max_live)
+            self._max_live = new_max_live
+            act = np.zeros((self.ecfg.num_slots,), np.int32)
+            rem = np.zeros((self.ecfg.num_slots,), np.int32)
+            for i, slot in enumerate(self.scheduler.slots):
+                if slot.request is not None \
+                        and slot.request.state == DECODE:
+                    act[i] = 1
+                    rem[i] = slot.request.remaining
+            self._active = jnp.asarray(act)
+            self._remaining = jnp.asarray(rem)
 
     def _materialize(self) -> List[Dict]:
         """One host sync: stack the token log and slice every request's

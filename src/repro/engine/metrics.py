@@ -1,8 +1,18 @@
 """Serving metrics: TTFT, TPOT, tokens/s, p50/p99 request latency.
 
-Timestamps are taken at *synchronization points* of the engine loop
-(after the prefill block and after each decode segment's block), so they
-measure completed device work, not async dispatch.
+Every request timestamp is taken where the host has seen the work it
+marks completed, never at an async dispatch:
+
+* ``first_token_t``: after the prefill dispatch's ``block_until_ready``
+  (for chunked prefill, the final chunk's);
+* ``finish_t``: when the token array of the request's last decode
+  segment is retired — at the boundary sync that waits on it (two-deep
+  dispatch retires the PREVIOUS segment, DESIGN.md §14), at a spec
+  segment's own sync, or at the drain when ``run`` ends. The engine
+  counts the request finished (``count_finish``) at the boundary where
+  it evicts it, and stamps it (``stamp_finish``) at that retirement, so
+  TPOT and latency measure completed decode work;
+* ``admit_t`` / ``enqueue_t``: host events, no device work.
 
 Rebased on the telemetry registry (DESIGN.md §10): every aggregate is a
 registry counter and every latency distribution a streaming log-bucketed
@@ -179,13 +189,25 @@ class EngineMetrics:
             self.tracer.flow_point(rid, "shed", t=t, final=True)
 
     def record_finish(self, rid: int, t: float, n_generated: int) -> None:
-        rt = self.requests[rid]
-        rt.finish_t = t
-        rt.n_generated = n_generated
+        """A request finished with its last token already seen ready at
+        ``t``: count it and stamp it."""
+        self.count_finish(rid, n_generated)
+        self.stamp_finish(rid, t)
+
+    def count_finish(self, rid: int, n_generated: int) -> None:
+        """Count a finished request when it is evicted; its stamp waits
+        for :meth:`stamp_finish`."""
+        self.requests[rid].n_generated = n_generated
         self._c_finished.inc()
         self._c_tokens.inc(n_generated)
+
+    def stamp_finish(self, rid: int, t: float) -> None:
+        """Stamp a counted request's completion: ``t`` is when the host
+        saw its last token ready."""
+        rt = self.requests[rid]
+        rt.finish_t = t
         self._h_latency.record(rt.latency_s * 1e3)
-        if n_generated > 1:
+        if rt.n_generated > 1:
             self._h_tpot.record(rt.tpot_s * 1e3)
         if self.tracer.enabled:
             self.tracer.flow_point(rid, "finish", t=t, final=True)

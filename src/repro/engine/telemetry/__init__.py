@@ -2,17 +2,18 @@
 
 Three pieces, one facade:
 
-* :class:`SpanTracer` — named phase spans at the engine's existing sync
-  points, exported as Chrome trace-event JSON (Perfetto-loadable), with
-  per-request flow events tying enqueue -> prefill -> segments -> finish
-  together across slices.
+* :class:`SpanTracer` — named phase spans of the engine loop, exported
+  as Chrome trace-event JSON (Perfetto-loadable) and, while open, as
+  ``jax.profiler.TraceAnnotation``s of the same name on the profiler's
+  clock, with per-request flow events tying enqueue -> prefill ->
+  segments -> finish together across slices.
 * :class:`MetricsRegistry` — counters, gauges and streaming log-bucketed
   histograms (quantiles without storing samples) shared by the KV cache,
   scheduler, spec ladder and :class:`~repro.engine.metrics.EngineMetrics`.
-* profiler hooks — ``tracer.annotate`` wraps jitted dispatches in
-  ``jax.profiler.TraceAnnotation`` (and the step functions themselves
-  carry ``jax.named_scope`` phase names) so device traces line up with
-  the host spans.
+* device alignment — the spans land on the profiler's host plane, the
+  step functions carry ``jax.named_scope`` phase names, and each GQSA
+  GEMV kernel is named by its linear (``gqsa_gemv_wq`` ...), so a
+  device trace lines up with the host spans.
 
 Everything is off by default and adds no device syncs either way::
 
@@ -46,10 +47,8 @@ class Telemetry:
 
     def __init__(self, trace: bool = False,
                  registry: Optional[MetricsRegistry] = None,
-                 stats_interval_s: float = 0.0,
-                 annotate_device: Optional[bool] = None):
-        self.tracer = SpanTracer(enabled=trace,
-                                 annotate_device=annotate_device)
+                 stats_interval_s: float = 0.0):
+        self.tracer = SpanTracer(enabled=trace)
         self.registry = registry if registry is not None else \
             MetricsRegistry()
         self.stats_interval_s = float(stats_interval_s)

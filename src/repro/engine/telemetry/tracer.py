@@ -1,14 +1,24 @@
 """Phase-span tracer with Chrome trace-event export (Perfetto-loadable).
 
-The engine loop is host-driven and syncs only at segment boundaries
-(DESIGN.md §3), so the tracer records two honest kinds of host span:
+One span API, two clocks: every ``span`` records a complete event in
+the Chrome JSON (``perf_counter``) and, while it is open, a
+``jax.profiler.TraceAnnotation`` of the same name, so a profiler trace
+of the device carries the engine's spans on its own clock (args given at
+``span(...)`` become the annotation's stats; ``set`` adds to the JSON
+only). The engine loop is host-driven and syncs only at scheduling
+boundaries (DESIGN.md §3), so what a span measures depends on where it
+closes:
 
-* spans that END at an existing ``block_until_ready`` (``prefill``,
-  ``decode_segment``/``spec_segment``, ``sync``) measure *completed
-  device work* — the same convention ``EngineMetrics`` timestamps use;
-* spans inside a segment (``draft``, ``verify`` rounds) bracket only the
-  *dispatch* — they carry ``cat: "dispatch"`` so a trace reader knows
-  the device work completes later, at the segment's ``sync`` span.
+* ``prefill`` / ``prefill_tail`` / ``prefill_chunk`` hold their own
+  ``block_until_ready`` (a ``sync`` child span): they END when the first
+  token exists, so they measure completed device work;
+* ``decode_segment``, ``draft`` and ``verify`` bracket only the
+  *dispatch* (``draft``/``verify`` carry ``cat: "dispatch"``): the
+  device work completes later, at a ``sync`` span (a decode segment's
+  boundary retires the PREVIOUS segment, DESIGN.md §14);
+* ``spec_segment`` holds its own boundary ``sync``;
+* ``admit``, ``evict`` and ``slot_sync`` (the host-to-device copies of
+  the slot state) are host work between dispatches.
 
 The tracer NEVER forces a sync of its own: enabling it changes
 timestamps taken, not the dispatch structure (pinned by a test counting
@@ -21,11 +31,11 @@ both render as arrows/tracks in Perfetto (load the JSON at
 https://ui.perfetto.dev or chrome://tracing).
 
 When disabled (the default), every hook returns a shared no-op span and
-records nothing — zero per-segment overhead beyond one attribute check.
+records nothing — no ``TraceAnnotation`` is constructed, and the cost
+per hook is one attribute check.
 """
 from __future__ import annotations
 
-import contextlib
 import json
 import time
 from pathlib import Path
@@ -62,7 +72,7 @@ class _Span:
     """Live span: ``set(**args)`` attaches args (token counts etc.) any
     time before exit; the complete event is recorded on ``__exit__``."""
 
-    __slots__ = ("_tr", "name", "tid", "cat", "args", "t0")
+    __slots__ = ("_tr", "name", "tid", "cat", "args", "t0", "_ann")
 
     def __init__(self, tracer: "SpanTracer", name: str, tid: int, cat: str,
                  args: Optional[dict]):
@@ -72,30 +82,30 @@ class _Span:
         self.cat = cat
         self.args = dict(args) if args else {}
         self.t0 = 0.0
+        self._ann = None
 
     def set(self, **args):
         self.args.update(args)
 
     def __enter__(self):
+        # a TraceAnnotation's event starts when it is constructed, not
+        # when entered, so it is made here
+        self._ann = TraceAnnotation(self.name, **self.args)
+        self._ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        self._tr.record_span(self.name, self.t0, time.perf_counter(),
+        t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        self._tr.record_span(self.name, self.t0, t1,
                              tid=self.tid, cat=self.cat, args=self.args)
         return False
 
 
 class SpanTracer:
-    def __init__(self, enabled: bool = False,
-                 annotate_device: Optional[bool] = None):
+    def __init__(self, enabled: bool = False):
         self.enabled = bool(enabled)
-        # jax.profiler.TraceAnnotation wrapping of the jitted dispatches:
-        # rides the same flag by default so host spans and device traces
-        # line up whenever a trace is being taken, and costs nothing
-        # when off (the profiler hooks are never constructed)
-        self.annotate_device = (self.enabled if annotate_device is None
-                                else bool(annotate_device))
         self._t0 = time.perf_counter()
         self.events: List[dict] = []
         self._flow_seen: set = set()
@@ -116,7 +126,8 @@ class SpanTracer:
 
     def span(self, name: str, tid: int = TID_ENGINE, cat: str = "phase",
              **args):
-        """Context manager recording one complete ('X') event."""
+        """Context manager recording one complete ('X') event, and a
+        ``TraceAnnotation`` of the same name while it is open."""
         if not self.enabled:
             return NULL_SPAN
         return _Span(self, name, tid, cat, args)
@@ -177,16 +188,6 @@ class SpanTracer:
             "name": name, "cat": "request", "ph": "e", "id": aid,
             "ts": self._us(t if t is not None else time.perf_counter()),
             "pid": 0, "tid": TID_REQUESTS, "args": {}})
-
-    # -- device-trace annotation ----------------------------------------
-
-    def annotate(self, name: str):
-        """``jax.profiler.TraceAnnotation`` around a dispatch so device
-        profiler traces carry the engine's phase names. No-op (shared
-        null span, nothing constructed) unless device annotation is on."""
-        if not (self.enabled and self.annotate_device):
-            return NULL_SPAN
-        return TraceAnnotation(name)
 
     # -- reading / export -----------------------------------------------
 

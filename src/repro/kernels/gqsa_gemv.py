@@ -78,10 +78,16 @@ def _kernel(work_ref,                                   # scalar prefetch
 def gqsa_gemv_pallas(x: jnp.ndarray, words: jnp.ndarray, scale: jnp.ndarray,
                      zero: jnp.ndarray, pos: jnp.ndarray, work: jnp.ndarray,
                      *, group_size: int, block_n: int, lane: int,
-                     block_t: int, interpret: bool = False) -> jnp.ndarray:
+                     block_t: int, label: str = "",
+                     interpret: bool = False) -> jnp.ndarray:
     """x: [T, G*Cp] in the layout above, T % block_t == 0. Returns
     [T, Np] f32. Items of one row block are consecutive in ``work``, so
     the output tile stays resident in VMEM until its last item writes it.
+
+    ``label`` names the linear (``wq`` ... ``wd``): the kernel is called
+    ``gqsa_gemv_<label>``, the one name a device trace keeps for it, so
+    a trace splits the kernel's time by linear (``gqsa_gemv`` alone
+    without a label).
     """
     t, kp = x.shape
     np_, mp = scale.shape
@@ -114,5 +120,5 @@ def gqsa_gemv_pallas(x: jnp.ndarray, words: jnp.ndarray, scale: jnp.ndarray,
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
-        name="gqsa_gemv",
+        name=f"gqsa_gemv_{label}" if label else "gqsa_gemv",
     )(work, words, scale, zero, pos, x)

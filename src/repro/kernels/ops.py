@@ -45,13 +45,15 @@ def _row_tile(t: int, cap: int) -> int:
     return min(cap, -(-t // 8) * 8)
 
 
-def gqsa_gemv(x: jnp.ndarray, bsr: BSRMatrix) -> jnp.ndarray:
+def gqsa_gemv(x: jnp.ndarray, bsr: BSRMatrix,
+              label: str = "") -> jnp.ndarray:
     """y = x @ dense(bsr).T with the task-centric sparse kernel.
 
     x: [T, K], any T (decode slots, verify rows or a prefill block: rows
     beyond DEFAULT_BLOCK_T tile the kernel's grid). The packed operands
     are used as laid out at pack time; only the activations are permuted
     to the kernel's group-column-major order. Returns [T, N] f32.
+    ``label`` (static) names the linear in the kernel's name.
     """
     t, k = x.shape
     n = bsr.shape[0]
@@ -63,7 +65,7 @@ def gqsa_gemv(x: jnp.ndarray, bsr: BSRMatrix) -> jnp.ndarray:
     y = gqsa_gemv_pallas(_pad_to(xg, 0, bt), bsr.words, bsr.scale, bsr.zero,
                          bsr.pos, bsr.work, group_size=g,
                          block_n=bsr.block_n, lane=bsr.lane, block_t=bt,
-                         interpret=_interpret())
+                         label=label, interpret=_interpret())
     return y[:t, :n]
 
 

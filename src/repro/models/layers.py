@@ -25,6 +25,13 @@ def linear_init(rng, n_out: int, n_in: int, dtype=jnp.float32,
     return {"w": w}
 
 
+def linear(p: Dict, name: str, x: jnp.ndarray,
+           use_pallas=False) -> jnp.ndarray:
+    """The linear ``p[name]`` of a block on ``x``, its GQSA kernel
+    named by ``name`` in a device trace (``gqsa_gemv_wq`` ...)."""
+    return apply_linear(p[name], x, use_pallas=use_pallas, label=name)
+
+
 def norm_init(dim: int, dtype=jnp.float32) -> jnp.ndarray:
     return jnp.ones((dim,), dtype)
 
@@ -426,9 +433,9 @@ def attn_qkv(p: Dict, x: jnp.ndarray, positions: jnp.ndarray, cfg,
              use_pallas=False):
     b, s, d = x.shape
     h, khn, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
-    q = apply_linear(p["wq"], x, use_pallas=use_pallas).reshape(b, s, h, hd)
-    k = apply_linear(p["wk"], x, use_pallas=use_pallas).reshape(b, s, khn, hd)
-    v = apply_linear(p["wv"], x, use_pallas=use_pallas).reshape(b, s, khn, hd)
+    q = linear(p, "wq", x, use_pallas).reshape(b, s, h, hd)
+    k = linear(p, "wk", x, use_pallas).reshape(b, s, khn, hd)
+    v = linear(p, "wv", x, use_pallas).reshape(b, s, khn, hd)
     if cfg.qk_norm:
         q = rmsnorm(q, p["q_norm"], cfg.norm_eps)
         k = rmsnorm(k, p["k_norm"], cfg.norm_eps)
@@ -451,7 +458,7 @@ def attention_block(p: Dict, x: jnp.ndarray, positions: jnp.ndarray, cfg,
     o = flash_attention(q, k, v, causal=causal,
                         block_q=cfg.attn_block_q, block_k=cfg.attn_block_k,
                         unroll=cfg.analysis_unroll)
-    return apply_linear(p["wo"], o.reshape(b, s, -1), use_pallas=use_pallas)
+    return linear(p, "wo", o.reshape(b, s, -1), use_pallas)
 
 
 def attention_block_sp(p: Dict, x: jnp.ndarray, cfg, *, causal=True,
@@ -485,8 +492,8 @@ def attention_block_sp(p: Dict, x: jnp.ndarray, cfg, *, causal=True,
                             block_q=min(cfg.attn_block_q, s_loc),
                             block_k=cfg.attn_block_k,
                             unroll=cfg.analysis_unroll, q_offset=offset)
-        yl = apply_linear(pp["wo"], o.reshape(xl.shape[0], s_loc, -1),
-                          use_pallas=use_pallas)
+        yl = linear(pp, "wo", o.reshape(xl.shape[0], s_loc, -1),
+                    use_pallas)
         return yl
 
     pspec = jax.tree_util.tree_map(
@@ -536,13 +543,13 @@ def attention_decode(p: Dict, x: jnp.ndarray, cache: Dict, pos: jnp.ndarray,
         else:
             o = decode_attention_int8(q, k_cache, k_scale, v_cache,
                                       v_scale, pos + 1)
-        y = apply_linear(p["wo"], o.reshape(b, 1, -1), use_pallas=use_pallas)
+        y = linear(p, "wo", o.reshape(b, 1, -1), use_pallas)
         return y, {"k": k_cache, "v": v_cache, "k_scale": k_scale,
                    "v_scale": v_scale}
     k_cache = write3(cache["k"], k)
     v_cache = write3(cache["v"], v)
     o = decode_attention(q, k_cache, v_cache, pos + 1)
-    y = apply_linear(p["wo"], o.reshape(b, 1, -1), use_pallas=use_pallas)
+    y = linear(p, "wo", o.reshape(b, 1, -1), use_pallas)
     return y, {"k": k_cache, "v": v_cache}
 
 
@@ -694,7 +701,7 @@ def attention_decode_paged(p: Dict, x: jnp.ndarray, cache: Dict,
             o = decode_attention(q, view(new["k_pages"]),
                                  view(new["v_pages"]), length,
                                  anc, base, window)
-    y = apply_linear(p["wo"], o.reshape(b, t, -1), use_pallas=use_pallas)
+    y = linear(p, "wo", o.reshape(b, t, -1), use_pallas)
     return y, new
 
 
@@ -715,9 +722,8 @@ def mlp_init(rng, d: int, d_ff: int, mlp_type: str, dtype=jnp.float32) -> Dict:
 def mlp_block(p: Dict, x: jnp.ndarray, mlp_type: str,
               use_pallas=False) -> jnp.ndarray:
     if mlp_type == "swiglu":
-        g = apply_linear(p["wg"], x, use_pallas=use_pallas)
-        u = apply_linear(p["wu"], x, use_pallas=use_pallas)
-        return apply_linear(p["wd"], jax.nn.silu(g) * u,
-                            use_pallas=use_pallas)
-    u = apply_linear(p["wu"], x, use_pallas=use_pallas)
-    return apply_linear(p["wd"], jax.nn.gelu(u), use_pallas=use_pallas)
+        g = linear(p, "wg", x, use_pallas)
+        u = linear(p, "wu", x, use_pallas)
+        return linear(p, "wd", jax.nn.silu(g) * u, use_pallas)
+    u = linear(p, "wu", x, use_pallas)
+    return linear(p, "wd", jax.nn.gelu(u), use_pallas)

@@ -236,8 +236,8 @@ def prefill(params: Dict, cache: Dict, tokens: jnp.ndarray,
                                   block_q=cfg.attn_block_q,
                                   block_k=cfg.attn_block_k,
                                   unroll=cfg.analysis_unroll)
-            a = apply_linear(lp["attn"]["wo"], o.reshape(b, s, -1),
-                             use_pallas=use_pallas)
+            a = L.linear(lp["attn"], "wo", o.reshape(b, s, -1),
+                         use_pallas)
             if int8:
                 k_i8, k_sc = L.quantize_kv(k)
                 v_i8, v_sc = L.quantize_kv(v)

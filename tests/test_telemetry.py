@@ -144,7 +144,9 @@ def test_histogram_quantile_bound_property(xs, q):
 def test_disabled_tracer_is_null():
     tr = SpanTracer(enabled=False)
     assert tr.span("x") is NULL_SPAN
-    assert tr.annotate("x") is NULL_SPAN
+    # a span with args (which would become the profiler annotation's
+    # stats) is the same shared no-op
+    assert tr.span("x", cat="sync", bucket=4) is NULL_SPAN
     with tr.span("x") as sp:
         sp.set(tokens=3)
     tr.instant("i")
@@ -352,3 +354,233 @@ def test_stats_interval_emits_line(tiny, capsys):
     lines = [l for l in capsys.readouterr().out.splitlines()
              if l.startswith("[stats] ")]
     assert lines and "pages_free" in lines[0] and "queue" in lines[0]
+
+
+# ---------------------------------------------------------------------------
+# counters of the work as dispatched: padded prefill rows, spanned pages
+# ---------------------------------------------------------------------------
+
+def _counters(eng, *names):
+    return tuple(eng.tel.registry.counter(n).value for n in names)
+
+
+PREFILL = ("engine.prefill_rows", "engine.prefill_tokens")
+PAGES = ("engine.decode_pages_live", "engine.decode_pages_spanned")
+
+
+def test_prefill_rows_and_pages_full_path(tiny):
+    """Prompts of 5 and 9 tokens share one prefill at bucket 16 on 2
+    slots: 32 rows for 14 real tokens. Budgets 4 and 6 (page 4, no
+    lookahead) reserve 3 and 4 pages, so max_live is 4; the first
+    segment runs 3 steps at write positions 5..7 and 9..11 (pages
+    2+2+2 and 3+3+3), the second 2 steps of the 9-token request at 12
+    and 13 (4+4)."""
+    cfg, api, params = tiny
+    eng = InferenceEngine(cfg, params, EngineConfig(num_slots=2,
+                                                    max_seq=32,
+                                                    page_size=4))
+    for p, n in zip(_prompts(cfg.vocab, (5, 9), seed=3), (4, 6)):
+        eng.submit(p, n)
+    eng.run()
+    assert _counters(eng, *PREFILL) == (2 * 16, 5 + 9)
+    assert eng.metrics.decode_steps == 3 + 2
+    assert _counters(eng, *PAGES) == (6 + 9 + 8, (3 + 2) * 2 * 4)
+
+
+def test_prefill_rows_prefix_tail_path(tiny):
+    """A 19-token prompt whose first 16 tokens (four full pages) are
+    cached feeds a 3-token tail, padded to 8 rows on each of 2 slots."""
+    cfg, api, params = tiny
+    eng = InferenceEngine(cfg, params, EngineConfig(
+        num_slots=2, max_seq=32, page_size=4, prefix_cache=True))
+    head, = _prompts(cfg.vocab, (16,), seed=5)
+    eng.submit(head, 2)
+    eng.run()
+    assert _counters(eng, *PREFILL) == (2 * 16, 16)
+    tail, = _prompts(cfg.vocab, (3,), seed=6)
+    eng.submit(np.concatenate([head, tail]), 2)
+    eng.run()
+    assert _counters(eng, *PREFILL) == (2 * 16 + 2 * 8, 16 + 3)
+    assert eng.tel.tracer.events == []       # counters need no tracing
+
+
+def test_prefill_rows_chunked_path(tiny):
+    """A 10-token prompt at a chunk budget of 4 feeds chunks of 4, 4
+    and 2 tokens, each padded to the bucket floor of 8 rows on 2 slots;
+    its one decode step writes position 10 (3 pages of 4) with 12 tokens
+    reserved (3 pages: max_live 4)."""
+    cfg, api, params = tiny
+    eng = InferenceEngine(cfg, params, EngineConfig(
+        num_slots=2, max_seq=32, page_size=4, prefill_chunk_tokens=4))
+    p, = _prompts(cfg.vocab, (10,), seed=7)
+    eng.submit(p, 2)
+    eng.run()
+    assert _counters(eng, *PREFILL) == (3 * 2 * 8, 10)
+    assert eng.tel.registry.counter("engine.prefill_chunk_tokens") \
+        .value == 10
+    assert _counters(eng, *PAGES) == (3, 1 * 2 * 4)
+
+
+# ---------------------------------------------------------------------------
+# finish stamps at completion
+# ---------------------------------------------------------------------------
+
+def test_finish_is_stamped_when_its_last_tokens_are_ready(tiny,
+                                                          monkeypatch):
+    """Every request's ``finish_t`` is no earlier than the return of the
+    call that retired its last token array (a boundary
+    ``block_until_ready``, or the drain's host read when ``run`` ends):
+    under two-deep dispatch a request's last segment is still in flight
+    at the boundary that evicts it."""
+    import time
+    cfg, api, params = tiny
+    ready = {}                       # id(array) -> (return time, how)
+
+    def noting(real, how):
+        def wrapper(x):
+            out = real(x)
+            t = time.perf_counter()
+            for leaf in jax.tree_util.tree_leaves(x):
+                ready.setdefault(id(leaf), (t, how))
+            return out
+        return wrapper
+    monkeypatch.setattr(jax, "block_until_ready",
+                        noting(jax.block_until_ready, "sync"))
+    monkeypatch.setattr(jax, "device_get", noting(jax.device_get, "drain"))
+    eng = InferenceEngine(cfg, params, EngineConfig(num_slots=2,
+                                                    max_seq=32))
+    for p, n in zip(_prompts(cfg.vocab, (4, 6, 5, 7, 4), seed=9),
+                    (3, 7, 5, 4, 6)):
+        eng.submit(p, n)
+    eng.run()
+    how = []
+    for r in eng.scheduler.finished:
+        t_ready, by = ready[id(eng._token_log[r.log_entries[-1]])]
+        assert eng.metrics.requests[r.rid].finish_t >= t_ready, r.rid
+        how.append(by)
+    assert len(how) == 5
+    assert "sync" in how and "drain" in how
+
+
+# ---------------------------------------------------------------------------
+# one span API: Chrome JSON and the profiler's host plane
+# ---------------------------------------------------------------------------
+
+def test_tracing_off_constructs_no_trace_annotation(tiny, monkeypatch):
+    from repro.engine.telemetry import tracer as tracer_mod
+    cfg, api, params = tiny
+    made = []
+    real = tracer_mod.TraceAnnotation
+
+    class Counted(real):
+        def __init__(self, *a, **k):
+            made.append(a[0])
+            super().__init__(*a, **k)
+    monkeypatch.setattr(tracer_mod, "TraceAnnotation", Counted)
+    _run(cfg, params, Telemetry())
+    assert made == []
+    _run(cfg, params, Telemetry(trace=True))
+    assert {"admit", "prefill", "decode_segment", "evict",
+            "slot_sync", "sync"} <= set(made)
+
+
+HOST_SPANS = ("admit", "evict", "slot_sync", "sync", "prefill",
+              "prefill_tail", "prefill_chunk", "decode_segment",
+              "spec_segment", "draft", "verify")
+
+
+def _profiled_run(tmp_path, fn):
+    """Run ``fn`` under jax.profiler; return the host plane's engine
+    spans as {name: [(start_ns, end_ns, stats)]} sorted by start."""
+    import glob
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                      recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    spans = {}
+    for plane in pd.planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name in HOST_SPANS:
+                    spans.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns,
+                         dict(e.stats)))
+    return {k: sorted(v, key=lambda s: s[0]) for k, v in spans.items()}, out
+
+
+def _inside(inner, outer):
+    return outer[0] <= inner[0] and inner[1] <= outer[1]
+
+
+def _engine_case(tiny, case, tel):
+    cfg, api, params = tiny
+    if case == "spec":
+        from repro.core.model_compress import compress_draft, draft_layers
+        return _run(cfg, params, tel, spec_k=2,
+                    draft=compress_draft(params, cfg, profile="w4l50"),
+                    dlayers=draft_layers(cfg, "w4l50"))[0]
+    ecfg = EngineConfig(num_slots=2, max_seq=32, page_size=4,
+                        prefix_cache=case == "prefix",
+                        prefill_chunk_tokens=4 if case == "chunked" else 0)
+    eng = InferenceEngine(cfg, params, ecfg, telemetry=tel)
+    if case == "prefix":
+        head, tail = _prompts(cfg.vocab, (16, 3), seed=5)
+        eng.submit(head, 3)
+        eng.run()
+        prompts = [np.concatenate([head, tail]), head[:9]]
+    else:
+        prompts = _prompts(cfg.vocab, (3, 10, 6), seed=11)
+    for p in prompts:
+        eng.submit(p, 4)
+    eng.run()
+    return eng
+
+
+@pytest.mark.parametrize("case", ["chunked", "prefix", "spec"])
+def test_spans_land_on_the_profiler_host_plane(tiny, tmp_path, case):
+    """Under jax.profiler (CPU), every engine span is also an annotation
+    of the same name on the /host:CPU plane, one per JSON span, covering
+    at least the JSON span's duration; ``sync`` sits inside each
+    prefill that waits and never inside ``decode_segment`` (which
+    brackets only the enqueue), ``slot_sync`` inside an ``evict``, and
+    ``draft``/``verify`` inside ``spec_segment``."""
+    tel = Telemetry(trace=True)
+    spans, _ = _profiled_run(tmp_path, lambda: _engine_case(tiny, case,
+                                                            tel))
+    json_spans = {}
+    for ev in tel.tracer.events:
+        if ev["ph"] == "X":
+            json_spans.setdefault(ev["name"], []).append(ev)
+    assert set(spans) == set(json_spans)
+    for name, evs in json_spans.items():
+        evs.sort(key=lambda e: e["ts"])
+        assert len(spans[name]) == len(evs), name
+        for (s, e, _), j in zip(spans[name], evs):
+            assert (e - s) * 1e-3 >= j["dur"] - 1.0, name
+    expect = {"admit", "evict", "slot_sync", "sync", "prefill"}
+    expect |= {"chunked": {"prefill_chunk", "decode_segment"},
+               "prefix": {"prefill_tail", "decode_segment"},
+               "spec": {"spec_segment", "draft", "verify"}}[case]
+    assert expect <= set(spans)
+    syncs = spans["sync"]
+    for name in ("prefill", "prefill_tail", "prefill_chunk"):
+        for p in spans.get(name, []):
+            waits = p[2].get("completed", 1) > 0
+            assert sum(_inside(s, p) for s in syncs) == int(waits), name
+    for d in spans.get("decode_segment", []):
+        assert not any(_inside(s, d) for s in syncs)
+    assert any(_inside(s, e) for s in spans["slot_sync"]
+               for e in spans["evict"])
+    for name in ("draft", "verify"):
+        for d in spans.get(name, []):
+            assert any(_inside(d, s) for s in spans["spec_segment"])
+    # a span's creation args become the annotation's stats
+    assert all(p[2]["bucket"] > 0 for p in spans["prefill"])

@@ -59,6 +59,25 @@ def test_gqsa_gemv_compiles_for_decode(one_chip, n, k):
              ((4, items), jnp.int32))
 
 
+def test_gqsa_gemv_label_names_the_compiled_kernel(one_chip):
+    """A linear's label reaches the kernel's name, the one name the
+    chip's trace keeps for the op (``%gqsa_gemv_wk.N = ... custom-call``)."""
+    n, k = KV_HEADS * HEAD_DIM, D_MODEL
+    m = k // G // 2
+    block_n, lane, np_, mp, cp = bsr_mod.tiles(n, k, G, m)
+    items = np_ // block_n * -(-m // lane)
+
+    def fn(x, words, scale, zero, pos, work):
+        return gqsa_gemv_pallas(x, words, scale, zero, pos, work,
+                                group_size=G, block_n=block_n, lane=lane,
+                                block_t=BATCH, label="wk")
+    text = _compile(fn, one_chip, ((BATCH, G * cp), jnp.bfloat16),
+                    ((G // 8, np_, mp), jnp.int32), ((np_, mp), jnp.float32),
+                    ((np_, mp), jnp.float32), ((np_, cp), jnp.int32),
+                    ((4, items), jnp.int32)).as_text()
+    assert "%gqsa_gemv_wk" in text
+
+
 @pytest.mark.parametrize("n,k", [(D_MODEL, D_MODEL), (D_MODEL, D_FF)])
 def test_w4_matmul_compiles_for_prefill_block(one_chip, n, k):
     """Dense W4 matmul on a 256-row prefill block."""
