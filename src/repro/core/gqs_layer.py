@@ -58,8 +58,9 @@ class GQSAConfig:
 def apply_linear(p: Dict, x: jnp.ndarray, *, qcfg: Optional[QuantConfig] = None,
                  use_pallas: bool = False, label: str = "") -> jnp.ndarray:
     """x: [..., K] -> [..., N]; dispatch on the parameter representation.
-    ``label`` (static: ``wq`` ... ``wd``) names the GQSA kernel of this
-    linear in a device trace (``gqsa_gemv_<label>``)."""
+    ``label`` (static: ``wq`` ... ``wd``) names the GQSA kernels of this
+    linear in a device trace (``gqsa_gemv_<label>``,
+    ``gqsa_densify_<label>``)."""
     lead = x.shape[:-1]
     k = x.shape[-1]
     x2 = x.reshape(-1, k)

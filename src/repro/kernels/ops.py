@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.bsr import BSRMatrix
-from repro.kernels.gqsa_gemv import gqsa_gemv_pallas
+from repro.kernels.gqsa_gemv import gqsa_densify_pallas, gqsa_gemv_pallas
 from repro.kernels.gqsa_gemv import DEFAULT_BLOCK_T as DEFAULT_GEMV_BLOCK_T
 from repro.kernels.paged_attention import paged_attention_pallas
 from repro.kernels.w4_matmul import (w4_matmul_pallas, DEFAULT_BLOCK_T,
@@ -47,19 +47,43 @@ def _row_tile(t: int, cap: int) -> int:
 
 def gqsa_gemv(x: jnp.ndarray, bsr: BSRMatrix,
               label: str = "") -> jnp.ndarray:
-    """y = x @ dense(bsr).T with the task-centric sparse kernel.
+    """y = x @ dense(bsr).T from the packed weight. Returns [T, N] f32.
 
-    x: [T, K], any T (decode slots, verify rows or a prefill block: rows
-    beyond DEFAULT_BLOCK_T tile the kernel's grid). The packed operands
-    are used as laid out at pack time; only the activations are permuted
-    to the kernel's group-column-major order. Returns [T, N] f32.
-    ``label`` (static) names the linear in the kernel's name.
+    x: [T, K], any T. The row count picks the schedule:
+
+    * T <= DEFAULT_BLOCK_T rows (decode, verify, short prefill) fit one
+      activation tile: the fused kernel ``gqsa_gemv_<label>`` densifies
+      each weight row block in VMEM and multiplies it there, so HBM
+      traffic is the packed payload.
+    * More rows would make the fused kernel densify every row block again
+      for each 256-row tile. Instead ``gqsa_densify_<label>`` densifies
+      the weight once, into an [Np, K] transient of x's dtype in HBM that
+      lives only for this call, and one XLA matmul contracts it with all
+      rows at f32 accumulation.
+
+    Both run the same placement code on the same operands; only the
+    summation order differs. The activations are permuted to the
+    kernels' group-column-major order. ``label`` (static) names the
+    linear in the kernels' names.
     """
     t, k = x.shape
     n = bsr.shape[0]
     g = bsr.group_size
     cp = bsr.pos.shape[-1]
     xg = x.reshape(t, k // g, g).transpose(0, 2, 1)           # [T, G, C]
+    if t > DEFAULT_GEMV_BLOCK_T:
+        wd = gqsa_densify_pallas(bsr.words, bsr.scale, bsr.zero, bsr.pos,
+                                 bsr.work, group_size=g,
+                                 block_n=bsr.block_n, lane=bsr.lane,
+                                 c=k // g, dtype=x.dtype, label=label,
+                                 interpret=_interpret())
+        # HIGHEST keeps f32 activations' products in f32, as the fused
+        # kernel does; bf16 operands take one MXU pass either way
+        y = jax.lax.dot_general(xg.reshape(t, k), wd,
+                                (((1,), (1,)), ((), ())),
+                                precision=jax.lax.Precision.HIGHEST,
+                                preferred_element_type=jnp.float32)
+        return y[:, :n]
     xg = _pad_to(xg, 2, cp).reshape(t, g * cp)
     bt = _row_tile(t, DEFAULT_GEMV_BLOCK_T)
     y = gqsa_gemv_pallas(_pad_to(xg, 0, bt), bsr.words, bsr.scale, bsr.zero,

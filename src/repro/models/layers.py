@@ -27,8 +27,9 @@ def linear_init(rng, n_out: int, n_in: int, dtype=jnp.float32,
 
 def linear(p: Dict, name: str, x: jnp.ndarray,
            use_pallas=False) -> jnp.ndarray:
-    """The linear ``p[name]`` of a block on ``x``, its GQSA kernel
-    named by ``name`` in a device trace (``gqsa_gemv_wq`` ...)."""
+    """The linear ``p[name]`` of a block on ``x``, its GQSA kernels
+    named by ``name`` in a device trace (``gqsa_gemv_wq``,
+    ``gqsa_densify_wq`` ...)."""
     return apply_linear(p[name], x, use_pallas=use_pallas, label=name)
 
 

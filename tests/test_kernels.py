@@ -60,6 +60,46 @@ def test_gemv_ragged_rows_task_centric(balanced):
                                rtol=1e-4, atol=1e-4)
 
 
+# Row counts on both sides of the one-tile threshold (DEFAULT_BLOCK_T =
+# 256): above it the weight is densified once and contracted by XLA. N =
+# 136 is not a multiple of block_n, K = 4608's 288 group columns pad to
+# 384 lanes, and unbalanced rows give row blocks of 2 and 1 chunks.
+@pytest.mark.parametrize("t", [1, 32, 256, 257, 700])
+@pytest.mark.parametrize("balanced", [True, False])
+def test_gemv_row_counts(t, balanced):
+    n, k = 136, 4608
+    w, bsr = _bsr_case(10, n, k, 16, 0.5 if balanced else 0.6,
+                       balanced=balanced,
+                       row_scale=np.where(np.arange(n) < 128, 1.0, 0.7))
+    x = jnp.asarray(np.random.default_rng(11).normal(size=(t, k)),
+                    jnp.float32)
+    y_ref = ref.gqsa_gemv_ref(x, bsr)
+    y_ker = ops.gqsa_gemv(x, bsr)
+    assert y_ker.shape == (t, n)
+    np.testing.assert_allclose(np.asarray(y_ker), np.asarray(y_ref),
+                               rtol=1e-4, atol=1e-4)
+
+
+def test_gemv_staged_matches_fused():
+    """At 700 rows the staged path (densify once, one matmul) and the
+    fused kernel over three 256-row tiles compute the same products."""
+    from repro.kernels.gqsa_gemv import gqsa_gemv_pallas
+    n, k, g, t = 136, 4608, 16, 700
+    w, bsr = _bsr_case(12, n, k, g, 0.6, balanced=False,
+                       row_scale=np.where(np.arange(n) < 128, 1.0, 0.7))
+    x = jnp.asarray(np.random.default_rng(13).normal(size=(t, k)),
+                    jnp.float32)
+    cp = bsr.pos.shape[-1]
+    xg = x.reshape(t, k // g, g).transpose(0, 2, 1)
+    xg = jnp.pad(xg, ((0, 768 - t), (0, 0), (0, cp - k // g)))
+    fused = gqsa_gemv_pallas(xg.reshape(768, g * cp), bsr.words, bsr.scale,
+                             bsr.zero, bsr.pos, bsr.work, group_size=g,
+                             block_n=bsr.block_n, lane=bsr.lane,
+                             block_t=256, interpret=True)[:t, :n]
+    np.testing.assert_allclose(np.asarray(ops.gqsa_gemv(x, bsr)),
+                               np.asarray(fused), rtol=1e-5, atol=1e-4)
+
+
 @pytest.mark.parametrize("xdtype", [jnp.float32, jnp.bfloat16])
 def test_gemv_dtypes(xdtype):
     w, bsr = _bsr_case(4, 64, 128, 16, 0.5)

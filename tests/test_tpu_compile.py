@@ -1,5 +1,6 @@
 """The main-path Pallas kernels compile for one TPU v5e chip at
-starcoder2-3b widths (and the DeepSeek latent head width).
+starcoder2-3b widths (and the DeepSeek latent head width, and Mistral-Nemo's
+K 5120 for the prefill densify pass).
 
 Nothing runs: each kernel is lowered and compiled for a chip that is
 described, not attached, so the TPU compiler refuses here whatever it
@@ -16,7 +17,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import bsr as bsr_mod
-from repro.kernels.gqsa_gemv import gqsa_gemv_pallas
+from repro.kernels.gqsa_gemv import gqsa_densify_pallas, gqsa_gemv_pallas
 from repro.kernels.paged_attention import paged_attention_pallas
 from repro.kernels.w4_matmul import w4_matmul_pallas
 
@@ -76,6 +77,44 @@ def test_gqsa_gemv_label_names_the_compiled_kernel(one_chip):
                     ((np_, mp), jnp.float32), ((np_, cp), jnp.int32),
                     ((4, items), jnp.int32)).as_text()
     assert "%gqsa_gemv_wk" in text
+
+
+# starcoder2-3b's down projection and Mistral-Nemo's gate (K 5120: 320
+# group columns pad to 384 lanes, and the unpadded tile is stored at
+# unaligned lane offsets).
+@pytest.mark.parametrize("n,k", [(D_MODEL, D_FF), (14336, 5120)])
+def test_gqsa_densify_compiles(one_chip, n, k):
+    """The prefill densify pass, named ``gqsa_densify_<label>`` in the
+    compiled program as in the chip's trace."""
+    m = k // G // 2
+    block_n, lane, np_, mp, cp = bsr_mod.tiles(n, k, G, m)
+    items = np_ // block_n * -(-m // lane)
+
+    def fn(words, scale, zero, pos, work):
+        return gqsa_densify_pallas(words, scale, zero, pos, work,
+                                   group_size=G, block_n=block_n, lane=lane,
+                                   c=k // G, dtype=jnp.bfloat16, label="wd")
+    text = _compile(fn, one_chip, ((G // 8, np_, mp), jnp.int32),
+                    ((np_, mp), jnp.float32), ((np_, mp), jnp.float32),
+                    ((np_, cp), jnp.int32), ((4, items), jnp.int32)).as_text()
+    assert "%gqsa_densify_wd" in text
+
+
+@pytest.mark.parametrize("t,fused", [(32, True), (4096, False)])
+def test_gqsa_linear_path_follows_row_count(one_chip, monkeypatch, t, fused):
+    """One activation tile (decode) runs the fused kernel; a 4096-row
+    prefill densifies the weight once and multiplies it outside."""
+    from repro.core.gqs_layer import GQSAConfig, packed_linear_shapes
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    bsr = jax.tree_util.tree_map(
+        lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=one_chip),
+        packed_linear_shapes(D_MODEL, D_MODEL, GQSAConfig())["bsr"])
+    x = jax.ShapeDtypeStruct((t, D_MODEL), jnp.bfloat16, sharding=one_chip)
+    text = jax.jit(lambda x, b: ops.gqsa_gemv(x, b, "wq")).lower(
+        x, bsr).compile().as_text()
+    assert ("%gqsa_gemv_wq" in text) == fused
+    assert ("%gqsa_densify_wq" in text) != fused
 
 
 @pytest.mark.parametrize("n,k", [(D_MODEL, D_MODEL), (D_MODEL, D_FF)])
