@@ -6,7 +6,16 @@ Everything that belongs to one configuration, traffic mix or per-layer
 metric is a file of its own, found by the name ``BENCHMARK.json`` gives
 it: ``configs/<config>.json``, ``traffic/<traffic>.json`` and
 ``layer_metrics/<metric>.py`` under the benchmark's directory. A
-configuration names its plain reference, ``references/<name>.py``.
+configuration names its model module, ``models/<name>.py`` (key
+``"model"``), and its plain reference, ``references/<name>.py`` (key
+``"reference"``).
+
+A model module provides ``Model.from_conf(conf)``, an object with at
+least ``vocab`` (readers get it as ``ctx.model``); ``program_config(conf)``,
+the program's ``ModelConfig``; ``build_params(conf, cache_root, device,
+workers) -> (params, parts)``, the served tree on the device and the
+seconds of each part of making it; and optionally ``KERNELS``, kernel
+names the breakdown keeps apart besides the harness's own.
 """
 from __future__ import annotations
 
@@ -25,7 +34,7 @@ import numpy as np
 
 from bench import traffic as traffic_mod
 from bench.trace_reduce import Trace
-from bench.weights import Compression, Model, build_params
+from bench.weights import Compression
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
 WINDOW = "bench_window"
@@ -50,22 +59,36 @@ def log(msg: str) -> None:
 
 def find(root: str, kind: str, name: str) -> str:
     """Path of ``kind`` file ``name`` under benchmark directory ``root``:
-    kind is "configs", "traffic", "layer_metrics" or "references"."""
-    ext = ".py" if kind in ("layer_metrics", "references") else ".json"
-    path = os.path.join(root, kind, name + ext)
+    kind is "configs", "traffic", "layer_metrics", "models" or
+    "references"."""
+    path = os.path.join(root, kind, name + _ext(kind))
     if not os.path.isfile(path):
-        have = sorted(f[:-len(ext)] for f in os.listdir(
-            os.path.join(root, kind)) if f.endswith(ext)) \
-            if os.path.isdir(os.path.join(root, kind)) else []
         raise KeyError(f"no {kind} file named {name!r} under {root} "
-                       f"(have: {', '.join(have) or 'none'})")
+                       f"(have: {names(root, kind)})")
     return path
 
 
+def names(root: str, kind: str) -> str:
+    """The names of the ``kind`` files under ``root``, for a refusal."""
+    ext, d = _ext(kind), os.path.join(root, kind)
+    have = sorted(f[:-len(ext)] for f in os.listdir(d)
+                  if f.endswith(ext)) if os.path.isdir(d) else []
+    return ", ".join(have) or "none"
+
+
+def _ext(kind: str) -> str:
+    return ".json" if kind in ("configs", "traffic") else ".py"
+
+
 def load_module(path: str):
-    name = "bench_" + os.path.basename(path)[:-3].replace(".", "_")
+    """Import the file ``path`` as a module of its own, registered under
+    its directory's and its own name (dataclasses look their module up)."""
+    kind, base = os.path.split(os.path.splitext(path)[0])
+    name = "bench_{}_{}".format(os.path.basename(kind),
+                                base.replace(".", "_"))
     spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
     spec.loader.exec_module(mod)
     return mod
 
@@ -84,6 +107,7 @@ class Cell:
     end_to_end: List[Dict]
     per_layer: List[Dict]
     readers: Dict[str, Callable]
+    module: object          # the configuration's model module
     reference: object
 
 
@@ -104,6 +128,10 @@ def load_cell(benchmark: Dict, workload: str, root: str = BENCH) -> Cell:
         raise ValueError(f"config file of {w['config']!r} is named "
                          f"{conf.get('name')!r}")
 
+    if "model" not in conf:
+        raise KeyError(f"config {w['config']!r} names no model module "
+                       f"(have: {names(root, 'models')})")
+
     def mine(m):
         return workload in m.get("workloads", [workload])
     per_layer = [m for m in benchmark["per_layer"] if mine(m)]
@@ -114,6 +142,7 @@ def load_cell(benchmark: Dict, workload: str, root: str = BENCH) -> Cell:
                 readers={m["name"]: load_module(find(
                     root, "layer_metrics", m["name"])).read
                     for m in per_layer},
+                module=load_module(find(root, "models", conf["model"])),
                 reference=load_module(find(root, "references",
                                            conf["reference"])))
 
@@ -121,23 +150,6 @@ def load_cell(benchmark: Dict, workload: str, root: str = BENCH) -> Cell:
 # ---------------------------------------------------------------------------
 # the program's side
 # ---------------------------------------------------------------------------
-
-def program_config(conf: Dict):
-    """The program's ModelConfig for a configuration file: the registry
-    arch, with every size taken from the file."""
-    from repro.configs.registry import get_config
-    m = Model.from_conf(conf)
-    base = get_config(conf["arch"])
-    if base.family != "dense":
-        raise ValueError(f"arch {conf['arch']!r} is not a dense decoder")
-    return dataclasses.replace(
-        base, name=conf["name"], n_layers=m.layers, d_model=m.d,
-        n_heads=m.heads, n_kv_heads=m.kv_heads, head_dim=m.head_dim,
-        d_ff=m.d_ff, vocab=m.vocab, rope_theta=m.rope_theta,
-        norm_eps=m.eps, tie_embeddings=m.tied,
-        mlp_type="swiglu" if m.gated else "gelu",
-        dtype=conf["compute_dtype"], qk_norm=False)
-
 
 def engine_config(traffic: Dict):
     from repro.engine import EngineConfig
@@ -296,7 +308,7 @@ class Server:
     n_devices: int
     peaks: Optional[Dict]
     engine: object
-    model: Model
+    model: object           # the model module's Model.from_conf(conf)
     comp: Compression
     parts: Dict[str, float]
     compiles: List[int]
@@ -310,7 +322,7 @@ class Window:
     window_s: float
     setup_s: float
     peak_bytes: int
-    counters: Dict[str, float]
+    counters: Dict[str, float]     # decode_steps, decode_tokens, registry's
     trace_dir: Optional[str]
 
 
@@ -350,13 +362,12 @@ def setup(cell: Cell, trace: bool = False, require_chip: bool = True,
 
     parts: Dict[str, float] = {}
     t = time.perf_counter()
-    params, wparts = build_params(cell.conf,
-                                  os.path.join(cache_root, "weights"), dev,
-                                  workers)
+    params, wparts = cell.module.build_params(
+        cell.conf, os.path.join(cache_root, "weights"), dev, workers)
     parts.update(wparts)
     parts["weights_s"] = time.perf_counter() - t
     t = time.perf_counter()
-    engine = InferenceEngine(program_config(cell.conf), params,
+    engine = InferenceEngine(cell.module.program_config(cell.conf), params,
                              engine_config(cell.traffic),
                              telemetry=Telemetry(trace=trace))
     del params
@@ -369,7 +380,7 @@ def setup(cell: Cell, trace: bool = False, require_chip: bool = True,
     gc.collect()
     gc.freeze()
     return Server(cell=cell, dev=dev, n_devices=len(devices), peaks=peaks,
-                  engine=engine, model=Model.from_conf(cell.conf),
+                  engine=engine, model=cell.module.Model.from_conf(cell.conf),
                   comp=Compression.from_conf(cell.conf["compression"]),
                   parts=parts, compiles=compiles)
 
@@ -400,6 +411,7 @@ def measure(srv: Server, seed: int, seconds: float, trace: bool) -> Window:
     def on_start() -> None:
         mark["setup_s"] = process_age_s() - (metrics.now() - source.start)
         mark["compiles"], mark["logged"] = srv.compiles[0], len(clog.names)
+        mark["registry"] = engine.tel.registry.window()
         if trace:
             # a TraceAnnotation starts when it is made, not when entered
             mark["annotation"] = jax.profiler.TraceAnnotation(WINDOW)
@@ -407,6 +419,7 @@ def measure(srv: Server, seed: int, seconds: float, trace: bool) -> Window:
 
     def on_stop() -> None:
         jax.block_until_ready(jax.live_arrays())
+        mark["counted"] = mark["registry"].tick()[1]
         mark["compiled"] = srv.compiles[0] - mark["compiles"]
         mark["inside"] = clog.names[mark["logged"]:]
         if "annotation" in mark:
@@ -460,7 +473,8 @@ def measure(srv: Server, seed: int, seconds: float, trace: bool) -> Window:
                   setup_s=mark["setup_s"],
                   peak_bytes=int(stats.get("peak_bytes_in_use", 0)),
                   counters={"decode_steps": metrics.decode_steps - steps0,
-                            "decode_tokens": metrics.decode_tokens - tokens0},
+                            "decode_tokens": metrics.decode_tokens - tokens0,
+                            **mark["counted"]},
                   trace_dir=trace_dir)
 
 
@@ -482,7 +496,9 @@ def per_layer(srv: Server, win: Window) -> Tuple[Dict, Dict, Dict]:
                   peak_flops=peaks["bf16_flops_per_s"],
                   peak_bw=peaks["hbm_bytes_per_s"],
                   decode_context_tokens=_decode_context(win.served),
-                  **win.counters)
+                  counters=win.counters,
+                  decode_steps=win.counters["decode_steps"],
+                  decode_tokens=win.counters["decode_tokens"])
     metrics = {}
     for m in srv.cell.per_layer:
         v = srv.cell.readers[m["name"]](ctx)
@@ -492,7 +508,8 @@ def per_layer(srv: Server, win: Window) -> Tuple[Dict, Dict, Dict]:
         log(n)
     device = {"busy_s": tr_data.busy_ns(span) * 1e-9,
               "window_s": (span[1] - span[0]) * 1e-9}
-    breakdown = {"device_ops": tr_data.top_ops(span, KERNELS),
+    kernels = KERNELS + tuple(getattr(srv.cell.module, "KERNELS", ()))
+    breakdown = {"device_ops": tr_data.top_ops(span, kernels),
                  "idle_gaps": tr_data.idle_gaps(span, HOST_LABELS)}
     log(f"trace reduced in {time.perf_counter() - t:.1f}s")
     return metrics, device, breakdown
@@ -584,7 +601,7 @@ def compare(cell: Cell, served: List[Served], seed: int,
     return gaps
 
 
-def decide(cell: Cell, served: List[Served], model: Model, gap: float,
+def decide(cell: Cell, served: List[Served], model, gap: float,
            label: str = "check") -> Dict:
     """Every number compared, each with its limit, and ``ok``: whether
     all hold. ``gap`` is the widest logit gap of the tokens judged."""
@@ -606,7 +623,7 @@ def decide(cell: Cell, served: List[Served], model: Model, gap: float,
 
 
 def check_outputs(cell: Cell, served: List[Served], seed: int,
-                  model: Model) -> Dict:
+                  model) -> Dict:
     """The decision on what the window served: :func:`decide` on the
     served tokens' widest gap."""
     gaps = compare(cell, served, seed)

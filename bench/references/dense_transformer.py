@@ -5,8 +5,9 @@ on split halves, grouped-query causal attention, a tanh-GELU or SwiGLU
 MLP, tied or untied output head) in float32 at ``highest`` matmul
 precision. It imports nothing of the program under test: every weight is
 drawn again from the configuration's weight seed by ``bench/weights.py``
-and dequantized here, layer by layer, inside the jitted layer function,
-so no weight tree is ever held whole.
+and ``bench/models/dense_transformer.py`` and dequantized here, layer by
+layer, inside the jitted layer function, so no weight tree is ever held
+whole.
 
 ``mode="fp8"`` is the control: the same computation with every matmul
 operand (weights, activations, attention scores and probabilities)
@@ -22,8 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.weights import (Compression, Model, canonical, dense_leaves,
-                           linear_key)
+from bench.models.dense_transformer import Model, dense_leaves, linear_key
+from bench.weights import Compression, canonical
 
 HI = jax.lax.Precision.HIGHEST
 Q_BLOCK = 256          # query rows per attention block

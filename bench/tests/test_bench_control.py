@@ -24,26 +24,30 @@ sys.path[:0] = [CHECKOUT, os.path.join(CHECKOUT, "src")]
 from bench import harness  # noqa: E402
 
 BENCH = os.path.join(CHECKOUT, "bench")
-# at this size an honest run reads ~4e-3 of a logit, the control ~7e-2
-# and a planted fault ~1 (CPU); the full-size cells hold their own limit
-TINY_LIMIT = 0.03
+# at this size, over 17 windows (CPU): an honest run reads at most 7.6e-3
+# of a logit, the control at least 2.85e-2, a planted fault ~1; the
+# full-size cells hold their own limit
+TINY_LIMIT = 0.018
 
 
-@pytest.fixture(scope="module")
-def tiny(tmp_path_factory):
-    root = tmp_path_factory.mktemp("bench")
+def tiny_cell(root, model="dense_transformer"):
+    """Cell "c" of a benchmark root written under ``root``: a two-layer
+    model at d 128 under a four-slot backlog. Its model module is the
+    dense decoder's, copied there under the name ``model``."""
     for d in ("layer_metrics", "references"):
         shutil.copytree(os.path.join(BENCH, d), root / d)
     shutil.copy(os.path.join(BENCH, "peaks.json"), root / "peaks.json")
-    os.makedirs(root / "configs")
-    os.makedirs(root / "traffic")
+    for d in ("configs", "traffic", "models"):
+        os.makedirs(root / d)
+    shutil.copy(os.path.join(BENCH, "models", "dense_transformer.py"),
+                root / "models" / f"{model}.py")
     with open(os.path.join(BENCH, "configs",
                            "starcoder2-3b.gqsa.json")) as f:
         conf = json.load(f)
-    conf.update(name="tiny", num_hidden_layers=2, hidden_size=128,
-                num_attention_heads=4, num_key_value_heads=2,
-                intermediate_size=256, vocab_size=512,
-                check={"max_logit_gap": TINY_LIMIT})
+    conf.update(name="tiny", model=model, num_hidden_layers=2,
+                hidden_size=128, num_attention_heads=4,
+                num_key_value_heads=2, intermediate_size=256,
+                vocab_size=512, check={"max_logit_gap": TINY_LIMIT})
     with open(root / "configs" / "tiny.json", "w") as f:
         json.dump(conf, f)
     with open(root / "traffic" / "t.json", "w") as f:
@@ -59,11 +63,17 @@ def tiny(tmp_path_factory):
     bm["configs"] = [{"name": "tiny"}]
     bm["workloads"] = [{"name": "c", "config": "tiny", "traffic": "t",
                         "chips": 1}]
-    bm["per_layer"] = [m for m in bm["per_layer"]
-                       if m["name"] == "decode_occupancy"]
+    bm["per_layer"] = [m for m in bm["per_layer"] if m["name"] in (
+        "decode_occupancy", "prefill_row_use", "kv_page_use.decode")]
     for m in bm["end_to_end"] + bm["per_layer"]:
         m.pop("workloads", None)
-    return harness.load_cell(bm, "c", root=str(root)), str(root)
+    return harness.load_cell(bm, "c", root=str(root))
+
+
+@pytest.fixture(scope="module")
+def tiny(tmp_path_factory):
+    root = tmp_path_factory.mktemp("bench")
+    return tiny_cell(root), str(root)
 
 
 SEED = 2**31 + 3

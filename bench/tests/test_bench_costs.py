@@ -12,7 +12,8 @@ CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
 sys.path[:0] = [CHECKOUT, os.path.join(CHECKOUT, "src")]
 
 from bench import harness  # noqa: E402
-from bench.weights import Compression, Model  # noqa: E402
+from bench.models.dense_transformer import Model  # noqa: E402
+from bench.weights import Compression  # noqa: E402
 
 METRICS = os.path.join(CHECKOUT, "bench", "layer_metrics")
 
@@ -118,10 +119,19 @@ def test_prefill_readers_count_only_the_refills():
         pytest.approx(100.0 * flops / (0.2 * 1e12))
 
 
+def test_counter_readers_by_hand():
+    ctx = _ctx(counters={"engine.prefill_rows": 400,
+                         "engine.prefill_tokens": 100,
+                         "engine.decode_pages_spanned": 80,
+                         "engine.decode_pages_live": 30})
+    assert _reader("prefill_row_use").read(ctx) == 25.0
+    assert _reader("kv_page_use.decode").read(ctx) == 37.5
+
+
 def test_readers_find_nothing_without_a_trace():
-    ctx = _ctx(trace=None, decode_steps=0)
+    ctx = _ctx(trace=None, decode_steps=0, counters={})
     for name in ("decode_occupancy", "decode_step_ms", "prefill_ms_per_ktok",
                  "idle_share.decode", "gqsa_gemv_roofline.decode",
                  "paged_attention_roofline.decode", "step_mfu.decode",
-                 "step_mfu.prefill"):
+                 "step_mfu.prefill", "prefill_row_use", "kv_page_use.decode"):
         assert _reader(name).read(ctx) is None

@@ -26,7 +26,7 @@ def _benchmark():
 
 def _copy_bench(tmp_path):
     root = tmp_path / "bench"
-    for d in ("configs", "traffic", "layer_metrics", "references"):
+    for d in ("configs", "traffic", "layer_metrics", "models", "references"):
         shutil.copytree(os.path.join(BENCH, d), root / d)
     shutil.copy(os.path.join(BENCH, "peaks.json"), root / "peaks.json")
     return str(root)
@@ -45,7 +45,7 @@ def test_every_cell_of_the_benchmark_loads():
 
 def test_new_files_are_found_by_name_without_edits(tmp_path):
     root = _copy_bench(tmp_path)
-    before = {p: open(os.path.join(dp, p)).read()
+    before = {os.path.join(dp, p): open(os.path.join(dp, p)).read()
               for dp, _, fs in os.walk(root) for p in fs}
     with open(os.path.join(root, "configs",
                            "starcoder2-3b.gqsa.json")) as f:
@@ -74,18 +74,34 @@ def test_new_files_are_found_by_name_without_edits(tmp_path):
     assert cell.traffic["queued"] == 6
     assert list(cell.readers) == ["slots_seen"]
     assert cell.readers["slots_seen"](harness.Context(slots=2)) == 2.0
-    for p, text in before.items():
-        path = next(os.path.join(dp, p) for dp, _, fs in os.walk(root)
-                    if p in fs)
+    for path, text in before.items():
         assert open(path).read() == text
 
 
 @pytest.mark.parametrize("kind,name", [("configs", "no-such-model"),
                                        ("traffic", "no_such_mix"),
-                                       ("layer_metrics", "no_such_metric")])
+                                       ("layer_metrics", "no_such_metric"),
+                                       ("models", "no_such_module")])
 def test_unknown_names_are_refused(kind, name):
     with pytest.raises(KeyError, match=name):
         harness.find(BENCH, kind, name)
+
+
+@pytest.mark.parametrize("model", [None, "no_such_module"])
+def test_a_config_without_a_known_model_module_is_refused(tmp_path, model):
+    """No default: a configuration that names no model module, or one
+    that is not there, is refused with the modules there are."""
+    root = _copy_bench(tmp_path)
+    path = os.path.join(root, "configs", "starcoder2-3b.gqsa.json")
+    with open(path) as f:
+        conf = json.load(f)
+    conf.pop("model")
+    if model is not None:
+        conf["model"] = model
+    with open(path, "w") as f:
+        json.dump(conf, f)
+    with pytest.raises(KeyError, match=r"model.*have: dense_transformer"):
+        harness.load_cell(_benchmark(), "sc2-3b.decode_backlog", root=root)
 
 
 def test_unknown_workload_is_refused():

@@ -1,7 +1,8 @@
 """What the program counts and names reaches what the benchmark reads:
 
-* the program's prefill counters, taken over a traced harness window,
-  count exactly the refills that ``prefill_ms_per_ktok`` reads;
+* the program's registry counters, which the harness hands readers over
+  a traced window, count exactly the refills that
+  ``prefill_ms_per_ktok`` reads;
 * a GQSA GEMV kernel named by its linear (``gqsa_gemv_wq``) reads under
   ``gqsa_gemv`` in the trace reduction.
 """
@@ -22,42 +23,41 @@ from test_bench_control import SEED, tiny  # noqa: E402,F401
 
 def test_window_counters_count_the_refills_prefill_ms_per_ktok_reads(
         tiny, monkeypatch):   # noqa: F811
-    """In a traced run, the program's registry counters over the window
-    hold the real prompt tokens of exactly the refills that
-    ``prefill_ms_per_ktok`` divides by, more rows than tokens, and no
-    more live KV pages than the decode steps spanned."""
+    """In a traced run, the registry counters that the harness hands
+    readers as ``ctx.counters`` hold the real prompt tokens of exactly
+    the refills that ``prefill_ms_per_ktok`` divides by, more rows than
+    tokens, and no more live KV pages than the decode steps spanned; the
+    readers of the two shares report them."""
     cell, root = tiny
     kept = {}
-    real_measure, real_backlog = harness.measure, harness.traffic_mod.Backlog
+    real_measure, real_read = harness.measure, cell.readers["decode_occupancy"]
 
     def measure(srv, *a, **k):
-        kept["registry"] = srv.engine.tel.registry
         kept["win"] = real_measure(srv, *a, **k)
         return kept["win"]
 
-    def backlog(requests, seconds, started, clock, on_start, on_stop):
-        def start():
-            on_start()
-            kept["window"] = kept["registry"].window()
-
-        def stop():
-            on_stop()
-            kept["counters"] = kept["window"].tick()[1]
-        return real_backlog(requests, seconds, started, clock, start, stop)
+    def read(ctx):
+        kept["ctx"] = ctx
+        return real_read(ctx)
     monkeypatch.setattr(harness, "measure", measure)
-    monkeypatch.setattr(harness.traffic_mod, "Backlog", backlog)
+    monkeypatch.setitem(cell.readers, "decode_occupancy", read)
     res = harness.run(cell, SEED + 2, 1.5, True, require_chip=False,
                       cache_root=os.path.join(root, ".cache"), workers=1,
                       root=root)
     assert res["correct"], res["check"]
     refills = sum(s.prompt_len for s in kept["win"].served
                   if s.admitted and not s.first_fill)
-    c = kept["counters"]
+    c = kept["ctx"].counters
     assert refills > 0
     assert c["engine.prefill_tokens"] == refills
     assert c["engine.prefill_rows"] > refills
     assert 0 < c["engine.decode_pages_live"] <= \
         c["engine.decode_pages_spanned"]
+    assert c["decode_steps"] == kept["ctx"].decode_steps > 0
+    m = res["metrics"]
+    assert m["prefill_row_use"]["value"] == pytest.approx(
+        100.0 * refills / c["engine.prefill_rows"])
+    assert 0 < m["kv_page_use.decode"]["value"] <= 100.0
 
 
 def test_kernels_named_by_their_linear_read_under_the_kernel():

@@ -57,7 +57,8 @@ def _param_shapes(conf, sharding):
     from repro.core.gqs_layer import GQSAConfig, packed_linear_shapes
     from repro.core.pruning import PruneConfig
     from repro.core.quant import QuantConfig
-    from bench.weights import Compression, Model
+    from bench.models.dense_transformer import Model
+    from bench.weights import Compression
     m, c = Model.from_conf(conf), Compression.from_conf(conf["compression"])
     gq = GQSAConfig(quant=QuantConfig(bits=4, group_size=c.group_size),
                     prune=PruneConfig(sparsity=c.sparsity,
@@ -91,7 +92,8 @@ def test_steps_compile_and_fit(one_chip, monkeypatch, config, traffic):
     from repro.engine.engine import _step_fns
     from repro.kernels import ops
     from repro.models.registry import get_model
-    from bench.harness import engine_config, program_config, shapes
+    from bench.harness import engine_config, shapes
+    from bench.models.dense_transformer import program_config
     monkeypatch.setattr(ops, "_interpret", lambda: False)
     conf, tr = _load(config, traffic)
     cfg, ecfg = program_config(conf), engine_config(tr)

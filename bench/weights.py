@@ -1,20 +1,21 @@
-"""Seeded random weights for a configuration, made without a dense tree.
+"""Seeded random GQSA linears, the part every model module shares.
 
-Every packed linear is drawn in canonical GQSA form from the
-configuration's weight seed: 4-bit codes, a row-balanced group mask that
-keeps ``groups_kept_per_row`` groups of each row, and a scale and zero per
-group. The program's own packer (``repro.core.bsr.pack_quantized`` and
-``stack_bsr``) turns them into the served layout, so a change to that
-layout flows through here with no edit. The plain reference
-(``bench/references``) draws the same canonical arrays from the same
-keys and dequantizes them itself; it never reads the packed tree.
+A model module (``bench/models/<name>.py``) lists its packed linears as
+``(block, name, id, n, k, lead)``: an ``[N, K]`` weight stacked over the
+lead shape, such as ``(layers,)`` or ``(layers, experts)``. Each slice is
+drawn in canonical GQSA form from the configuration's weight seed: 4-bit
+codes, a row-balanced group mask that keeps ``groups_kept_per_row``
+groups of each row, and a scale and zero per group. The program's own
+packer (``repro.core.bsr.pack_quantized`` and ``stack_bsr``) turns them
+into the served layout, so a change to that layout flows through here
+with no edit. The plain reference (``bench/references``) draws the same
+canonical arrays from the same keys and dequantizes them itself; it
+never reads the packed tree.
 
 Packing runs on the host, spread over worker processes, and the packed
 tree is kept in a cache inside the checkout, keyed by configuration,
-weight seed and a digest of the packer's sources, so only the first run
-of a checkout packs. Unpacked leaves (embedding, untied head, norms) are
-made on the device in one jitted call, in the leaf dtype the
-configuration states.
+weight seed, the linears' sizes and a digest of the packer's and the
+model module's sources, so only the first run of a checkout packs.
 """
 from __future__ import annotations
 
@@ -27,7 +28,7 @@ import shutil
 import time
 from concurrent.futures import ProcessPoolExecutor
 from multiprocessing import get_context
-from typing import Dict, List, Tuple
+from typing import Dict, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -37,57 +38,11 @@ BENCH = os.path.dirname(os.path.abspath(__file__))
 CHECKOUT = os.path.dirname(BENCH)
 SRC = os.path.join(CHECKOUT, "src")
 
-# stable fold-in ids: a linear's key is fold_in(fold_in(seed, layer), id)
-LINEAR_IDS = {"wq": 0, "wk": 1, "wv": 2, "wo": 3, "wg": 4, "wu": 5, "wd": 6}
-EMBED_ID, HEAD_ID = 10_000, 10_001
 # std of (q - z) for codes uniform on 0..15: sqrt((16^2 - 1) / 12)
 CODE_STD = 4.6098
-EMBED_STD = 0.02
 
-
-@dataclasses.dataclass(frozen=True)
-class Model:
-    """The sizes of a dense GQA decoder, read from a configuration file
-    (Hugging Face ``config.json`` key names)."""
-    layers: int
-    d: int
-    heads: int
-    kv_heads: int
-    head_dim: int
-    d_ff: int
-    vocab: int
-    gated: bool
-    tied: bool
-    rope_theta: float
-    eps: float
-
-    @classmethod
-    def from_conf(cls, m: Dict) -> "Model":
-        act = m["hidden_act"]
-        if act not in ("silu", "gelu_pytorch_tanh"):
-            raise ValueError(f"hidden_act {act!r}: the dense transformer "
-                             "runs SwiGLU (silu) or tanh-GELU MLPs")
-        return cls(layers=m["num_hidden_layers"], d=m["hidden_size"],
-                   heads=m["num_attention_heads"],
-                   kv_heads=m["num_key_value_heads"],
-                   head_dim=m.get("head_dim") or
-                   m["hidden_size"] // m["num_attention_heads"],
-                   d_ff=m["intermediate_size"], vocab=m["vocab_size"],
-                   gated=act == "silu",
-                   tied=bool(m.get("tie_word_embeddings", False)),
-                   rope_theta=float(m["rope_theta"]),
-                   eps=float(m.get("rms_norm_eps", m.get("norm_epsilon"))))
-
-    def linears(self) -> List[Tuple[str, str, int, int]]:
-        """(block, name, n_out, k_in) of every packed linear of a layer."""
-        q, kv = self.heads * self.head_dim, self.kv_heads * self.head_dim
-        out = [("attn", "wq", q, self.d), ("attn", "wk", kv, self.d),
-               ("attn", "wv", kv, self.d), ("attn", "wo", self.d, q)]
-        if self.gated:
-            out.append(("mlp", "wg", self.d_ff, self.d))
-        out += [("mlp", "wu", self.d_ff, self.d),
-                ("mlp", "wd", self.d, self.d_ff)]
-        return out
+# (block, name, id, n_out, k_in, lead shape) of one stacked linear
+Linear = Tuple[str, str, int, int, int, Tuple[int, ...]]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -112,9 +67,15 @@ class Compression:
                                 * (1.0 - self.sparsity))))
 
 
-def linear_key(seed: int, layer: int, name: str):
-    base = jax.random.fold_in(jax.random.PRNGKey(seed), layer)
-    return jax.random.fold_in(base, LINEAR_IDS[name])
+def slice_key(seed: int, index: Sequence, lid: int):
+    """Key of slice ``index`` (layer first) of linear ``lid``:
+    ``fold_in(fold_in(PRNGKey(seed), layer), lid)``, folded once more
+    for each further index (an expert's, say)."""
+    key = jax.random.fold_in(jax.random.fold_in(
+        jax.random.PRNGKey(seed), index[0]), lid)
+    for i in index[1:]:
+        key = jax.random.fold_in(key, i)
+    return key
 
 
 @functools.partial(jax.jit, static_argnums=(1, 2, 3, 4))
@@ -137,26 +98,6 @@ def canonical(key, n: int, k: int, g: int, m: int):
     return codes, gmask, scale, zero
 
 
-def dense_leaves(model: Model, seed: int, dtype):
-    """Embedding, untied head and norm weights, made on the default
-    device in one jitted call."""
-
-    def make():
-        key = jax.random.PRNGKey(seed)
-        out = {"embed": (jax.random.normal(
-            jax.random.fold_in(key, EMBED_ID), (model.vocab, model.d),
-            jnp.float32) * EMBED_STD).astype(dtype),
-            "ln1": jnp.ones((model.layers, model.d), dtype),
-            "ln2": jnp.ones((model.layers, model.d), dtype),
-            "final_norm": jnp.ones((model.d,), dtype)}
-        if not model.tied:
-            out["lm_head"] = (jax.random.normal(
-                jax.random.fold_in(key, HEAD_ID), (model.vocab, model.d),
-                jnp.float32) * EMBED_STD).astype(dtype)
-        return out
-    return jax.jit(make)()
-
-
 # ---------------------------------------------------------------------------
 # packing (host, worker processes)
 # ---------------------------------------------------------------------------
@@ -171,11 +112,12 @@ def _worker_init(src: str) -> None:
 
 
 def pack_one(task):
-    """Draw and pack one linear: returns its BSR leaves as numpy and the
-    static fields, so nothing device-side crosses the process boundary."""
-    seed, layer, name, n, k, g, m = task
+    """Draw and pack one slice of a linear: returns its BSR leaves as
+    numpy and the static fields, so nothing device-side crosses the
+    process boundary."""
+    seed, index, lid, n, k, g, m = task
     from repro.core.bsr import pack_quantized
-    arrs = [np.asarray(a) for a in canonical(linear_key(seed, layer, name),
+    arrs = [np.asarray(a) for a in canonical(slice_key(seed, index, lid),
                                              n, k, g, m)]
     bsr = pack_quantized(*arrs, group_size=g)
     leaves, aux = jax.tree_util.tree_flatten(bsr)
@@ -195,14 +137,15 @@ def _digest(paths: List[str]) -> str:
     return h.hexdigest()[:16]
 
 
-def cache_key(conf: Dict) -> str:
-    sizes = json.dumps([dataclasses.asdict(Model.from_conf(conf)),
-                        conf["compression"]], sort_keys=True)
+def cache_key(conf: Dict, linears: List[Linear], sources: List[str]) -> str:
+    """Name of the packed tree's cache directory: ``sources`` are the
+    model module's files, digested beside the packer's."""
+    sizes = json.dumps([linears, conf["compression"]], sort_keys=True)
     return "{}-w{}-{}-{}".format(
         conf["name"], conf["weight_seed"],
         hashlib.sha256(sizes.encode()).hexdigest()[:8],
         _digest([os.path.join(SRC, "repro", "core"),
-                 os.path.abspath(__file__)]))
+                 os.path.abspath(__file__)] + list(sources)))
 
 
 def _memory_available() -> int:
@@ -230,28 +173,28 @@ def _memory_available() -> int:
     return avail
 
 
-def pack_workers(model: Model, cap: int) -> int:
+def pack_workers(linears: List[Linear], cap: int) -> int:
     """Worker processes for packing: at most ``cap``, and as many as a
     fifth of the memory left to this process holds at ~22 bytes per
     weight of the largest linear (the measured peak of one ``pack_one``:
     1.6 GB at 14336 x 5120) plus 0.3 GB of runtime each. The rest is
     the parent's, which holds the packed results and the TPU runtime."""
-    largest = max(n * k for _, _, n, k in model.linears())
+    largest = max(n * k for _, _, _, n, k, _ in linears)
     per = 0.3e9 + 22 * largest
     return max(1, min(cap, int(0.2 * _memory_available() / per)))
 
 
-def _pack_all(model: Model, comp: Compression, seed: int, out_dir: str,
-              workers: int) -> None:
-    """Pack every linear of every layer, stack each linear over layers
-    with the program's ``stack_bsr`` and save its leaves under
+def _pack_all(linears: List[Linear], comp: Compression, seed: int,
+              out_dir: str, workers: int) -> None:
+    """Pack every slice of every linear, stack each linear over its lead
+    shape with the program's ``stack_bsr`` and save its leaves under
     ``out_dir``."""
     from repro.core.bsr import stack_bsr
     g = comp.group_size
-    tasks = [(seed, layer, name, n, k, g, comp.kept(k))
-             for _, name, n, k in model.linears()
-             for layer in range(model.layers)]
-    workers = pack_workers(model, workers)
+    tasks = [(seed, index, lid, n, k, g, comp.kept(k))
+             for _, _, lid, n, k, lead in linears
+             for index in np.ndindex(*lead)]
+    workers = pack_workers(linears, workers)
     if workers > 1:
         pool = ProcessPoolExecutor(max_workers=workers,
                                    mp_context=get_context("spawn"),
@@ -262,13 +205,13 @@ def _pack_all(model: Model, comp: Compression, seed: int, out_dir: str,
         pool, results = None, map(pack_one, tasks)
     try:
         meta = {}
-        for block, name, _, _ in model.linears():
+        for block, name, _, _, _, lead in linears:
             mats = []
-            for _ in range(model.layers):
+            for _ in range(int(np.prod(lead))):
                 leaves, aux = next(results)
                 mats.append(jax.tree_util.tree_unflatten(aux, leaves))
             with jax.default_device(jax.local_devices(backend="cpu")[0]):
-                st = stack_bsr(mats, (model.layers,))
+                st = stack_bsr(mats, tuple(lead))
             leaves, aux = jax.tree_util.tree_flatten(st)
             for i, leaf in enumerate(leaves):
                 np.save(os.path.join(out_dir, f"{name}.{i}.npy"),
@@ -304,32 +247,25 @@ def _load_packed(cache_dir: str, device):
     return tree
 
 
-def build_params(conf: Dict, cache_root: str, device, workers: int):
-    """The served parameter tree on ``device``, and the seconds spent in
-    each part of making it (``pack`` is 0 when the cache held it)."""
-    model = Model.from_conf(conf)
+def packed_layers(conf: Dict, linears: List[Linear], sources: List[str],
+                  cache_root: str, device, workers: int):
+    """The packed linears on ``device``, as ``{block: {name: {"bsr":
+    BSRMatrix}}}``, packed first where the cache does not hold them; and
+    the seconds spent in each part (``pack_s`` is 0 when the cache held
+    them)."""
     comp = Compression.from_conf(conf["compression"])
     parts = {"pack_s": 0.0}
-    cache_dir = os.path.join(cache_root, cache_key(conf))
+    cache_dir = os.path.join(cache_root, cache_key(conf, linears, sources))
     t = time.perf_counter()
     if not os.path.exists(os.path.join(cache_dir, "meta.json")):
         tmp = cache_dir + ".partial"
         shutil.rmtree(tmp, ignore_errors=True)
         os.makedirs(tmp)
-        _pack_all(model, comp, int(conf["weight_seed"]), tmp, workers)
+        _pack_all(linears, comp, int(conf["weight_seed"]), tmp, workers)
         os.replace(tmp, cache_dir)
         parts["pack_s"] = time.perf_counter() - t
     t = time.perf_counter()
     with jax.default_device(device):
-        layers = _load_packed(cache_dir, device)
-        dense = jax.block_until_ready(dense_leaves(
-            model, int(conf["weight_seed"]),
-            jnp.dtype(conf["leaf_dtype"])))
-    jax.block_until_ready(layers)
+        tree = jax.block_until_ready(_load_packed(cache_dir, device))
     parts["load_s"] = time.perf_counter() - t
-    layers["ln1"], layers["ln2"] = dense.pop("ln1"), dense.pop("ln2")
-    params = {"embed": dense["embed"], "layers": layers,
-              "final_norm": dense["final_norm"]}
-    if "lm_head" in dense:
-        params["lm_head"] = {"w": dense["lm_head"]}
-    return params, parts
+    return tree, parts
