@@ -15,15 +15,30 @@ class MoECfg:
     d_expert: int = 0         # per-expert FFN hidden size
     capacity_factor: float = 1.25
     router_aux_weight: float = 1e-2
+    # gates: the top-k softmax probabilities renormalised to sum to 1,
+    # or (False, DeepSeek-V2) taken as they are times the scaling factor
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
 
 
 @dataclasses.dataclass(frozen=True)
 class MLACfg:
     kv_lora_rank: int = 512
-    q_lora_rank: int = 1536
+    q_lora_rank: Optional[int] = 1536   # None: q is one direct projection
     qk_nope_dim: int = 128
     qk_rope_dim: int = 64
     v_dim: int = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnCfg:
+    """YaRN RoPE scaling (DeepSeek-V2's ``rope_scaling`` of type yarn)."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -50,10 +65,15 @@ class ModelConfig:
     moe: Optional[MoECfg] = None
     mla: Optional[MLACfg] = None
     ssm: Optional[SSMCfg] = None
+    # leading dense layers of an MoE model (DeepSeek's
+    # first_k_dense_replace), counted in n_layers, with their MLP width
+    n_dense_layers: int = 0
+    dense_d_ff: int = 0
     # architecture knobs
     mlp_type: str = "swiglu"          # swiglu | gelu
     qk_norm: bool = False
     rope_theta: float = 1e4
+    rope_scaling: Optional[YarnCfg] = None
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     # hybrid (zamba2): shared attention block applied every k SSM layers
@@ -113,9 +133,10 @@ class ModelConfig:
         elif self.family == "mla_moe":
             m, moe = self.mla, self.moe
             h = self.n_heads
-            attn = (d * m.q_lora_rank
-                    + m.q_lora_rank * h * (m.qk_nope_dim + m.qk_rope_dim)
-                    + d * (m.kv_lora_rank + m.qk_rope_dim)
+            q_out = h * (m.qk_nope_dim + m.qk_rope_dim)
+            q = d * q_out if m.q_lora_rank is None else \
+                d * m.q_lora_rank + m.q_lora_rank * q_out
+            attn = (q + d * (m.kv_lora_rank + m.qk_rope_dim)
                     + m.kv_lora_rank * h * (m.qk_nope_dim + m.v_dim)
                     + h * m.v_dim * d)
             expert = 3 * d * moe.d_expert
@@ -142,6 +163,11 @@ class ModelConfig:
             dec_layer = 2 * attn + mlp      # self + cross attention
             enc_layer = attn + mlp
             return emb + self.enc_layers * enc_layer + self.n_layers * dec_layer
+        if self.n_dense_layers:
+            # leading dense layers: the same attention, a dense SwiGLU
+            dense = attn + 3 * d * self.dense_d_ff
+            return emb + self.n_dense_layers * dense \
+                + (self.n_layers - self.n_dense_layers) * per_layer
         return emb + self.n_layers * per_layer
 
     def n_active_params(self) -> int:
@@ -151,7 +177,7 @@ class ModelConfig:
         full = self.n_params()
         expert = 3 * self.d_model * self.moe.d_expert
         inactive = (self.moe.n_experts - self.moe.top_k) * expert
-        return full - self.n_layers * inactive
+        return full - (self.n_layers - self.n_dense_layers) * inactive
 
 
 @dataclasses.dataclass(frozen=True)

@@ -58,6 +58,7 @@ from repro.engine.resilience import (ChaosDeviceError, PRESSURE_CRITICAL,
 from repro.engine.sampling import SamplingParams, sample
 from repro.engine.scheduler import DECODE, PREFILLING, Request, Scheduler
 from repro.engine.telemetry import Telemetry
+from repro.models.moe import expert_rows
 from repro.models.registry import get_model
 
 
@@ -274,6 +275,13 @@ class InferenceEngine:
         self._c_prefill_tokens = reg.counter("engine.prefill_tokens")
         self._c_pages_spanned = reg.counter("engine.decode_pages_spanned")
         self._c_pages_live = reg.counter("engine.decode_pages_live")
+        # MoE dispatch (models/moe.py:moe_serve): routed entries (real
+        # tokens x top_k x MoE layers) against the rows the expert
+        # kernels compute, per prefill and per decode dispatch
+        self._c_moe = {kind: (reg.counter(f"moe.routed_rows.{kind}"),
+                              reg.counter(f"moe.expert_rows.{kind}"))
+                       for kind in ("prefill", "decode")} \
+            if cfg.moe is not None else None
         self._ladder_rung: Optional[int] = None
         self.rcfg = engine_cfg.resilience if engine_cfg.resilience \
             is not None else ResilienceConfig()
@@ -650,6 +658,18 @@ class InferenceEngine:
         self._c_pages_live.inc(live)
         self._c_pages_spanned.inc(seg * self.ecfg.num_slots * self._max_live)
 
+    def _count_moe(self, kind: str, tokens: int, rows: int,
+                   dispatches: int = 1) -> None:
+        """Count an MoE model's ``dispatches`` of ``rows`` token rows that
+        carry ``tokens`` real tokens in all: their routed entries, and
+        the rows the expert kernels compute for them (static shapes)."""
+        if self._c_moe is None:
+            return
+        layers = self.cfg.n_layers - self.cfg.n_dense_layers
+        routed, computed = self._c_moe[kind]
+        routed.inc(tokens * self.cfg.moe.top_k * layers)
+        computed.inc(dispatches * expert_rows(self.cfg, rows) * layers)
+
     def _stamp_finishes(self, rids: List[int]) -> None:
         """Stamp the finish of requests whose last token the host has
         just seen ready."""
@@ -707,6 +727,8 @@ class InferenceEngine:
         if max_steps is not None:
             seg = min(seg, max_steps)
         self._count_decode_pages(actives, seg)
+        self._count_moe("decode", seg * len(actives), self.ecfg.num_slots,
+                        dispatches=seg)
         finished: List[Request] = []
         with tracer.span("decode_segment", steps=seg, slots=len(actives),
                          tokens=seg * len(actives)) as seg_sp:
@@ -964,6 +986,7 @@ class InferenceEngine:
                     jnp.asarray(lengths), jnp.asarray(bt), self._rng)
                 self._c_prefill_rows.inc(b * s)
                 self._c_prefill_tokens.inc(int(lengths.sum()))
+                self._count_moe("prefill", int(lengths.sum()), b * s)
                 with tracer.span("sync", cat="sync"):
                     jax.block_until_ready(first)
                 if tracer.enabled:
@@ -1015,6 +1038,7 @@ class InferenceEngine:
                     jnp.asarray(bt), self._rng, max_live)
                 self._c_prefill_rows.inc(b * t_pad)
                 self._c_prefill_tokens.inc(int(feed.sum()))
+                self._count_moe("prefill", int(feed.sum()), b * t_pad)
                 with tracer.span("sync", cat="sync"):
                     jax.block_until_ready(first_t)
                 if tracer.enabled:
@@ -1114,6 +1138,7 @@ class InferenceEngine:
                 jnp.asarray(bt), self._rng, max_live)
             self._c_prefill_rows.inc(b * t_pad)
             self._c_prefill_tokens.inc(int(feed.sum()))
+            self._count_moe("prefill", int(feed.sum()), b * t_pad)
             if finals:
                 # completed prefills take their TTFT timestamp here, so
                 # the first token must actually exist (same convention

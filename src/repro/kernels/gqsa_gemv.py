@@ -77,8 +77,11 @@ def _kernel(work_ref,                                   # scalar prefetch
             words_ref, scale_ref, zero_ref, pos_ref, x_ref,   # VMEM in
             y_ref,                                      # VMEM out
             wd_ref,                                     # scratch
-            *, group_size: int, lane: int, cp: int):
-    w = pl.program_id(1)
+            *, group_size: int, lane: int, cp: int, items: int = 0):
+    # over an expert stack the work lists lie end to end, ``items`` each,
+    # and the grid's first axis walks the experts
+    w = pl.program_id(0) * items + pl.program_id(2) if items \
+        else pl.program_id(1)
     _place(work_ref, words_ref, scale_ref, zero_ref, pos_ref, wd_ref, w,
            group_size=group_size, lane=lane, cp=cp)
 
@@ -94,8 +97,10 @@ def _densify_kernel(work_ref,                                   # prefetch
                     words_ref, scale_ref, zero_ref, pos_ref,    # VMEM in
                     o_ref,                                      # VMEM out
                     wd_ref,                                     # scratch
-                    *, group_size: int, lane: int, cp: int, c: int):
-    w = pl.program_id(0)
+                    *, group_size: int, lane: int, cp: int, c: int,
+                    items: int = 0):
+    w = pl.program_id(0) * items + pl.program_id(1) if items \
+        else pl.program_id(0)
     _place(work_ref, words_ref, scale_ref, zero_ref, pos_ref, wd_ref, w,
            group_size=group_size, lane=lane, cp=cp)
 
@@ -153,6 +158,111 @@ def gqsa_gemv_pallas(x: jnp.ndarray, words: jnp.ndarray, scale: jnp.ndarray,
         interpret=interpret,
         name=f"gqsa_gemv_{label}" if label else "gqsa_gemv",
     )(work, words, scale, zero, pos, x)
+
+
+def gqsa_gemv_experts_pallas(x: jnp.ndarray, words: jnp.ndarray,
+                             scale: jnp.ndarray, zero: jnp.ndarray,
+                             pos: jnp.ndarray, work: jnp.ndarray, *,
+                             group_size: int, block_n: int, lane: int,
+                             block_t: int, label: str = "",
+                             interpret: bool = False) -> jnp.ndarray:
+    """:func:`gqsa_gemv_pallas` over a stack of E experts in one kernel:
+    x [E, T, G*Cp] and every packed leaf with a leading E (work
+    [E, 4, W]). Returns [E, T, Np] f32. The grid's outer axis walks the
+    experts, so each expert's payload streams through VMEM once per
+    activation tile, as one expert's would. Named
+    ``gqsa_gemv_<label>``."""
+    e, t, kp = x.shape
+    np_, mp = scale.shape[1:]
+    cp = pos.shape[-1]
+    n_words = words.shape[1]
+    items = work.shape[-1]
+    flat = work.transpose(1, 0, 2).reshape(4, e * items)
+
+    def item(ei, w, wk):
+        return wk[0, ei * items + w], wk[1, ei * items + w]
+
+    def payload(ei, i, w, wk):
+        return (ei,) + item(ei, w, wk)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(e, t // block_t, items),
+        in_specs=[
+            pl.BlockSpec((None, n_words, block_n, lane),
+                         lambda ei, i, w, wk: (ei, 0) + item(ei, w, wk)),
+            pl.BlockSpec((None, block_n, lane), payload),
+            pl.BlockSpec((None, block_n, lane), payload),
+            pl.BlockSpec((None, block_n, cp),
+                         lambda ei, i, w, wk: (ei, item(ei, w, wk)[0], 0)),
+            pl.BlockSpec((None, block_t, kp),
+                         lambda ei, i, w, wk: (ei, i, 0)),
+        ],
+        out_specs=pl.BlockSpec(
+            (None, block_t, block_n),
+            lambda ei, i, w, wk: (ei, i, item(ei, w, wk)[0])),
+        scratch_shapes=[pltpu.VMEM((block_n, kp), jnp.float32)],
+    )
+    kernel = functools.partial(_kernel, group_size=group_size, lane=lane,
+                               cp=cp, items=items)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((e, t, np_), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+        name=f"gqsa_gemv_{label}" if label else "gqsa_gemv",
+    )(flat, words, scale, zero, pos, x)
+
+
+def gqsa_densify_experts_pallas(words: jnp.ndarray, scale: jnp.ndarray,
+                                zero: jnp.ndarray, pos: jnp.ndarray,
+                                work: jnp.ndarray, *, group_size: int,
+                                block_n: int, lane: int, c: int, dtype,
+                                label: str = "",
+                                interpret: bool = False) -> jnp.ndarray:
+    """:func:`gqsa_densify_pallas` over a stack of E experts in one
+    kernel (leaves with a leading E, work [E, 4, W]): [E, Np, G*C].
+    Named ``gqsa_densify_<label>``."""
+    e, np_ = scale.shape[:2]
+    cp = pos.shape[-1]
+    n_words = words.shape[1]
+    items = work.shape[-1]
+    flat = work.transpose(1, 0, 2).reshape(4, e * items)
+
+    def row(ei, w, wk):
+        return wk[0, ei * items + w]
+
+    def payload(ei, w, wk):
+        return (ei, row(ei, w, wk), wk[1, ei * items + w])
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(e, items),
+        in_specs=[
+            pl.BlockSpec((None, n_words, block_n, lane),
+                         lambda ei, w, wk: (ei, 0) + payload(ei, w, wk)[1:]),
+            pl.BlockSpec((None, block_n, lane), payload),
+            pl.BlockSpec((None, block_n, lane), payload),
+            pl.BlockSpec((None, block_n, cp),
+                         lambda ei, w, wk: (ei, row(ei, w, wk), 0)),
+        ],
+        out_specs=pl.BlockSpec((None, block_n, group_size * c),
+                               lambda ei, w, wk: (ei, row(ei, w, wk), 0)),
+        scratch_shapes=[pltpu.VMEM((block_n, group_size * cp), jnp.float32)],
+    )
+    kernel = functools.partial(_densify_kernel, group_size=group_size,
+                               lane=lane, cp=cp, c=c, items=items)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((e, np_, group_size * c), dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+        name=f"gqsa_densify_{label}" if label else "gqsa_densify",
+    )(flat, words, scale, zero, pos)
 
 
 def gqsa_densify_pallas(words: jnp.ndarray, scale: jnp.ndarray,

@@ -14,7 +14,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.bsr import BSRMatrix
-from repro.kernels.gqsa_gemv import gqsa_densify_pallas, gqsa_gemv_pallas
+from repro.kernels.gqsa_gemv import (gqsa_densify_experts_pallas,
+                                     gqsa_densify_pallas,
+                                     gqsa_gemv_experts_pallas,
+                                     gqsa_gemv_pallas)
 from repro.kernels.gqsa_gemv import DEFAULT_BLOCK_T as DEFAULT_GEMV_BLOCK_T
 from repro.kernels.paged_attention import paged_attention_pallas
 from repro.kernels.w4_matmul import (w4_matmul_pallas, DEFAULT_BLOCK_T,
@@ -72,11 +75,7 @@ def gqsa_gemv(x: jnp.ndarray, bsr: BSRMatrix,
     cp = bsr.pos.shape[-1]
     xg = x.reshape(t, k // g, g).transpose(0, 2, 1)           # [T, G, C]
     if t > DEFAULT_GEMV_BLOCK_T:
-        wd = gqsa_densify_pallas(bsr.words, bsr.scale, bsr.zero, bsr.pos,
-                                 bsr.work, group_size=g,
-                                 block_n=bsr.block_n, lane=bsr.lane,
-                                 c=k // g, dtype=x.dtype, label=label,
-                                 interpret=_interpret())
+        wd = gqsa_densify(bsr, x.dtype, label)
         # HIGHEST keeps f32 activations' products in f32, as the fused
         # kernel does; bf16 operands take one MXU pass either way
         y = jax.lax.dot_general(xg.reshape(t, k), wd,
@@ -91,6 +90,87 @@ def gqsa_gemv(x: jnp.ndarray, bsr: BSRMatrix,
                          block_n=bsr.block_n, lane=bsr.lane, block_t=bt,
                          label=label, interpret=_interpret())
     return y[:t, :n]
+
+
+def gqsa_densify(bsr: BSRMatrix, dtype, label: str = "") -> jnp.ndarray:
+    """The weight of ``bsr`` dense in HBM, [Np, K] of ``dtype`` with its
+    columns in group-column-major order (:func:`group_major` orders the
+    activations to match), by the kernel ``gqsa_densify_<label>``."""
+    k = bsr.shape[1]
+    g = bsr.group_size
+    return gqsa_densify_pallas(bsr.words, bsr.scale, bsr.zero, bsr.pos,
+                               bsr.work, group_size=g, block_n=bsr.block_n,
+                               lane=bsr.lane, c=k // g, dtype=dtype,
+                               label=label, interpret=_interpret())
+
+
+def group_major(x: jnp.ndarray, group_size: int) -> jnp.ndarray:
+    """[T, K] activations in the column order of :func:`gqsa_densify`."""
+    t, k = x.shape
+    return x.reshape(t, k // group_size, group_size).transpose(
+        0, 2, 1).reshape(t, k)
+
+
+def gqsa_gemv_experts(x: jnp.ndarray, bsr: BSRMatrix,
+                      label: str = "") -> jnp.ndarray:
+    """y[e] = x[e] @ dense(bsr[e]).T for a stack of E experts, by one
+    fused kernel ``gqsa_gemv_<label>`` over all of them: x [E, T, K]
+    with T at most DEFAULT_GEMV_BLOCK_T (decode), bsr with leaves led
+    by E. Returns [E, T, N] f32."""
+    e, t, k = x.shape
+    n = bsr.shape[0]
+    g = bsr.group_size
+    cp = bsr.pos.shape[-1]
+    if t > DEFAULT_GEMV_BLOCK_T:
+        raise ValueError(f"{t} rows per expert: the expert GEMV serves "
+                         f"one activation tile ({DEFAULT_GEMV_BLOCK_T})")
+    xg = x.reshape(e, t, k // g, g).transpose(0, 1, 3, 2)      # [E,T,G,C]
+    xg = _pad_to(xg, 3, cp).reshape(e, t, g * cp)
+    bt = _row_tile(t, DEFAULT_GEMV_BLOCK_T)
+    y = gqsa_gemv_experts_pallas(_pad_to(xg, 1, bt), bsr.words, bsr.scale,
+                                 bsr.zero, bsr.pos, bsr.work, group_size=g,
+                                 block_n=bsr.block_n, lane=bsr.lane,
+                                 block_t=bt, label=label,
+                                 interpret=_interpret())
+    return y[:, :t, :n]
+
+
+def gqsa_densify_experts(bsr: BSRMatrix, dtype,
+                         label: str = "") -> jnp.ndarray:
+    """:func:`gqsa_densify` of a stack of E experts by one kernel
+    ``gqsa_densify_<label>``: [E, Np, K], columns group-column-major."""
+    k = bsr.shape[1]
+    g = bsr.group_size
+    return gqsa_densify_experts_pallas(
+        bsr.words, bsr.scale, bsr.zero, bsr.pos, bsr.work, group_size=g,
+        block_n=bsr.block_n, lane=bsr.lane, c=k // g, dtype=dtype,
+        label=label, interpret=_interpret())
+
+
+def grouped_matmul(xs: jnp.ndarray, w: jnp.ndarray,
+                   sizes: jnp.ndarray) -> jnp.ndarray:
+    """Rows of ``xs`` [M, K], sorted by group, each times its group's
+    ``w`` [E, N, K] transposed: the grouped (megablox) matmul kernel.
+    ``sizes`` [E] counts each group's rows; rows past their sum are
+    zero. Returns [M, N] f32."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    m, k = xs.shape
+    n = w.shape[1]
+    tm = 128
+    y = gmm(_pad_to(xs, 0, tm), w, sizes, preferred_element_type=jnp.float32,
+            tiling=(tm, _tile(k), _tile(n)), transpose_rhs=True,
+            interpret=_interpret())[:m]
+    rows = jnp.arange(m) < jnp.sum(sizes)
+    return jnp.where(rows[:, None], y, 0.0)
+
+
+def _tile(size: int) -> int:
+    """A grouped-matmul tile of a K or N dim: 512 where it divides, else
+    the largest multiple of 128 that does."""
+    for t in (512, 384, 256, 128):
+        if size % t == 0:
+            return t
+    return size
 
 
 def _block_r(rows: int, group_size: int) -> int:

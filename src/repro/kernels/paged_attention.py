@@ -264,5 +264,7 @@ def paged_attention_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        name="paged_attention",
+        # the latent mode carries its own name, so a device trace tells
+        # MLA attention apart from GQA attention
+        name="paged_latent_attention" if latent else "paged_attention",
     )(*prefetch, *args)

@@ -67,10 +67,12 @@ def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
 
 
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
-               theta: float) -> jnp.ndarray:
-    """x: [..., S, H, D]; positions: [..., S] (broadcastable)."""
+               theta: float,
+               inv_freq: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """x: [..., S, H, D]; positions: [..., S] (broadcastable).
+    ``inv_freq`` [D/2] replaces the plain frequencies (YaRN)."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)                            # [D/2]
+    freqs = rope_freqs(d, theta) if inv_freq is None else inv_freq
     ang = positions[..., None].astype(jnp.float32) * freqs  # [..., S, D/2]
     cos = jnp.cos(ang)[..., None, :]                        # [..., S, 1, D/2]
     sin = jnp.sin(ang)[..., None, :]

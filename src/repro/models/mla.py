@@ -6,12 +6,21 @@ memory win. Decode uses the *absorbed* formulation: q_nope is projected
 through W_UK once per step so scores contract directly against the latent
 cache (no per-step K up-projection over the whole history).
 
-GQSA note: w_qa/w_qb/w_kva/wo are GQS-compressible GEMVs; w_uk/w_uv are used
-in per-head einsum form (absorbed path) and stay dense FP (~8M params each —
-documented exclusion, DESIGN.md §6).
+GQSA note: w_qa/w_qb (or the direct wq when there is no q LoRA), w_kva and
+wo are GQS-compressible GEMVs; w_uk/w_uv are used in per-head einsum form
+(absorbed path) and stay dense FP (~8M params each — documented exclusion,
+DESIGN.md §6).
+
+YaRN (``cfg.rope_scaling``) follows DeepSeek-V2's published modelling
+code: the rope inverse frequencies ramp from the original ones to the
+interpolated ones (÷ factor) over the pair indices between the
+correction dims of ``beta_fast`` and ``beta_slow``, and the softmax
+scale is multiplied by mscale(factor, mscale_all_dim)². The factor is
+put on q, so the flash prefill and the absorbed decode agree.
 """
 from __future__ import annotations
 
+import math
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -21,18 +30,62 @@ from repro.core.gqs_layer import apply_linear
 from repro.models.layers import (apply_rope, decode_attention,
                                  flash_attention, linear_init, norm_init,
                                  page_write, paged_block_geometry,
-                                 paged_write_pages, rmsnorm)
+                                 paged_write_pages, rmsnorm, rope_freqs)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freqs(dim: int, theta: float, y) -> jnp.ndarray:
+    """[dim/2] YaRN inverse frequencies of a rope head of ``dim``."""
+    def corr_dim(rotations):
+        return dim * math.log(y.original_max_position
+                              / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    low = max(math.floor(corr_dim(y.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(y.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    extra = rope_freqs(dim, theta)
+    return extra / y.factor * ramp + extra * (1.0 - ramp)
+
+
+def softmax_gain(cfg) -> float:
+    """What YaRN multiplies the softmax scale by: mscale(factor,
+    mscale_all_dim)², or 1."""
+    y = cfg.rope_scaling
+    if y is None or not y.mscale_all_dim:
+        return 1.0
+    return yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+
+
+def _rope(x: jnp.ndarray, positions, cfg) -> jnp.ndarray:
+    y = cfg.rope_scaling
+    if y is None:
+        return apply_rope(x, positions, cfg.rope_theta)
+    out = apply_rope(x, positions, cfg.rope_theta,
+                     yarn_inv_freqs(x.shape[-1], cfg.rope_theta, y))
+    gain = yarn_mscale(y.factor, y.mscale) \
+        / yarn_mscale(y.factor, y.mscale_all_dim)
+    return out if gain == 1.0 else out * jnp.asarray(gain, out.dtype)
 
 
 def mla_init(rng, cfg, dtype=jnp.float32) -> Dict:
     m = cfg.mla
     d, h = cfg.d_model, cfg.n_heads
     ks = jax.random.split(rng, 7)
+    q_out = h * (m.qk_nope_dim + m.qk_rope_dim)
+    if m.q_lora_rank is None:
+        q = {"wq": linear_init(ks[0], q_out, d, dtype)}
+    else:
+        q = {"w_qa": linear_init(ks[0], m.q_lora_rank, d, dtype),
+             "q_norm": norm_init(m.q_lora_rank, dtype),
+             "w_qb": linear_init(ks[1], q_out, m.q_lora_rank, dtype)}
     return {
-        "w_qa": linear_init(ks[0], m.q_lora_rank, d, dtype),
-        "q_norm": norm_init(m.q_lora_rank, dtype),
-        "w_qb": linear_init(ks[1], h * (m.qk_nope_dim + m.qk_rope_dim),
-                            m.q_lora_rank, dtype),
+        **q,
         "w_kva": linear_init(ks[2], m.kv_lora_rank + m.qk_rope_dim, d, dtype),
         "kv_norm": norm_init(m.kv_lora_rank, dtype),
         "w_uk": jax.random.normal(ks[3], (h, m.qk_nope_dim, m.kv_lora_rank),
@@ -44,25 +97,32 @@ def mla_init(rng, cfg, dtype=jnp.float32) -> Dict:
 
 
 def _mla_q(p: Dict, x: jnp.ndarray, positions, cfg, use_pallas):
+    """q split into (nope, rope) heads, carrying YaRN's softmax gain."""
     m = cfg.mla
     b, s, _ = x.shape
     h = cfg.n_heads
-    cq = rmsnorm(apply_linear(p["w_qa"], x, use_pallas=use_pallas),
-                 p["q_norm"], cfg.norm_eps)
-    q = apply_linear(p["w_qb"], cq, use_pallas=use_pallas)
+    if "wq" in p:
+        q = apply_linear(p["wq"], x, use_pallas=use_pallas, label="wq")
+    else:
+        cq = rmsnorm(apply_linear(p["w_qa"], x, use_pallas=use_pallas,
+                                  label="wqa"),
+                     p["q_norm"], cfg.norm_eps)
+        q = apply_linear(p["w_qb"], cq, use_pallas=use_pallas, label="wqb")
+    gain = softmax_gain(cfg)
+    if gain != 1.0:
+        q = q * jnp.asarray(gain, q.dtype)
     q = q.reshape(b, s, h, m.qk_nope_dim + m.qk_rope_dim)
     q_nope, q_rope = jnp.split(q, [m.qk_nope_dim], axis=-1)
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
-    return q_nope, q_rope
+    return q_nope, _rope(q_rope, positions, cfg)
 
 
 def _mla_kv_latent(p: Dict, x: jnp.ndarray, positions, cfg, use_pallas):
     m = cfg.mla
-    ckv_full = apply_linear(p["w_kva"], x, use_pallas=use_pallas)
+    ckv_full = apply_linear(p["w_kva"], x, use_pallas=use_pallas,
+                            label="wkva")
     c_kv, k_rope = jnp.split(ckv_full, [m.kv_lora_rank], axis=-1)
     c_kv = rmsnorm(c_kv, p["kv_norm"], cfg.norm_eps)
-    k_rope = apply_rope(k_rope[:, :, None, :], positions,
-                        cfg.rope_theta)[:, :, 0, :]
+    k_rope = _rope(k_rope[:, :, None, :], positions, cfg)[:, :, 0, :]
     return c_kv, k_rope
 
 
@@ -95,7 +155,8 @@ def mla_prefill_paged(p: Dict, x: jnp.ndarray, positions: jnp.ndarray, cfg,
     o = flash_attention(q, k, v, causal=True,
                         block_q=cfg.attn_block_q, block_k=cfg.attn_block_k,
                         unroll=cfg.analysis_unroll)
-    out = apply_linear(p["wo"], o.reshape(b, s, -1), use_pallas=use_pallas)
+    out = apply_linear(p["wo"], o.reshape(b, s, -1), use_pallas=use_pallas,
+                       label="wo")
     return out, jnp.concatenate([c_kv, k_rope], axis=-1)
 
 
@@ -149,8 +210,8 @@ def mla_decode(p: Dict, x: jnp.ndarray, cache: Dict, pos, cfg,
     ctx = decode_attention(q_scaled, k_cat, c_kv[:, :, None, :], pos + 1)
     # ctx: [B,1,H,R] -> per-head value up-projection
     v = jnp.einsum("bshr,hvr->bshv", ctx, p["w_uv"].astype(ctx.dtype))
-    return apply_linear(p["wo"], v.reshape(b, 1, -1), use_pallas=use_pallas)\
-        , {"c_kv": c_kv, "k_rope": k_rope}
+    return apply_linear(p["wo"], v.reshape(b, 1, -1), use_pallas=use_pallas,
+                        label="wo"), {"c_kv": c_kv, "k_rope": k_rope}
 
 
 def mla_decode_paged(p: Dict, x: jnp.ndarray, cache: Dict,
@@ -211,4 +272,4 @@ def mla_decode_paged(p: Dict, x: jnp.ndarray, cache: Dict,
                                anc, base, window)
     v = jnp.einsum("bshr,hvr->bshv", ctx, p["w_uv"].astype(ctx.dtype))
     return apply_linear(p["wo"], v.reshape(b, t, -1),
-                        use_pallas=use_pallas), new
+                        use_pallas=use_pallas, label="wo"), new

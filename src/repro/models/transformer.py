@@ -2,6 +2,13 @@
 
 Layers are weight-stacked and iterated with lax.scan (small HLO, fast
 compiles at 60+ layers); the per-layer body is remat'd when cfg.remat.
+
+An MoE model with leading dense layers (``cfg.n_dense_layers``,
+DeepSeek's first_k_dense_replace) keeps them in a stack of their own,
+``params["dense_layers"]``, scanned before ``params["layers"]``; their
+cache leaves carry the prefix ``dense_``, so each group of layers has its
+own pool leaf and a step never joins them. A layer's FFN is the MoE
+block where its params hold ``moe``, else its dense MLP.
 """
 from __future__ import annotations
 
@@ -21,7 +28,10 @@ from repro.models import moe as MOE
 # init
 # ---------------------------------------------------------------------------
 
-def _layer_init(rng, cfg, dtype):
+DENSE = "dense_"          # cache-leaf prefix of the leading dense layers
+
+
+def _layer_init(rng, cfg, dtype, dense: bool = False):
     ks = jax.random.split(rng, 4)
     p = {"ln1": L.norm_init(cfg.d_model, dtype),
          "ln2": L.norm_init(cfg.d_model, dtype)}
@@ -29,11 +39,11 @@ def _layer_init(rng, cfg, dtype):
         p["attn"] = MLA.mla_init(ks[0], cfg, dtype)
     else:
         p["attn"] = L.attn_init(ks[0], cfg, dtype)
-    if cfg.moe is not None:
+    if cfg.moe is not None and not dense:
         p["moe"] = MOE.moe_init(ks[1], cfg, dtype)
     else:
-        p["mlp"] = L.mlp_init(ks[1], cfg.d_model, cfg.d_ff, cfg.mlp_type,
-                              dtype)
+        d_ff = cfg.dense_d_ff if cfg.moe is not None else cfg.d_ff
+        p["mlp"] = L.mlp_init(ks[1], cfg.d_model, d_ff, cfg.mlp_type, dtype)
     return p
 
 
@@ -41,12 +51,18 @@ def init_params(rng, cfg) -> Dict:
     dtype = cfg.params_dtype
     k_embed, k_layers, k_head = jax.random.split(rng, 3)
     layer_keys = jax.random.split(k_layers, cfg.n_layers)
+    nd = cfg.n_dense_layers
     params = {
         "embed": jax.random.normal(k_embed, (cfg.vocab, cfg.d_model),
                                    dtype) * 0.02,
-        "layers": jax.vmap(lambda k: _layer_init(k, cfg, dtype))(layer_keys),
+        "layers": jax.vmap(lambda k: _layer_init(k, cfg, dtype))(
+            layer_keys[nd:]),
         "final_norm": L.norm_init(cfg.d_model, dtype),
     }
+    if nd:
+        params["dense_layers"] = jax.vmap(
+            lambda k: _layer_init(k, cfg, dtype, dense=True))(
+                layer_keys[:nd])
     if not cfg.tie_embeddings:
         params["lm_head"] = L.linear_init(k_head, cfg.vocab, cfg.d_model,
                                           dtype, scale=0.02)
@@ -69,11 +85,47 @@ def _block(lp: Dict, h: jnp.ndarray, positions: jnp.ndarray, cfg, dist,
                               dist=dist)
     h = h + a
     hn = L.rmsnorm(h, lp["ln2"], cfg.norm_eps)
-    if cfg.moe is not None:
+    if "moe" in lp:
         m, aux = MOE.moe_block(lp["moe"], hn, cfg, dist, use_pallas)
     else:
         m, aux = L.mlp_block(lp["mlp"], hn, cfg.mlp_type, use_pallas), 0.0
     return h + m, jnp.asarray(aux, jnp.float32)
+
+
+def _ffn_serve(lp: Dict, hn: jnp.ndarray, cfg, dist, use_pallas,
+               valid=None) -> jnp.ndarray:
+    """A layer's FFN in the serving steps: the dropless MoE block
+    (`models/moe.py:moe_serve`), or under a mesh the EP block with its
+    capacity; the dense MLP where the layer has no experts."""
+    if "moe" not in lp:
+        return L.mlp_block(lp["mlp"], hn, cfg.mlp_type, use_pallas)
+    if dist is not None:
+        return MOE.moe_block(lp["moe"], hn, cfg, dist, use_pallas)[0]
+    return MOE.moe_serve(lp["moe"], hn, cfg, use_pallas, valid)
+
+
+def _groups(params: Dict, cache: Optional[Dict] = None):
+    """(cache-leaf prefix, layer stack, that stack's cache) of each group
+    of layers in order: the leading dense layers, then the rest."""
+    out = []
+    if "dense_layers" in params:
+        out.append((DENSE, params["dense_layers"], None if cache is None
+                    else {k[len(DENSE):]: v for k, v in cache.items()
+                          if k.startswith(DENSE)}))
+    out.append(("", params["layers"], None if cache is None
+                else {k: v for k, v in cache.items()
+                      if not k.startswith(DENSE)}))
+    return out
+
+
+def _scan_groups(body, h, params: Dict, cache: Dict):
+    """lax.scan ``body`` over every group of layers with its cache;
+    returns (h, the new cache with each group's leaves as they came)."""
+    new = {}
+    for prefix, stack, lc in _groups(params, cache):
+        h, nc = jax.lax.scan(body, h, (stack, lc))
+        new.update({prefix + k: v for k, v in nc.items()})
+    return h, new
 
 
 def embed_tokens(params: Dict, tokens: jnp.ndarray, cfg) -> jnp.ndarray:
@@ -128,7 +180,10 @@ def forward(params: Dict, tokens: jnp.ndarray, cfg, dist=None,
     if cfg.remat:
         body = jax.checkpoint(body,
                               policy=jax.checkpoint_policies.nothing_saveable)
-    (h, aux), _ = jax.lax.scan(body, (h, jnp.float32(0.0)), params["layers"])
+    carry = (h, jnp.float32(0.0))
+    for _, stack, _ in _groups(params):
+        carry, _ = jax.lax.scan(body, carry, stack)
+    h, aux = carry
     if last_only:
         h = h[:, -1:, :]
     logits = unembed(params, h, cfg)
@@ -139,9 +194,24 @@ def forward(params: Dict, tokens: jnp.ndarray, cfg, dist=None,
 # decode
 # ---------------------------------------------------------------------------
 
+def _with_dense(make, cfg) -> Dict:
+    """A cache of ``make(layers)`` leaves for the main stack, and the
+    same under the ``dense_`` prefix for the leading dense layers."""
+    nd = cfg.n_dense_layers
+    out = make(cfg.n_layers - nd)
+    if nd:
+        out.update({DENSE + k: v for k, v in make(nd).items()})
+    return out
+
+
 def init_cache(cfg, batch: int, max_seq: int, dtype=None) -> Dict:
     dtype = dtype or cfg.compute_dtype
-    lyr = cfg.n_layers
+    return _with_dense(lambda lyr: _contiguous_cache(cfg, lyr, batch,
+                                                     max_seq, dtype), cfg)
+
+
+def _contiguous_cache(cfg, lyr: int, batch: int, max_seq: int,
+                      dtype) -> Dict:
     if cfg.family == "mla_moe":
         m = cfg.mla
         return {"c_kv": jnp.zeros((lyr, batch, max_seq, m.kv_lora_rank),
@@ -176,7 +246,12 @@ def init_paged_cache(cfg, num_pages: int, page_size: int,
     are a recorded follow-on, ROADMAP). int8 pages carry per-token x head
     scales [L, KH, P, ps, 1]."""
     dtype = dtype or cfg.compute_dtype
-    lyr = cfg.n_layers
+    return _with_dense(lambda lyr: _paged_cache(cfg, lyr, num_pages,
+                                                page_size, dtype), cfg)
+
+
+def _paged_cache(cfg, lyr: int, num_pages: int, page_size: int,
+                 dtype) -> Dict:
     if cfg.family == "mla_moe":
         m = cfg.mla
         return {"lat_pages": jnp.zeros(
@@ -214,7 +289,8 @@ def prefill(params: Dict, cache: Dict, tokens: jnp.ndarray,
     # (b, s) -> flat page/offset; invalid (padding) positions -> OOB page
     page = jnp.take_along_axis(
         block_tables, positions // page_size, axis=1)       # [B, S]
-    page = jnp.where(positions < lengths[:, None], page, num_pages)
+    valid = positions < lengths[:, None]
+    page = jnp.where(valid, page, num_pages)
     off = positions % page_size
     mla = cfg.family == "mla_moe"
     int8 = "k_scale_pages" in cache
@@ -254,13 +330,9 @@ def prefill(params: Dict, cache: Dict, tokens: jnp.ndarray,
                     "v_pages": L.page_write(lc["v_pages"], page, off, v)}
         hh = hh + a
         hn = L.rmsnorm(hh, lp["ln2"], cfg.norm_eps)
-        if cfg.moe is not None:
-            m, _ = MOE.moe_block(lp["moe"], hn, cfg, dist, use_pallas)
-        else:
-            m = L.mlp_block(lp["mlp"], hn, cfg.mlp_type, use_pallas)
-        return hh + m, new_c
+        return hh + _ffn_serve(lp, hn, cfg, dist, use_pallas, valid), new_c
 
-    h, new_cache = jax.lax.scan(body, h, (params["layers"], cache))
+    h, new_cache = _scan_groups(body, h, params, cache)
     # logits only at each row's last valid token (cheap unembed: [B, 1, V])
     h_last = jnp.take_along_axis(
         h, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1)
@@ -339,12 +411,10 @@ def decode_step(params: Dict, cache: Dict, tokens: jnp.ndarray,
                                           use_pallas)
         hh = hh + a
         hn = L.rmsnorm(hh, lp["ln2"], cfg.norm_eps)
-        if cfg.moe is not None:
-            m, _ = MOE.moe_block(lp["moe"], hn, cfg, dist, use_pallas)
-        else:
-            m = L.mlp_block(lp["mlp"], hn, cfg.mlp_type, use_pallas)
-        return hh + m, new_c
+        return hh + _ffn_serve(lp, hn, cfg, dist, use_pallas, valid), new_c
 
-    h, new_cache = jax.lax.scan(body, h, (params["layers"], cache))
+    valid = None if feed_len is None else \
+        jnp.arange(tokens.shape[1])[None, :] < feed_len[:, None]
+    h, new_cache = _scan_groups(body, h, params, cache)
     logits = unembed(params, h, cfg)
     return logits, new_cache

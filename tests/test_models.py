@@ -87,7 +87,7 @@ def test_arch_decode_matches_forward(arch):
 
 def test_train_shapes_all_archs_listed():
     assert len(list_archs()) == 10
-    assert len(ARCH_IDS) == 11  # + the paper's llama2-7b
+    assert len(ARCH_IDS) == 12  # + the paper's llama2-7b, deepseek-v2-lite
 
 
 def test_moe_aux_loss_nonzero_and_balanced_router():

@@ -164,3 +164,124 @@ def test_paged_latent_attention_compiles(one_chip, t):
              ((1, POOL_PAGES, PAGE, d), jnp.bfloat16),
              ((BATCH, t), jnp.int32), ((BATCH, MAX_PAGES), jnp.int32),
              ((BATCH,), jnp.int32))
+
+
+def test_latent_and_gqa_kernels_carry_their_own_names(one_chip):
+    """The latent mode compiles as ``paged_latent_attention`` and the GQA
+    mode keeps ``paged_attention``, so a device trace tells them apart."""
+    def text(latent):
+        d, kh = (576, 1) if latent else (HEAD_DIM, KV_HEADS)
+        rows = 16 if latent else GROUPS
+
+        def fn(q, k, v, lengths, bt, live):
+            return paged_attention_pallas(q, k, None if latent else v,
+                                          lengths, bt, live, t=1, r=rows)
+        pool = ((kh, POOL_PAGES, PAGE, d), jnp.bfloat16)
+        return _compile(fn, one_chip, ((BATCH, kh, 16, d), jnp.bfloat16),
+                        pool, pool, ((BATCH, 1), jnp.int32),
+                        ((BATCH, MAX_PAGES), jnp.int32),
+                        ((BATCH,), jnp.int32)).as_text()
+    latent, gqa = text(True), text(False)
+    assert "%paged_latent_attention" in latent
+    assert "%paged_attention" in gqa and "paged_latent" not in gqa
+
+
+# DeepSeek-V2-Lite's expert linears: 64 experts of [1408, 2048] (gate,
+# up) and [2048, 1408] (down)
+@pytest.mark.parametrize("n,k", [(1408, 2048), (2048, 1408)])
+def test_expert_stack_kernels_compile(one_chip, n, k):
+    """The fused GEMV over a 64-expert stack at a 32-row decode buffer
+    and the stack's densify pass, each one kernel named by its linear."""
+    from repro.core.gqs_layer import GQSAConfig, packed_linear_shapes
+    from repro.kernels import ops
+    bsr = jax.tree_util.tree_map(
+        lambda l: jax.ShapeDtypeStruct((64,) + l.shape, l.dtype,
+                                       sharding=one_chip),
+        packed_linear_shapes(n, k, GQSAConfig())["bsr"])
+    x = jax.ShapeDtypeStruct((64, 32, k), jnp.bfloat16, sharding=one_chip)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "_interpret", lambda: False)
+        gemv = jax.jit(lambda x, b: ops.gqsa_gemv_experts(x, b, "ewg")
+                       ).lower(x, bsr).compile().as_text()
+        dense = jax.jit(lambda b: ops.gqsa_densify_experts(
+            b, jnp.bfloat16, "ewd")).lower(bsr).compile().as_text()
+    assert "%gqsa_gemv_ewg" in gemv and "%gqsa_densify_ewd" in dense
+
+
+def test_dsv2_lite_steps_compile_and_fit(one_chip, monkeypatch):
+    """DeepSeek-V2-Lite at its benchmark cell's size (published widths,
+    14 of 27 layers, 32 slots, max_seq 768): the decode step runs the
+    expert GEMV over the stack and the latent kernel, the first fill's
+    prefill (32 x 768 rows) densifies the experts once; both fit one
+    chip by the compiler's count."""
+    import json
+    import sys
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path[:0] = [checkout]
+    from bench import weights
+    from bench.harness import engine_config, shapes
+    from bench.models import mla_moe
+    from repro.core.gqs_layer import GQSAConfig, packed_linear_shapes
+    from repro.engine import SamplingParams
+    from repro.engine.engine import _step_fns
+    from repro.kernels import ops
+    from repro.models.registry import get_model
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    with open(os.path.join(checkout, "bench", "configs",
+                           "deepseek-v2-lite.gqsa.json")) as f:
+        conf = json.load(f)
+    with open(os.path.join(checkout, "bench", "traffic",
+                           "decode_backlog.json")) as f:
+        tr = json.load(f)
+    model = mla_moe.Model.from_conf(conf)
+    assert weights.Compression.from_conf(conf["compression"]) == \
+        weights.Compression(4, 0.5, 16)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    packed = {}
+    for block, name, _, n, k, lead in model.linears():
+        leaves, tdef = jax.tree_util.tree_flatten(
+            packed_linear_shapes(n, k, GQSAConfig())["bsr"])
+        packed.setdefault(block, {})[name] = {"bsr": tdef.unflatten(
+            [sds(tuple(lead) + l.shape, l.dtype) for l in leaves])}
+    dense = jax.tree_util.tree_map(
+        lambda l: sds(l.shape, l.dtype),
+        jax.eval_shape(lambda: mla_moe.unpacked_leaves(model, 0,
+                                                       jnp.bfloat16)))
+    params = mla_moe.assemble(packed, dense)
+    cfg, ecfg = mla_moe.program_config(conf), engine_config(tr)
+    b = ecfg.num_slots
+    mp = -(-ecfg.max_seq // ecfg.page_size)
+    cache = jax.tree_util.tree_map(
+        lambda l: sds(l.shape, l.dtype),
+        jax.eval_shape(lambda: get_model(cfg).init_paged_cache(
+            cfg, b * mp, ecfg.page_size)))
+
+    def i32(*shape):
+        return sds(shape, jnp.int32)
+    rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    sh = shapes(tr, ecfg)
+    _step_fns.cache_clear()
+    try:
+        prefill, decode, _ = _step_fns(cfg, SamplingParams(), True)
+        dec = decode.lower(params, cache, i32(b), i32(b), i32(b, mp),
+                           i32(b), rng, max(sh["decode"])).compile()
+        pre = prefill.lower(params, cache, i32(b, sh["first_fill"][0]),
+                            i32(b), i32(b, mp), rng).compile()
+    finally:
+        _step_fns.cache_clear()
+    for what, comp, kernels in (
+            ("decode", dec, ("%gqsa_gemv_ewg", "%gqsa_gemv_ewd",
+                             "%paged_latent_attention")),
+            ("prefill", pre, ("%gqsa_densify_ewu",))):
+        ma = comp.memory_analysis()
+        total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+                 + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+        print(f"deepseek-v2-lite x 14 layers {what}: arguments "
+              f"{ma.argument_size_in_bytes / 2**30:.2f} GiB, temp "
+              f"{ma.temp_size_in_bytes / 2**30:.2f} GiB, total "
+              f"{total / 2**30:.2f} GiB")
+        text = comp.as_text()
+        assert all(kname in text for kname in kernels), what
+        assert total < 15 * 2**30
